@@ -15,8 +15,8 @@ COPY video_features_tpu ./video_features_tpu
 COPY main.py bench.py ./
 COPY scripts ./scripts
 
-# CPU jax by default; swap for the TPU wheel on TPU VMs:
-#   pip install -e ".[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html
+# CPU jax 0.9.0 by default (pyproject.toml pins it); on TPU VMs add libtpu:
+#   pip install -e ".[tpu]"      # jax[tpu]==0.9.0, libtpu==0.0.34
 RUN pip install --no-cache-dir -e ".[convert]"
 
 # converted weights cache (mount a volume here; see scripts/convert_weights.py)

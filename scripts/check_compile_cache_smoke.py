@@ -35,6 +35,9 @@ from pathlib import Path
 from typing import List
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# this gate drives the store through an explicit temporary compile_cache_dir;
+# where JAX_COMPILATION_CACHE_DIR is set the store resolves disabled
+os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT))
 

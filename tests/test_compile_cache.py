@@ -31,22 +31,29 @@ from video_features_tpu import compile_cache as cc
 REPO = Path(__file__).resolve().parent.parent
 
 
+@pytest.fixture(autouse=True)
+def no_env_placement(monkeypatch):
+    """These tests pass explicit temporary stores; where the machine sets
+    JAX_COMPILATION_CACHE_DIR the store would resolve disabled
+    (compile_cache.env_placement) — the tests that pin that rule set the
+    variable themselves."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("VFT_COMPILE_CACHE_DIR", raising=False)
+
+
 @pytest.fixture
 def cc_detached():
     """Detach the process-global entry around a test and restore JAX's
     compilation-cache config afterwards, so in-process attach tests
     cannot leak state into the rest of the suite."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
     prev = jax.config.jax_compilation_cache_dir
     cc.detach_for_tests()
     yield
     cc.detach_for_tests()
     jax.config.update("jax_compilation_cache_dir", prev)
-    try:
-        from jax._src import compilation_cache as _jcc
-        _jcc.reset_cache()
-    except Exception:
-        pass
+    compilation_cache.reset_cache()
 
 
 # -- keying ------------------------------------------------------------------
@@ -175,16 +182,48 @@ def test_unsealed_file_dropped_at_attach(tmp_path):
 def test_resolve_root_semantics(tmp_path, monkeypatch):
     assert cc.resolve_root({"compile_cache": False}) is None
     # auto on the CPU backend without an explicit dir: disabled (tests
-    # and casual runs must not grow a store in $HOME)
+    # and casual runs must not grow a store as a side effect)
     assert cc.resolve_root({"compile_cache": "auto"}) is None
     assert cc.resolve_root({"compile_cache": "auto",
                             "compile_cache_dir": str(tmp_path)}) \
         == str(tmp_path)
+    # nothing set: the one fixed in-checkout directory
+    assert cc.resolve_root({"compile_cache": True}) == cc.default_root() \
+        == str(REPO / ".cache" / "xla")
     monkeypatch.setenv("VFT_COMPILE_CACHE_DIR", str(tmp_path / "envroot"))
     assert cc.resolve_root({"compile_cache": True}) \
         == str(tmp_path / "envroot")
     with pytest.raises(ValueError, match="compile_cache"):
         cc.resolve_root({"compile_cache": "bogus"})
+
+
+@pytest.mark.quick
+def test_jax_env_var_places_the_cache(tmp_path, monkeypatch, capsys,
+                                      cc_detached):
+    """JAX_COMPILATION_CACHE_DIR wins over compile_cache_dir= and
+    VFT_COMPILE_CACHE_DIR: attach resolves disabled with ONE printed
+    line, and no entry may redirect jax_compilation_cache_dir."""
+    import jax
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+    monkeypatch.setenv("VFT_COMPILE_CACHE_DIR", str(tmp_path / "envroot"))
+    cc._announce_env_placement.cache_clear()
+    args = dict(BASE, compile_cache_dir=str(tmp_path / "store"))
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append(k))
+    assert cc.resolve_root(args) is None
+    assert cc.attach("resnet", args) is None and cc.active() is None
+    assert cc.attach_for_args("resnet", args) is None
+    out = capsys.readouterr().out
+    assert out.count("JAX_COMPILATION_CACHE_DIR") == 1 and "disabled" in out
+    assert not (tmp_path / "store").exists()
+    with pytest.raises(RuntimeError, match="JAX_COMPILATION_CACHE_DIR"):
+        _fake_entry(tmp_path).activate()
+    assert not updates
+    # an explicit compile_cache=false stays silent: nothing to announce
+    cc._announce_env_placement.cache_clear()
+    assert cc.resolve_root({"compile_cache": False}) is None
+    assert not capsys.readouterr().out
 
 
 @pytest.mark.quick
@@ -233,7 +272,7 @@ def test_warmup_then_extract_zero_miss(sample_video, tmp_path):
                  "batch_size": 8, "compile_cache": True,
                  "compile_cache_dir": str(store),
                  "video_paths": str(sample_video)}
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")  # no_env_placement applies
     warm = subprocess.run(
         [sys.executable, "-c", cc._WARMUP_WORKER, "resnet",
          json.dumps(overrides)], capture_output=True, text=True, env=env,

@@ -1,6 +1,8 @@
 """Config system: YAML defaults, dotlist overrides, sanity_check semantics."""
 import os
 
+from pathlib import Path
+
 import pytest
 
 from video_features_tpu.config import (Config, load_config, merge,
@@ -91,23 +93,58 @@ def test_merge_deep():
 
 
 def test_compilation_cache_knob(monkeypatch, tmp_path):
-    """compilation_cache_dir: 'auto' resolves env var then the home cache;
-    null/empty disables (cli.py _enable_compilation_cache)."""
+    """compilation_cache_dir (cli.py _enable_compilation_cache): null/empty
+    disables; unset environment -> 'auto' is the fixed in-checkout
+    directory; where JAX_COMPILATION_CACHE_DIR is set nothing points the
+    cache anywhere else, whatever the key says."""
+    from video_features_tpu import compile_cache
     from video_features_tpu.cli import _enable_compilation_cache
 
     calls = {}
     import jax
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: calls.__setitem__(k, v))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("VFT_COMPILE_CACHE_DIR", raising=False)
 
     _enable_compilation_cache(dict(compilation_cache_dir=None))
     _enable_compilation_cache(dict(compilation_cache_dir=False))  # yaml 'false'
+    # XLA:CPU executables are microarch-scoped: 'auto' never persists them
+    _enable_compilation_cache(dict(compilation_cache_dir="auto",
+                                   device="cpu"))
     assert not calls
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    repo = Path(__file__).resolve().parent.parent
     _enable_compilation_cache(dict(compilation_cache_dir="auto"))
-    assert calls["jax_compilation_cache_dir"] == str(tmp_path / "env")
+    assert calls["jax_compilation_cache_dir"] == str(repo / ".cache" / "xla") \
+        == compile_cache.default_root()
     _enable_compilation_cache(dict(compilation_cache_dir=str(tmp_path / "x")))
     assert calls["jax_compilation_cache_dir"] == str(tmp_path / "x")
+
+    calls.clear()
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    _enable_compilation_cache(dict(compilation_cache_dir="auto"))
+    _enable_compilation_cache(dict(compilation_cache_dir=str(tmp_path / "x")))
+    assert "jax_compilation_cache_dir" not in calls
+    # small programs still get cached there (and counted as misses)
+    assert calls["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+
+def test_device_tpu_is_verified_and_auto_announces_cpu(tmp_path, capsys):
+    """No path that hides the device: an explicit device=tpu on a host
+    without one raises at extractor init instead of extracting on the
+    CPU, and device=auto says so when it resolves to the CPU."""
+    from video_features_tpu.config import resolve_device
+    from video_features_tpu.extractors.base import BaseExtractor
+
+    assert resolve_device("tpu") == "tpu"  # verified where it is used
+    with pytest.raises(RuntimeError, match=r"device=tpu.*'cpu'.*devices"):
+        BaseExtractor(Config({
+            "feature_type": "resnet", "device": "tpu",
+            "output_path": str(tmp_path / "o"),
+            "tmp_path": str(tmp_path / "t")}))
+    capsys.readouterr()
+    assert resolve_device("auto") == "cpu"
+    assert "found no TPU" in capsys.readouterr().out
 
 
 def test_video_workers_auto(tmp_path):

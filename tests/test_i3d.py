@@ -237,7 +237,31 @@ def test_device_flow_multi_stack_chunking(rng):
 def test_stacks_per_forward_geometry_budget():
     """Auto flow-stack batching: 4 at the 224px flagship geometry, scaled
     down for larger sources so the correlation pyramid fits HBM."""
-    from video_features_tpu.extractors.i3d_flow import _stacks_per_forward
-    assert _stacks_per_forward(64, 224, 224) == 4
-    assert _stacks_per_forward(64, 256, 454) == 1  # 3.8 GB/stack pyramid
-    assert _stacks_per_forward(16, 64, 64) == 4    # tiny input: cap wins
+    import jax
+    from video_features_tpu.extractors.i3d_flow import (
+        _flow_pyramid_budget, _stacks_per_forward)
+    budget = _flow_pyramid_budget(jax.devices()[0])
+    assert budget == 7 * 1024 ** 3  # the CPU backend reports no HBM
+    assert _stacks_per_forward(64, 224, 224, budget) == 4
+    assert _stacks_per_forward(64, 256, 454, budget) == 1  # 3.8 GB/stack
+    assert _stacks_per_forward(16, 64, 64, budget) == 4    # cap wins
+
+
+def test_flow_pyramid_budget_reads_the_device():
+    """On a TPU the budget is a share of what the device reports, and a
+    device that reports nothing is an error, not an assumed 16 GB chip."""
+    from video_features_tpu.extractors.i3d_flow import _flow_pyramid_budget
+
+    class FakeTpu:
+        platform = "tpu"
+
+        def __init__(self, stats):
+            self._stats = stats
+
+        def memory_stats(self):
+            return self._stats
+
+    assert _flow_pyramid_budget(FakeTpu({"bytes_limit": 32 << 30})) \
+        == 14 << 30
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        _flow_pyramid_budget(FakeTpu(None))

@@ -253,3 +253,39 @@ def test_precision_bfloat16_wires_model_dtype(tmp_path, monkeypatch):
         sanity_check(args)
         ex = get_extractor_cls("raft")(args)
         assert ex.model.dtype == want, precision
+
+
+def test_corr_lookup_states_what_ran(rng, monkeypatch, capsys):
+    """Feature values are the same on every lookup branch, so the branch is
+    stated: the plan at init (impl / fused / compiled-or-interpreted), a
+    ``corr_lookup`` span event per traced forward, and a printed line
+    whenever a size gate replaces the planned kernel."""
+    from video_features_tpu.telemetry.spans import VideoSpan
+    monkeypatch.delenv("VFT_CORR_LOOKUP", raising=False)
+    monkeypatch.delenv("VFT_FUSE_CONVC1", raising=False)
+    monkeypatch.setitem(raft_model._CORR_CONFIG, "impl", None)
+    monkeypatch.setitem(raft_model._CORR_CONFIG, "fuse_convc1", None)
+    assert raft_model.corr_lookup_plan() == {
+        "impl": "gather", "fused": False, "compiled": None}
+    raft_model.configure_corr_lookup("pallas", None)
+    # off-TPU pallas_call is the interpreter, and the statement says so
+    assert raft_model.corr_lookup_plan() == {
+        "impl": "pallas", "fused": True, "compiled": False}
+    raft_model.announce_corr_lookup("raft")
+    assert "impl=pallas fused with convc1, in the Pallas interpreter" \
+        in capsys.readouterr().out
+
+    # the VMEM size gate: the one-hot twin runs, and says that it did
+    f1 = jnp.asarray(rng.normal(size=(1, 4, 4, 8)).astype(np.float32))
+    pyramid = raft_model.build_corr_pyramid(f1, f1)
+    coords = jnp.asarray(rng.uniform(0, 4, size=(1, 4, 4, 2))
+                         .astype(np.float32))
+    monkeypatch.setattr(raft_model, "_pallas_supported", lambda p: False)
+    with VideoSpan("v.mp4") as span:
+        got = raft_model.corr_lookup(pyramid, coords)
+    np.testing.assert_allclose(
+        got, raft_model.corr_lookup_gather(pyramid, coords), atol=1e-5)
+    assert "impl=pallas fell back to onehot (XLA)" in capsys.readouterr().out
+    (event,) = [e for e in span.record["events"]
+                if e["kind"] == "corr_lookup"]
+    assert event["impl"] == "pallas" and "onehot" in event["fallback"]

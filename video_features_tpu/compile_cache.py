@@ -52,8 +52,10 @@ proved out:
 
 Enabled by ``compile_cache=``/``compile_cache_dir=`` in all 8 configs
 (``auto`` = on for TPU runs; CPU runs need an explicit dir — their
-executables are microarch-scoped, and tests must stay hermetic). The
-attach point is process-global (JAX has ONE cache directory per
+executables are microarch-scoped, and tests must stay hermetic), and
+disabled wherever ``JAX_COMPILATION_CACHE_DIR`` already places JAX's
+cache (:func:`env_placement` — the one placement rule, shared with
+cli.py). The attach point is process-global (JAX has ONE cache directory per
 process): first attach wins, multi-family runs attach one combined
 entry. Hit/miss counters ride the existing ``jax.monitoring`` listeners
 (telemetry/recorder.py) into every heartbeat's ``compile_cache`` section
@@ -62,6 +64,7 @@ edition".
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -97,11 +100,38 @@ def _sha256_file(path: str) -> str:
     return h.hexdigest()
 
 
+#: JAX's own variable: JAX reads it into ``jax_compilation_cache_dir`` at
+#: import, so where it is set the cache is already placed
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def env_placement() -> Optional[str]:
+    """The directory ``JAX_COMPILATION_CACHE_DIR`` names, or None.
+
+    THE placement rule, shared with ``cli._enable_compilation_cache``:
+    where the variable is set (an operator's shared mount, the chip
+    driver's kept directory) it wins over ``compilation_cache_dir=``,
+    ``compile_cache_dir=`` and ``VFT_COMPILE_CACHE_DIR`` — nothing in this
+    repo points ``jax_compilation_cache_dir`` anywhere else, and the
+    fleet store (whose per-entry directories would do exactly that)
+    resolves disabled. Hit/miss counters come from ``jax.monitoring`` and
+    reach ``_run.json`` either way."""
+    return os.environ.get(JAX_CACHE_ENV) or None
+
+
 def default_root() -> str:
-    return os.environ.get(
-        "VFT_COMPILE_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     "video_features_tpu", "compile_cache"))
+    """Where executables go when nothing placed the cache: one fixed
+    directory inside the checkout (config.CACHE_ROOT), the same for this
+    store and for the CLI's flat per-machine cache."""
+    from .config import CACHE_ROOT
+    return os.environ.get("VFT_COMPILE_CACHE_DIR") or str(CACHE_ROOT / "xla")
+
+
+@functools.lru_cache(maxsize=None)
+def _announce_env_placement(placed: str) -> None:
+    """One line per process, however many extractors ask."""
+    print(f"compile cache: {JAX_CACHE_ENV}={placed} places XLA's cache; "
+          "the fleet store (compile_cache=) is disabled for this process")
 
 
 # -- fingerprints -------------------------------------------------------------
@@ -356,25 +386,20 @@ class CompileCacheEntry:
         entry directory. Process-global by JAX's design — which is
         exactly why attach() is first-wins."""
         import jax
+        from jax.experimental.compilation_cache import compilation_cache
+        if env_placement():
+            raise RuntimeError(
+                f"{JAX_CACHE_ENV} is set: the cache is placed there and no "
+                "entry may redirect it (resolve_root gates every attach)")
         jax.config.update("jax_compilation_cache_dir", self.dir)
         # small executables are worth caching too (cli.py's rationale)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass  # knob absent on older jax: the default caches everything
-        # JAX latches its cache state at the FIRST compile: a process
-        # that compiled anything before attach (extractor init work,
-        # library callers) latched "no cache" and would silently ignore
-        # the dir update — reset so the next compile re-initializes
-        # against the entry directory
-        try:
-            from jax._src import compilation_cache as _jcc
-            if getattr(_jcc, "_cache_initialized", False) or \
-                    getattr(_jcc, "_cache_checked", False):
-                _jcc.reset_cache()
-        except Exception:
-            pass  # private API drifted: pre-first-compile attaches still work
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # JAX opens its cache at the FIRST compile: a process that compiled
+        # anything before attach (extractor init work, library callers)
+        # holds the old directory, or none, and would ignore the update —
+        # reset so the next compile opens the entry directory
+        compilation_cache.reset_cache()
 
 
 # -- process-global attach ----------------------------------------------------
@@ -389,13 +414,19 @@ def resolve_root(args) -> Optional[str]:
     is unconditionally safe and valuable — TPU runs — and requires an
     explicit ``compile_cache_dir`` on the CPU backend: CPU entries are
     microarch-scoped (env_fingerprint covers the flags), and tests /
-    casual CPU runs must not grow a store in $HOME as a side effect."""
+    casual CPU runs must not grow a store as a side effect. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the store is disabled, with one
+    printed line (:func:`env_placement`)."""
     mode = args.get("compile_cache", "auto")
     if mode in (None, False, "", "false", "null", "off"):
         return None
     if mode not in (True, "auto", "true", "on"):
         raise ValueError(f"compile_cache={mode!r}: expected true, false "
                          "or 'auto'")
+    placed = env_placement()
+    if placed:
+        _announce_env_placement(placed)
+        return None
     explicit = args.get("compile_cache_dir")
     if mode == "auto" and explicit is None:
         import jax
@@ -558,7 +589,7 @@ def _warmup_one(family: str, overrides: Dict[str, Any]) -> Dict[str, Any]:
 
     from .config import load_config, sanity_check
     from .registry import get_extractor_cls
-    from .telemetry.recorder import _install_monitoring, _mon_snapshot, \
+    from .telemetry.recorder import compile_cache_baseline, \
         compile_cache_summary
 
     overrides = dict(overrides or {})
@@ -581,8 +612,7 @@ def _warmup_one(family: str, overrides: Dict[str, Any]) -> Dict[str, Any]:
         overrides["tmp_path"] = os.path.join(td, "tmp")
         cfg = load_config(family, overrides)
         sanity_check(cfg)
-        _install_monitoring()
-        baseline = _mon_snapshot()
+        baseline = compile_cache_baseline()
         t0 = time.perf_counter()
         # attach BEFORE construction: the init-time compiles are part of
         # the warm set (the same order the CLI driver uses)
@@ -590,7 +620,8 @@ def _warmup_one(family: str, overrides: Dict[str, Any]) -> Dict[str, Any]:
         if entry is None:
             return {"family": family, "status": "disabled",
                     "note": "compile_cache resolved disabled "
-                            "(compile_cache=false?)"}
+                            f"(compile_cache=false, or {JAX_CACHE_ENV} "
+                            "is set)"}
         warm_before = entry.warm_at_attach
         ext = get_extractor_cls(family)(cfg)
         with contextlib.redirect_stdout(sys.stderr):

@@ -24,6 +24,12 @@ import yaml
 
 _CONFIG_DIR = Path(__file__).resolve().parent / "configs"
 
+#: everything the program builds or caches by default (XLA executables, the
+#: native writer's .so) goes under this one directory inside the checkout,
+#: listed in .gitignore — a fixed path, because a cache that moves never
+#: hits, and not $HOME, which the machine may not keep
+CACHE_ROOT = Path(__file__).resolve().parent.parent / ".cache"
+
 #: validated config keys that legitimately appear in only SOME family
 #: YAMLs — family-specific defaults (flow nets have iteration counts,
 #: clip-stack families have windowing, CLIP has a text side). ``vft-lint``
@@ -265,14 +271,19 @@ def resolve_device(device: Optional[str]) -> str:
               "this framework targets TPU. Treating it as device=auto.")
         device = "auto"
     if device in ("tpu", "cpu"):
-        # never touch jax.devices() for an explicit choice: initializing the
-        # accelerator plugin claims the chip, which `device=cpu` must not do
+        # an explicit choice never enumerates devices here: `device=cpu`
+        # must not claim a chip on a TPU host, and `device=tpu` is verified
+        # against the backend where it is used (extractors/base.py)
         return device
     if device != "auto":
         raise ValueError(f"Unsupported device {device!r}; use tpu|cpu|auto")
     import jax
-    platforms = {d.platform for d in jax.devices()}
-    return "tpu" if "tpu" in platforms else "cpu"
+    devices = jax.devices()
+    if any(d.platform == "tpu" for d in devices):
+        return "tpu"
+    print(f"device=auto: JAX found no TPU (devices: {devices}); running on "
+          "the CPU")
+    return "cpu"
 
 
 def sanity_check(args: Config, *, require_videos: bool = True) -> None:
@@ -456,7 +467,8 @@ def sanity_check(args: Config, *, require_videos: bool = True) -> None:
     if ccd is not None and not isinstance(ccd, str):
         raise ValueError(f"compile_cache_dir={ccd!r}: expected a directory "
                          "path or null (null -> VFT_COMPILE_CACHE_DIR or "
-                         "~/.cache/video_features_tpu/compile_cache)")
+                         "<checkout>/.cache/xla; JAX_COMPILATION_CACHE_DIR, "
+                         "where set, overrides all of them)")
 
     # fleet scheduling keys (parallel/queue.py): validated at launch —
     # a typo'd fleet mode must fail before N hosts start claiming

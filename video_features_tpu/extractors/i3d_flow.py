@@ -60,33 +60,37 @@ def _pwc_quantized_flow(model, crop: int, params, pairs_u8):
     return _crop_quantize(flow, crop)
 
 
-#: HBM budget for one pair-batch forward's correlation pyramid — the
-#: dominant RAFT allocation, (pairs, P, Hsum, Wp) f32 (kernels/corr_lookup
-#: stack_aligned_pyramid). The fallback 7 GiB picks 4 stacks/forward at the
-#: 224px flagship geometry (6.6 GB, measured fine on 16 GB v5e incl.
-#: towers) and scales down automatically for larger source resolutions.
-_FLOW_PYRAMID_BUDGET_FALLBACK = 7 * 1024 ** 3
+#: Share of device memory one pair-batch forward's correlation pyramid may
+#: take — the dominant RAFT allocation, (pairs, P, Hsum, Wp) f32
+#: (kernels/corr_lookup stack_aligned_pyramid). 7/16 of a 16 GB v5e picks 4
+#: stacks/forward at the 224px flagship geometry (6.6 GB, measured fine
+#: incl. towers) and scales down for larger source resolutions.
+_FLOW_PYRAMID_SHARE = 7 / 16
+
+#: what the CPU backend (tests, device=cpu), which reports no memory
+#: stats, sizes against instead: the share of a 16 GiB device
+_FLOW_PYRAMID_BUDGET_CPU = int(_FLOW_PYRAMID_SHARE * 16 * 1024 ** 3)
 
 
-def _flow_pyramid_budget() -> int:
-    """Size the pyramid budget from the actual device HBM when the runtime
-    reports it (advisor r4: the 7 GiB constant assumed a 16 GB v5e — a
-    smaller-HBM chip would OOM at k=4, a larger one under-batch). Uses the
-    same 7/16 fraction the measured v5e number embodied; falls back to the
-    constant when memory_stats is unavailable (CPU backend, older runtimes).
-    """
-    try:
-        import jax
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit:
-            return int(limit * 7 / 16)
-    except Exception:
-        pass
-    return _FLOW_PYRAMID_BUDGET_FALLBACK
+def _flow_pyramid_budget(device) -> int:
+    """The pyramid budget in bytes for ``device`` (the first device of
+    the extractor's mesh — every device of a mesh is the same kind). On a
+    TPU a missing ``bytes_limit`` is an error, not an assumed 16 GB chip:
+    a smaller-HBM chip would OOM at the k the constant picks, a larger one
+    under-batch."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit * _FLOW_PYRAMID_SHARE)
+    if device.platform == "tpu":
+        raise RuntimeError(
+            f"{device} reports no memory_stats()['bytes_limit']: cannot "
+            "size flow_stack_batch=auto against its HBM. Pass "
+            "flow_stack_batch=<int>.")
+    return _FLOW_PYRAMID_BUDGET_CPU
 
 
-def _stacks_per_forward(t: int, h: int, w: int, cap: int = 4) -> int:
+def _stacks_per_forward(t: int, h: int, w: int, budget: int,
+                        cap: int = 4) -> int:
     """How many stacks' pair batches to fuse into one flow forward.
 
     Round-4 measurement (scripts/bench_i3d_variants.py, interleaved): 1 ->
@@ -94,20 +98,20 @@ def _stacks_per_forward(t: int, h: int, w: int, cap: int = 4) -> int:
     unfused and 5.90 -> 6.34 fused at 64f@224px on v5e — more queries per
     launch amortize per-dispatch and per-scan-iteration fixed costs.
     Power-of-two result (wire buckets pad power-of-two), capped by the
-    pyramid HBM budget at this geometry."""
+    pyramid HBM ``budget`` (:func:`_flow_pyramid_budget`) at this
+    geometry."""
     from ..kernels.corr_lookup import stacked_plane_cells
     h8, w8 = -(-h // 8), -(-w // 8)  # RAFT pads inputs to /8 (InputPadder)
     per_stack = t * (h8 * w8) * 4 * stacked_plane_cells(
         h8, w8, levels=raft_model.CORR_LEVELS)
-    budget = _flow_pyramid_budget()
     k = 1
     while k * 2 <= cap and (k * 2) * per_stack <= budget:
         k *= 2
     return k
 
 
-def _pwc_stacks_per_forward(t: int, h: int, w: int, cap: int = 4,
-                            bytes_per_el: int = 2) -> int:
+def _pwc_stacks_per_forward(t: int, h: int, w: int, budget: int,
+                            cap: int = 4, bytes_per_el: int = 2) -> int:
     """PWC twin of :func:`_stacks_per_forward`.
 
     PWC's dominant live set is not an all-pairs pyramid but the per-pair
@@ -123,7 +127,6 @@ def _pwc_stacks_per_forward(t: int, h: int, w: int, cap: int = 4,
     hp, wp = -(-h // 64) * 64, -(-w // 64) * 64
     per_pair = 70 * hp * wp * bytes_per_el
     per_stack = t * per_pair
-    budget = _flow_pyramid_budget()
     k = 1
     while k * 2 <= cap and (k * 2) * per_stack <= budget:
         k *= 2
@@ -135,6 +138,7 @@ class FlowStream:
     def __init__(self, parent, args, mesh, dtype, allow_random) -> None:
         self.parent = parent
         self._flow_dtype = dtype  # sizes the PWC stack-batch HBM budget
+        self._budget = _flow_pyramid_budget(mesh.devices.flat[0])
         # stacks fused per flow forward: 'auto' (geometry-sized at dispatch,
         # see _stacks_per_forward) or a forced integer
         raw_sb = args.get("flow_stack_batch", "auto")
@@ -149,6 +153,7 @@ class FlowStream:
             # env vars stay perf-probe overrides (models/raft.py)
             raft_model.configure_corr_lookup(args.get("corr_lookup_impl"),
                                              args.get("fuse_convc1"))
+            raft_model.announce_corr_lookup("i3d flow stream (raft)")
             # the reference hardcodes the sintel checkpoint for the i3d flow
             # sub-model (extract_i3d.py:178); flow_iters trades flow accuracy
             # for speed (fewer GRU refinement steps) — default is the
@@ -253,7 +258,7 @@ class FlowStream:
         if self.stack_batch is not None:
             k = self.stack_batch
         elif self.parent.flow_type == "raft":
-            k = _stacks_per_forward(t, *group.shape[2:4])
+            k = _stacks_per_forward(t, *group.shape[2:4], self._budget)
         else:
             # PWC budget models the decoder live set, not RAFT's all-pairs
             # pyramid (_pwc_stacks_per_forward). Round-5 interleaved A/B
@@ -261,7 +266,7 @@ class FlowStream:
             # from 6.78 to 11.33 stacks/s (scripts/bench_i3d_variants.py
             # p1b/p2b medians).
             k = _pwc_stacks_per_forward(
-                t, *group.shape[2:4],
+                t, *group.shape[2:4], self._budget,
                 bytes_per_el=jnp.dtype(self._flow_dtype).itemsize)
         outs = []
         for i in range(0, len(group), k):

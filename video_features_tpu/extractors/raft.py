@@ -36,6 +36,7 @@ class ExtractRAFT(OpticalFlowExtractor):
         # vars remain perf-probe overrides (models/raft.py).
         raft_model.configure_corr_lookup(args.get("corr_lookup_impl"),
                                          args.get("fuse_convc1"))
+        raft_model.announce_corr_lookup("raft")
         finetuned_on = args.get("finetuned_on", "sintel")
         if finetuned_on not in ("sintel", "kitti"):
             raise NotImplementedError(

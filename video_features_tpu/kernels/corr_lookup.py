@@ -60,11 +60,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept both so
-# the kernels (and their CPU interpret-mode tests) run across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _blend(window: jnp.ndarray, fx: jnp.ndarray, fy: jnp.ndarray,
            n: int) -> jnp.ndarray:
@@ -267,7 +262,7 @@ def corr_lookup_level_pallas(corr: jnp.ndarray, px0: jnp.ndarray,
         # grid iterations are independent (each owns its query tile):
         # declaring them parallel lets Mosaic pipeline the block DMAs more
         # aggressively (the coarse levels are DMA-latency-bound)
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(px0.astype(jnp.float32)[..., None, None],
@@ -486,7 +481,7 @@ def _corr_lookup_proj_flat(stacked: jnp.ndarray,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, qq, c_out), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tp, len(metas) * n * n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(cx.astype(jnp.float32)[..., None, None],
@@ -752,7 +747,7 @@ def _corr_lookup_packed_flat(packed: jnp.ndarray,
         out_specs=pl.BlockSpec((tq, len(metas) * n * n),
                                lambda qi: (qi, 0), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((qq, len(metas) * n * n), out_dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(cx[:, None, None].astype(jnp.float32),

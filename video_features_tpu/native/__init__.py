@@ -1,13 +1,15 @@
 """Native (C++) runtime IO: build-on-demand, ctypes-bound, always optional.
 
 ``vft_native.cpp`` is compiled with g++ into a cached shared library on first
-import (no pybind11 in this environment — plain ``extern "C"`` + ctypes).
+use (no pybind11 in this environment — plain ``extern "C"`` + ctypes); the
+.so lives under the in-checkout ``.cache/native`` (config.CACHE_ROOT).
 Every entry point has a pure-Python fallback at its call site, so the
 framework runs unchanged where a toolchain is unavailable; set
 ``VFT_NATIVE=0`` to force the fallbacks.
 
 Exports:
   available()               -> bool
+  status()                  -> 'native' | 'python' | 'unused', no build
   write_npy_atomic(path, a) -> write a .npy via temp-file + fsync + rename
   validate_npy(path)        -> structural corruption check, O(header)
 """
@@ -29,10 +31,8 @@ _build_failed = False
 
 
 def _cache_dir() -> Path:
-    root = os.environ.get("VFT_CACHE_DIR",
-                          os.path.join(os.path.expanduser("~"), ".cache",
-                                       "video_features_tpu"))
-    d = Path(root) / "native"
+    from ..config import CACHE_ROOT
+    d = Path(os.environ.get("VFT_CACHE_DIR") or CACHE_ROOT) / "native"
     d.mkdir(parents=True, exist_ok=True)
     return d
 
@@ -82,6 +82,16 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """Which .npy writer this process used so far, without triggering a
+    build: ``native`` (the g++-built library loaded), ``python`` (build or
+    load failed, or ``VFT_NATIVE=0`` — the byte-identical fallback ran) or
+    ``unused`` (nothing was written). The run manifest records it."""
+    if _lib is not None:
+        return "native"
+    return "python" if _build_failed else "unused"
 
 
 def write_npy_atomic(fpath: str, value) -> bool:

@@ -38,10 +38,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # older jax: not yet promoted out of experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -163,11 +160,10 @@ def ring_attention_sharded(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return _softmax_fold(q, acc, ck, cv, scale, valid)
 
     o0, m0, l0 = _fold_init(b, h, t_local, d)
-    if hasattr(jax.lax, "pcast"):
-        # the accumulators become device-varying after one scan step; the
-        # replicated initializers must be cast so the carry types are stable
-        o0, m0, l0 = (jax.lax.pcast(x, (axis_name,), to="varying")
-                      for x in (o0, m0, l0))
+    # the accumulators become device-varying after one scan step; the
+    # replicated initializers must be cast so the carry types are stable
+    o0, m0, l0 = (jax.lax.pcast(x, (axis_name,), to="varying")
+                  for x in (o0, m0, l0))
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def step(carry, i):

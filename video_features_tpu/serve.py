@@ -319,6 +319,8 @@ class ServeLoop:
         # request after a crash or deploy contains no compile, which is
         # the whole point of the serve mode; sealed when run() exits
         from . import compile_cache
+        from .telemetry.recorder import compile_cache_baseline
+        mon_baseline = compile_cache_baseline()  # before anything compiles
         self.compile_cache_entry = (
             compile_cache.attach_for_multi_args(per_family)
             if per_family is not None
@@ -376,7 +378,7 @@ class ServeLoop:
             self.spool_dir, run_config=run_config,
             feature_type=",".join(families),
             interval_s=float(args.get("metrics_interval_s") or 5.0),
-            host_id=host_id)
+            host_id=host_id, mon_baseline=mon_baseline)
         self.recorder.extra_sections["serve"] = self._serve_section
 
         # retained history + alerting, homed on the spool like the

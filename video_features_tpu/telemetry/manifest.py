@@ -52,6 +52,10 @@ def _versions() -> Dict[str, str]:
             out[mod] = str(getattr(m, "__version__", "?"))
         except Exception:
             out[mod] = "absent"
+    # which .npy writer the sinks used (native/__init__.py): the g++-built
+    # one or its byte-identical Python fallback
+    from .. import native
+    out["native_writer"] = native.status()
     return out
 
 

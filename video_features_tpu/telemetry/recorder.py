@@ -81,6 +81,16 @@ def _mon_snapshot() -> Dict[str, int]:
         return dict(_mon_counts)
 
 
+def compile_cache_baseline() -> Dict[str, int]:
+    """Install the ``jax.monitoring`` listeners (once per process) and
+    return the counters as they stand. The drivers take this before the
+    extractor is built and hand it to the recorder, so the manifest's
+    hit/miss counts cover the init-time compiles too — a second process
+    that recompiled its ``model.init`` must not report zero misses."""
+    _install_monitoring()
+    return _mon_snapshot()
+
+
 def compile_cache_summary(baseline: Dict[str, int]) -> Dict[str, int]:
     """Delta of compile-cache events since ``baseline``, folded into
     hit/miss totals plus the raw per-event counts."""
@@ -105,7 +115,8 @@ class TelemetryRecorder:
                  run_config: Optional[dict] = None,
                  feature_type: Optional[str] = None,
                  interval_s: float = 30.0,
-                 host_id: Optional[str] = None) -> None:
+                 host_id: Optional[str] = None,
+                 mon_baseline: Optional[Dict[str, int]] = None) -> None:
         self.output_path = str(output_path)
         self.run_config = run_config
         self.feature_type = feature_type
@@ -135,7 +146,9 @@ class TelemetryRecorder:
         self._health: Dict[str, Dict[str, int]] = {}
         self._t0 = time.perf_counter()
         self._start_time = time.time()
-        self._mon_baseline: Dict[str, int] = {}
+        #: compile-cache counters at the start of what this run accounts
+        #: for (compile_cache_baseline()); None -> taken at start()
+        self._mon_baseline = mon_baseline
         self._started = False
         self._closed = False
         # extension hook: {section_name: zero-arg callable -> JSONable}.
@@ -158,7 +171,8 @@ class TelemetryRecorder:
     def start(self) -> "TelemetryRecorder":
         from . import _set_active
         _install_monitoring()
-        self._mon_baseline = _mon_snapshot()
+        if self._mon_baseline is None:
+            self._mon_baseline = _mon_snapshot()
         os.makedirs(self.output_path, exist_ok=True)
         _set_active(self)
         profiler.set_hook(self._observe_stage)

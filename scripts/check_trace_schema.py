@@ -41,8 +41,9 @@ REPO_ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO_ROOT))
 
 from video_features_tpu.telemetry.trace import (  # noqa: E402
-    REQUIRED_C_FIELDS, REQUIRED_I_FIELDS, REQUIRED_M_FIELDS,
-    REQUIRED_X_FIELDS, TRACE_FILENAME, TRACE_SCHEMA)
+    KNOWN_COUNTER_NAMES, KNOWN_SPAN_NAMES, REQUIRED_C_FIELDS,
+    REQUIRED_I_FIELDS, REQUIRED_M_FIELDS, REQUIRED_X_FIELDS,
+    SPAN_TREE_FIELDS, STAGE_NAMES, TRACE_FILENAME, TRACE_SCHEMA)
 
 #: 3 visual families (frame-wise + frame-wise + clip-stack), tiny frame
 #: budgets — the union-plan fan-out with per-family queues, cheap enough
@@ -50,8 +51,45 @@ from video_features_tpu.telemetry.trace import (  # noqa: E402
 FAMILIES = ("resnet", "clip", "r21d")
 SAMPLE = REPO_ROOT / "tests" / "assets" / "v_synth_sample.mp4"
 
-REQUIRED_BY_PH = {"X": REQUIRED_X_FIELDS, "i": REQUIRED_I_FIELDS,
+#: a complete event this program writes also carries the span tree's
+#: fields (an older trace, which trace_report.py still reads, does not)
+REQUIRED_BY_PH = {"X": REQUIRED_X_FIELDS + SPAN_TREE_FIELDS,
+                  "i": REQUIRED_I_FIELDS,
                   "C": REQUIRED_C_FIELDS, "M": REQUIRED_M_FIELDS}
+
+
+def check_tree(events: List[dict]) -> List[str]:
+    """The span tree's own rules: ids are unique, a parent resolves to one
+    event, cpu is a non-negative number or null, and every name is one the
+    vocabulary in telemetry/trace.py knows (a new span joins
+    ``KNOWN_SPAN_NAMES`` in the PR that adds it)."""
+    errs: List[str] = []
+    xs = [e for e in events if e.get("ph") == "X"]
+    sids = [e.get("sid") for e in xs]
+    if len(set(sids)) != len(sids):
+        errs.append("span ids (sid) are not unique")
+    known = set(sids)
+    for e in xs:
+        if e.get("parent") is not None and e["parent"] not in known:
+            errs.append(f"span {e.get('name')!r} names a parent "
+                        f"{e['parent']!r} that is no event's sid")
+        cpu = e.get("cpu")
+        if cpu is not None and not (isinstance(cpu, (int, float))
+                                    and cpu >= 0):
+            errs.append(f"span {e.get('name')!r} has cpu={cpu!r}")
+        if len(errs) > 20:
+            return errs + ["... (further tree violations elided)"]
+    unknown = ({e.get("name") for e in xs}
+               - set(KNOWN_SPAN_NAMES) - set(STAGE_NAMES))
+    if unknown:
+        errs.append(f"span names outside KNOWN_SPAN_NAMES: "
+                    f"{sorted(unknown)}")
+    strays = ({e.get("name") for e in events if e.get("ph") == "C"}
+              - set(KNOWN_COUNTER_NAMES))
+    if strays:
+        errs.append(f"counter names outside KNOWN_COUNTER_NAMES: "
+                    f"{sorted(strays)}")
+    return errs
 
 
 def run_smoke(out: Path, tmp: Path) -> None:
@@ -104,10 +142,13 @@ def check(out: Path) -> List[str]:
                 errs.append("... (further field violations elided)")
                 break
 
+    errs += check_tree(events)
+
     # 2. load-bearing spans and lanes
     names = {e.get("name") for e in events if e.get("ph") == "X"}
     for want in ("decode", "forward", "video_attempt",
-                 "fanout.decode_pass"):
+                 "fanout.decode_pass", "decode.read", "batch.assemble",
+                 "mesh.pad", "mesh.enqueue", "mesh.fetch", "batch.collect"):
         if want not in names:
             errs.append(f"no {want!r} span in the trace — an "
                         "instrumentation point fell off")

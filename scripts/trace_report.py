@@ -73,7 +73,8 @@ BUCKETS = ("decode", "transform", "h2d", "device", "write", "stall")
 
 #: umbrella spans bracket a whole job INCLUDING its idle waits — they
 #: cut windows (critical path) but must not count as busy time
-UMBRELLA_SPAN_NAMES = ("family", "video_attempt", "fanout.decode_pass")
+UMBRELLA_SPAN_NAMES = ("family", "video_attempt", "fanout.decode_pass",
+                       "serve.request")
 
 
 def load_host_trace(path: str) -> Tuple[dict, str]:
@@ -191,6 +192,71 @@ def top_stalls(xs: List[dict], top: int) -> List[str]:
             str(args.get("video", "")))
         lines.append(f"{e['dur'] / 1e3:9.1f}  {e['name']}"
                      + (f" [{tag}]" if tag else ""))
+    return lines
+
+
+def per_request(xs: List[dict], top: int = 3) -> List[str]:
+    """One line per request id (``rid``, on every span since PR 24): its
+    spans and threads, the wall time of its ``serve.request``, the thread
+    CPU its spans burned, and where its own time went — the spans with
+    most SELF time (children on the same thread taken out), waits among
+    them. A batch run, or a trace written before events carried ``rid``,
+    has none."""
+    by_rid: Dict[str, List[dict]] = {}
+    for e in xs:
+        if e.get("rid") is not None:
+            by_rid.setdefault(str(e["rid"]), []).append(e)
+    if not by_rid:
+        return ["(no request ids on the spans: a batch run, or a trace "
+                "written before spans carried rid)"]
+    lines = [f"{'request':<24} {'spans':>6} {'thr':>3} {'wall ms':>9} "
+             f"{'cpu ms':>9}  most self time"]
+    for rid, evs in sorted(by_rid.items(),
+                           key=lambda kv: min(e["ts"] for e in kv[1])):
+        kids: Dict[str, float] = {}
+        for e in evs:
+            parent = e.get("parent")
+            if parent is not None:
+                kids[(parent, e["tid"])] = \
+                    kids.get((parent, e["tid"]), 0.0) + e["dur"]
+        selfs: Dict[str, float] = {}
+        cpu = 0.0
+        for e in evs:
+            own = e["dur"] - kids.get((e.get("sid"), e["tid"]), 0.0)
+            if e["name"] not in UMBRELLA_SPAN_NAMES:
+                selfs[e["name"]] = selfs.get(e["name"], 0.0) + max(own, 0.0)
+            if e.get("parent") is None or e["name"] == "prefetch.next":
+                cpu += e.get("cpu") or 0.0  # tops of each thread's tree
+        wall = sum(e["dur"] for e in evs if e["name"] == "serve.request") \
+            or (max(e["ts"] + e["dur"] for e in evs)
+                - min(e["ts"] for e in evs))
+        most = ", ".join(f"{n} {v / 1e3:.1f}" for n, v in
+                         sorted(selfs.items(), key=lambda kv: -kv[1])[:top])
+        lines.append(f"{rid[:24]:<24} {len(evs):6d} "
+                     f"{len({e['tid'] for e in evs}):3d} {wall / 1e3:9.1f} "
+                     f"{cpu / 1e3:9.1f}  {most}")
+    return lines
+
+
+def counter_tracks(events: List[dict]) -> List[str]:
+    """One line per counter track (``ph: C``; Perfetto draws each as a
+    graph lane): samples and the least, median and greatest value — how
+    full the packer's buffer ran, how deep the streams, how many rows the
+    ragged flushes carried."""
+    tracks: Dict[str, List[float]] = {}
+    for e in events:
+        if e.get("ph") == "C":
+            for value in (e.get("args") or {}).values():
+                if isinstance(value, (int, float)):
+                    tracks.setdefault(e["name"], []).append(float(value))
+    if not tracks:
+        return ["(no counter samples)"]
+    lines = [f"{'samples':>8} {'min':>8} {'median':>8} {'max':>8}  counter"]
+    for name, values in sorted(tracks.items()):
+        values.sort()
+        lines.append(f"{len(values):8d} {values[0]:8.1f} "
+                     f"{values[len(values) // 2]:8.1f} {values[-1]:8.1f}  "
+                     f"{name}")
     return lines
 
 
@@ -374,6 +440,14 @@ def main() -> None:
 
     print("\n== top stalls ==")
     for line in top_stalls(xs, args.top):
+        print(line)
+
+    print("\n== per request ==")
+    for line in per_request(xs):
+        print(line)
+
+    print("\n== counters ==")
+    for line in counter_tracks(events):
         print(line)
 
     print("\n== per-video critical path ==")

@@ -25,6 +25,7 @@ import numpy as np
 
 from ..config import Config
 from ..parallel.mesh import DataParallelApply
+from ..telemetry import trace
 from ..utils.io import Prefetcher, VideoSource
 from ..utils.lists import form_slices
 from .base import BaseExtractor
@@ -148,8 +149,9 @@ class ClipStackExtractor(BaseExtractor):
                 start_idx = idx
             current.append(f)
             if len(current) == self.stack_size:
-                yield (start_idx, start_idx + self.stack_size), \
-                    np.stack(current)
+                with trace.span("batch.assemble", frames=self.stack_size):
+                    stack = np.stack(current)
+                yield (start_idx, start_idx + self.stack_size), stack
                 current.clear()
                 until_next = gap
         # a trailing partial stack is dropped by falling off the loop
@@ -166,7 +168,8 @@ class ClipStackExtractor(BaseExtractor):
         stream = self._make_stream()
 
         def flush():
-            group = np.stack(stacks)
+            with trace.span("batch.assemble", rows=len(stacks)):
+                group = np.stack(stacks)
             stream.submit(group, ctx=(list(windows), group))
             stacks.clear()
             windows.clear()
@@ -178,15 +181,18 @@ class ClipStackExtractor(BaseExtractor):
                 flush()
         if stacks:
             flush()
-        for bi, feats in enumerate(stream.finish()):
-            if self.parity:
-                # backbone seam: per-group clip activations off the device
-                from ..telemetry import parity as _parity
-                _parity.tap("backbone", self.feature_type, feats,
-                            video=str(src.path),
-                            feature_type=self.feature_type, index=bi)
-            vid_feats.extend(list(feats))
-        return {self.feature_type: np.array(vid_feats)}
+        done = stream.finish()
+        with trace.span("batch.collect", batches=len(done)):
+            for bi, feats in enumerate(done):
+                if self.parity:
+                    # backbone seam: per-group clip activations off the
+                    # device
+                    from ..telemetry import parity as _parity
+                    _parity.tap("backbone", self.feature_type, feats,
+                                video=str(src.path),
+                                feature_type=self.feature_type, index=bi)
+                vid_feats.extend(list(feats))
+            return {self.feature_type: np.array(vid_feats)}
 
     def _extract_packed(self, src: VideoSource) -> Dict[str, np.ndarray]:
         """Cross-video group packing: clips go straight into the shared

@@ -22,6 +22,7 @@ import numpy as np
 
 from ..config import Config
 from ..parallel.mesh import DataParallelApply
+from ..telemetry import trace
 from ..utils.io import Prefetcher, VideoSource
 from .base import BaseExtractor
 
@@ -176,10 +177,13 @@ class FrameWiseExtractor(BaseExtractor):
                     runner,
                     on_result=lambda feats, ctx: self.maybe_show_pred(feats))
             # runner pads ragged tails to fixed_batch
-            stream.submit(np.stack(batch))
+            with trace.span("batch.assemble", rows=len(batch)):
+                group = np.stack(batch)
+            stream.submit(group)
             timestamps_ms.extend(times)
-        if stream is not None:
-            for bi, feats in enumerate(stream.finish()):
+        done = stream.finish() if stream is not None else []
+        with trace.span("batch.collect", batches=len(done)):
+            for bi, feats in enumerate(done):
                 if self.parity:
                     # backbone seam: the per-batch activations exactly as
                     # they come off the device runner
@@ -188,8 +192,9 @@ class FrameWiseExtractor(BaseExtractor):
                                 video=str(video_path),
                                 feature_type=self.feature_type, index=bi)
                 vid_feats.extend(list(feats))
+            collected = np.array(vid_feats)
         return {
-            self.feature_type: np.array(vid_feats),
+            self.feature_type: collected,
             "fps": np.array(video.fps),
             "timestamps_ms": np.array(timestamps_ms),
         }

@@ -16,6 +16,7 @@ import numpy as np
 
 from ..config import Config
 from ..models import r21d as r21d_model
+from ..models.common import scope
 from ..ops import colorspace
 from ..ops import host_transforms as ht
 from ..ops import preprocess as pp
@@ -31,12 +32,15 @@ def _device_forward(model: r21d_model.R2Plus1D, dtype, params, batch):
     /255 (uint8 wire format only), K400-normalize, backbone — all fused by
     XLA into the stem conv. The dtype branch is resolved at trace time.
     """
-    if batch.dtype == jnp.uint8:
-        batch = batch.astype(jnp.float32) / 255.0
-    x = (batch - jnp.asarray(r21d_model.R21D_MEAN, batch.dtype)) / \
-        jnp.asarray(r21d_model.R21D_STD, batch.dtype)
-    x = x.astype(dtype)
-    return model.apply({"params": params}, x).astype(jnp.float32)
+    with scope("R2Plus1D", "ingest"):
+        if batch.dtype == jnp.uint8:
+            batch = batch.astype(jnp.float32) / 255.0
+        x = (batch - jnp.asarray(r21d_model.R21D_MEAN, batch.dtype)) / \
+            jnp.asarray(r21d_model.R21D_STD, batch.dtype)
+        x = x.astype(dtype)
+    feats = model.apply({"params": params}, x)
+    with scope("R2Plus1D", "head"):
+        return feats.astype(jnp.float32)
 
 
 def _device_forward_yuv420(model: r21d_model.R2Plus1D, dtype, params, batch):
@@ -45,7 +49,8 @@ def _device_forward_yuv420(model: r21d_model.R2Plus1D, dtype, params, batch):
     On-device colorspace conversion (ops/colorspace.py) into the shared
     normalize + backbone; the wire carries 1.5 bytes/pixel instead of 3.
     """
-    rgb = colorspace.yuv420_packed_to_rgb(batch, 112, 112) / 255.0
+    with scope("R2Plus1D", "ingest"):
+        rgb = colorspace.yuv420_packed_to_rgb(batch, 112, 112) / 255.0
     return _device_forward(model, dtype, params, rgb)
 
 

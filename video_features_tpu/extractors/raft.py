@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from ..config import Config
 from ..models import raft as raft_model
+from ..models.common import scope
 from ..parallel.mesh import get_mesh
 from ..weights import store
 from .flow import OpticalFlowExtractor
@@ -20,10 +21,12 @@ from .flow import OpticalFlowExtractor
 
 def _raft_forward(model: raft_model.RAFT, params, pairs_u8):
     """(B, 2, H, W, 3) uint8 -> (B, H, W, 2) flow; pad/unpad inside jit."""
-    flow, ((pt, pb), (pl, pr)) = raft_model.padded_flow(
-        model, params, pairs_u8.astype(jnp.float32))
+    with scope("RAFT", "encode"):
+        pairs = pairs_u8.astype(jnp.float32)
+    flow, ((pt, pb), (pl, pr)) = raft_model.padded_flow(model, params, pairs)
     hp, wp = flow.shape[1], flow.shape[2]
-    return flow[:, pt:hp - pb, pl:wp - pr, :].astype(jnp.float32)
+    with scope("RAFT", "upsample"):
+        return flow[:, pt:hp - pb, pl:wp - pr, :].astype(jnp.float32)
 
 
 class ExtractRAFT(OpticalFlowExtractor):

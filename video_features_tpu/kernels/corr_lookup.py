@@ -249,6 +249,7 @@ def corr_lookup_level_pallas(corr: jnp.ndarray, px0: jnp.ndarray,
                               memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_level_kernel, radius=radius),
+        name="corr_lookup_level",  # the kernel's own name in a device trace
         grid=(b, pp // tp),
         in_specs=[
             coord_spec,
@@ -465,6 +466,7 @@ def _corr_lookup_proj_flat(stacked: jnp.ndarray,
                               memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_proj_kernel, radius=radius, metas=metas),
+        name="corr_lookup_proj",  # the kernel's own name in a device trace
         grid=(qq // tp,),
         in_specs=[
             coord_spec, coord_spec,
@@ -740,6 +742,7 @@ def _corr_lookup_packed_flat(packed: jnp.ndarray,
                               memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         functools.partial(_packed_kernel, radius=radius, metas=metas),
+        name="corr_lookup_packed",  # the kernel's own name in a device trace
         grid=(qq // tq,),
         in_specs=[coord_spec, coord_spec,
                   pl.BlockSpec((tq, k_total), lambda qi: (qi, 0),

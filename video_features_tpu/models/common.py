@@ -11,11 +11,25 @@ adjacent conv epilogue.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+
+
+def scope(*names: str) -> contextlib.ExitStack:
+    """``with scope("RAFT", "ingest"):`` — nested ``jax.named_scope``s, for
+    the operations a device step runs OUTSIDE its flax module (the module's
+    own calls already sit under ``<Module>/<submodule>/...``). Metadata
+    only: a profiler trace then names every operation of the program
+    ``<Module>/<stage>/...`` (PERF.md section 3), the compiled program is
+    the same."""
+    stack = contextlib.ExitStack()
+    for name in names:
+        stack.enter_context(jax.named_scope(name))
+    return stack
 
 
 class BNInf(nn.Module):

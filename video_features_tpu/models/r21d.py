@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Mapping, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
@@ -96,13 +97,16 @@ class R2Plus1D(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         stages, _ = VARIANTS[self.variant]
+        # the stage scopes (stem, layer1..4, head) are metadata: a device
+        # trace names every operation R2Plus1D/<stage>/<flax path>
         # R(2+1)D stem: spatial 7x7 then temporal 3 (torchvision R2Plus1dStem)
-        x = _conv3d(45, (1, 7, 7), (1, 2, 2), (0, 3, 3), "stem_conv_s")(x)
-        x = BNInf(name="stem_bn_s")(x)
-        x = nn.relu(x)
-        x = _conv3d(64, (3, 1, 1), (1, 1, 1), (1, 0, 0), "stem_conv_t")(x)
-        x = BNInf(name="stem_bn_t")(x)
-        x = nn.relu(x)
+        with jax.named_scope("stem"):
+            x = _conv3d(45, (1, 7, 7), (1, 2, 2), (0, 3, 3), "stem_conv_s")(x)
+            x = BNInf(name="stem_bn_s")(x)
+            x = nn.relu(x)
+            x = _conv3d(64, (3, 1, 1), (1, 1, 1), (1, 0, 0), "stem_conv_t")(x)
+            x = BNInf(name="stem_bn_t")(x)
+            x = nn.relu(x)
 
         in_planes = 64
         for stage_idx, num_blocks in enumerate(stages):
@@ -111,11 +115,13 @@ class R2Plus1D(nn.Module):
             for block_idx in range(num_blocks):
                 s = stride if block_idx == 0 else 1
                 needs_ds = (s != 1) or (in_planes != planes)
-                x = BasicBlock(planes, s, needs_ds,
-                               name=f"layer{stage_idx + 1}_{block_idx}")(x)
+                with jax.named_scope(f"layer{stage_idx + 1}"):
+                    x = BasicBlock(planes, s, needs_ds,
+                                   name=f"layer{stage_idx + 1}_{block_idx}")(x)
                 in_planes = planes
         # AdaptiveAvgPool3d(1)
-        return jnp.mean(x, axis=(1, 2, 3))
+        with jax.named_scope("head"):
+            return jnp.mean(x, axis=(1, 2, 3))
 
 
 class Classifier(nn.Module):

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from .common import BNInf
+from .common import BNInf, scope
 from ..weights import torch_import as ti
 
 CORR_LEVELS = 4
@@ -147,10 +147,11 @@ class BasicMotionEncoder(nn.Module):
             from ..kernels import interpret_mode
             from ..kernels.corr_lookup import corr_lookup_proj
             k, b = _Convc1Params(name="convc1")()
-            cor = corr_lookup_proj(corr, self.fuse_meta, coords,
-                                   k.reshape(k.shape[2], k.shape[3]), b,
-                                   interpret=interpret_mode())
-            cor = cor.astype(flow.dtype)
+            with jax.named_scope("lookup"):
+                cor = corr_lookup_proj(corr, self.fuse_meta, coords,
+                                       k.reshape(k.shape[2], k.shape[3]), b,
+                                       interpret=interpret_mode())
+                cor = cor.astype(flow.dtype)
         else:
             cor = nn.relu(nn.Conv(256, (1, 1), name="convc1")(corr))
         cor = nn.relu(nn.Conv(192, (3, 3), padding=1, name="convc2")(cor))
@@ -219,8 +220,10 @@ class UpdateIter(nn.Module):
             # state's dtype so the update convs stay on the MXU-native
             # dtype. coords stay f32 through the carry: delta promotes back
             # on add.
-            corr = corr_lookup(pyramid, coords1,
-                               packed_meta=self.corr_meta).astype(net.dtype)
+            with jax.named_scope("lookup"):
+                corr = corr_lookup(pyramid, coords1,
+                                   packed_meta=self.corr_meta
+                                   ).astype(net.dtype)
             motion = BasicMotionEncoder(name="encoder")(flow, corr)
         x = jnp.concatenate([inp, motion], axis=-1)
         net = SepConvGRU(name="gru")(net, x)
@@ -525,9 +528,10 @@ def padded_flow(model: "RAFT", params, pairs_f32: jnp.ndarray,
     (the I3D flow stream, which never unpads — extract_i3d.py:153)."""
     (pt, pb), (pl, pr) = pad_to_multiple(pairs_f32[:, 0], mode=mode)
     pad = ((0, 0), (pt, pb), (pl, pr), (0, 0))
-    flow = model.apply({"params": params},
-                       jnp.pad(pairs_f32[:, 0], pad, mode="edge"),
-                       jnp.pad(pairs_f32[:, 1], pad, mode="edge"))
+    with scope("RAFT", "encode"):
+        image1 = jnp.pad(pairs_f32[:, 0], pad, mode="edge")
+        image2 = jnp.pad(pairs_f32[:, 1], pad, mode="edge")
+    flow = model.apply({"params": params}, image1, image2)
     return flow, ((pt, pb), (pl, pr))
 
 
@@ -556,79 +560,89 @@ class RAFT(nn.Module):
 
     @nn.compact
     def __call__(self, image1: jnp.ndarray, image2: jnp.ndarray) -> jnp.ndarray:
-        image1 = (2 * (image1 / 255.0) - 1.0).astype(self.dtype)
-        image2 = (2 * (image2 / 255.0) - 1.0).astype(self.dtype)
+        # the stage scopes (encode, corr_pyramid, update, upsample) are
+        # metadata: a device trace names every operation
+        # RAFT/<stage>/<flax path>; the lookup adds update/.../lookup
+        with jax.named_scope("encode"):
+            image1 = (2 * (image1 / 255.0) - 1.0).astype(self.dtype)
+            image2 = (2 * (image2 / 255.0) - 1.0).astype(self.dtype)
 
-        fnet = BasicEncoder(256, "instance", name="fnet")
-        # one shared-weight call on the concatenated pair, like the
-        # reference's fnet([image1, image2]) (raft.py:132)
-        fmaps = fnet(jnp.concatenate([image1, image2], axis=0))
-        fmap1, fmap2 = jnp.split(fmaps, 2, axis=0)
-        pyramid = build_corr_pyramid(fmap1, fmap2)
+            fnet = BasicEncoder(256, "instance", name="fnet")
+            # one shared-weight call on the concatenated pair, like the
+            # reference's fnet([image1, image2]) (raft.py:132)
+            fmaps = fnet(jnp.concatenate([image1, image2], axis=0))
+            fmap1, fmap2 = jnp.split(fmaps, 2, axis=0)
+        with jax.named_scope("corr_pyramid"):
+            pyramid = build_corr_pyramid(fmap1, fmap2)
         corr_meta = None
         fuse_meta = None
         plan = corr_lookup_plan()
         impl = plan["impl"]
         fallback = None
-        if impl == "pallas" and _pallas_supported(pyramid):
-            # tile-align the loop-invariant pyramid ONCE, outside the scan:
-            # the pallas lookup needs (8, 128)-aligned level planes, and XLA
-            # does not hoist the pads out of the while body — unhoisted they
-            # ran 20x per forward and cost ~30% of the whole RAFT step
-            # (kernels/corr_lookup.py align_level; zero pads are exactly the
-            # reference's out-of-range zeros rule)
-            from ..kernels.corr_lookup import (align_level,
-                                               proj_lookup_supported,
-                                               stack_aligned_pyramid)
-            if plan["fused"] and proj_lookup_supported(pyramid):
-                # round-4 default: ONE kernel serves all four levels AND
-                # the motion encoder's convc1 — the 324-channel lookup
-                # intermediate (and its relayout boundary) never exists
-                pyramid, fuse_meta = stack_aligned_pyramid(pyramid)
-            else:
-                if plan["fused"]:
-                    hl, wl = pyramid[0].shape[2:]
-                    fallback = (f"the unfused per-level kernels: the stacked "
-                                f"{hl}x{wl} pyramid plane fits no legal VMEM "
-                                "tile")
-                pyramid = tuple(align_level(c) for c in pyramid)
-            # (measured, not kept as default: a lane-DENSE packed pyramid
-            # moves 5.8x fewer bytes but lands ~10% slower end-to-end —
-            # the lookup is selection-bound, not DMA-bound. The packed
-            # kernel stays available as VFT_CORR_LOOKUP=packed; the
-            # negative-result record lives in kernels/corr_lookup.py.)
-        elif impl == "packed" and _fused_supported(pyramid):
-            # lane-dense-pack ONCE outside the scan; ONE fused kernel
-            # serves all four levels per iteration
-            from ..kernels.corr_lookup import pack_pyramid
-            pyramid, corr_meta = pack_pyramid(pyramid)
+        with jax.named_scope("corr_pyramid"):
+            if impl == "pallas" and _pallas_supported(pyramid):
+                # tile-align the loop-invariant pyramid ONCE, outside the scan:
+                # the pallas lookup needs (8, 128)-aligned level planes, and XLA
+                # does not hoist the pads out of the while body — unhoisted they
+                # ran 20x per forward and cost ~30% of the whole RAFT step
+                # (kernels/corr_lookup.py align_level; zero pads are exactly the
+                # reference's out-of-range zeros rule)
+                from ..kernels.corr_lookup import (align_level,
+                                                   proj_lookup_supported,
+                                                   stack_aligned_pyramid)
+                if plan["fused"] and proj_lookup_supported(pyramid):
+                    # round-4 default: ONE kernel serves all four levels AND
+                    # the motion encoder's convc1 — the 324-channel lookup
+                    # intermediate (and its relayout boundary) never exists
+                    pyramid, fuse_meta = stack_aligned_pyramid(pyramid)
+                else:
+                    if plan["fused"]:
+                        hl, wl = pyramid[0].shape[2:]
+                        fallback = (f"the unfused per-level kernels: the stacked "
+                                    f"{hl}x{wl} pyramid plane fits no legal VMEM "
+                                    "tile")
+                    pyramid = tuple(align_level(c) for c in pyramid)
+                # (measured, not kept as default: a lane-DENSE packed pyramid
+                # moves 5.8x fewer bytes but lands ~10% slower end-to-end —
+                # the lookup is selection-bound, not DMA-bound. The packed
+                # kernel stays available as VFT_CORR_LOOKUP=packed; the
+                # negative-result record lives in kernels/corr_lookup.py.)
+            elif impl == "packed" and _fused_supported(pyramid):
+                # lane-dense-pack ONCE outside the scan; ONE fused kernel
+                # serves all four levels per iteration
+                from ..kernels.corr_lookup import pack_pyramid
+                pyramid, corr_meta = pack_pyramid(pyramid)
         # (an unsupported pallas/packed pyramid stays raw; corr_lookup's own
         # size gate states its one-hot fallback when the scan body traces)
         _state_corr_lookup({**plan, "fused": fuse_meta is not None},
                            fallback)
 
-        cnet = BasicEncoder(HIDDEN_DIM + CONTEXT_DIM, "batch",
-                            name="cnet")(image1)
-        net = jnp.tanh(cnet[..., :HIDDEN_DIM])
-        inp = nn.relu(cnet[..., HIDDEN_DIM:])
+        with jax.named_scope("encode"):
+            cnet = BasicEncoder(HIDDEN_DIM + CONTEXT_DIM, "batch",
+                                name="cnet")(image1)
+            net = jnp.tanh(cnet[..., :HIDDEN_DIM])
+            inp = nn.relu(cnet[..., HIDDEN_DIM:])
 
-        b, h8, w8, _ = net.shape
-        gx, gy = jnp.meshgrid(jnp.arange(w8, dtype=jnp.float32),
-                              jnp.arange(h8, dtype=jnp.float32))
-        coords0 = jnp.broadcast_to(jnp.stack([gx, gy], axis=-1),
-                                   (b, h8, w8, 2))
+        with jax.named_scope("update"):
+            b, h8, w8, _ = net.shape
+            gx, gy = jnp.meshgrid(jnp.arange(w8, dtype=jnp.float32),
+                                  jnp.arange(h8, dtype=jnp.float32))
+            coords0 = jnp.broadcast_to(jnp.stack([gx, gy], axis=-1),
+                                       (b, h8, w8, 2))
 
-        # lax.scan compiles ONE iteration body regardless of iters; the
-        # reference's Python loop (raft.py:154-171) unrolls 20 copies
-        scanned = nn.scan(
-            UpdateIter, variable_broadcast="params",
-            split_rngs={"params": False}, in_axes=nn.broadcast,
-            length=self.iters)(corr_meta=corr_meta, fuse_meta=fuse_meta,
-                               name="update_block")
-        (net, coords1), _ = scanned((net, coords0), (pyramid, inp, coords0))
+            # lax.scan compiles ONE iteration body regardless of iters; the
+            # reference's Python loop (raft.py:154-171) unrolls 20 copies
+            scanned = nn.scan(
+                UpdateIter, variable_broadcast="params",
+                split_rngs={"params": False}, in_axes=nn.broadcast,
+                length=self.iters)(corr_meta=corr_meta, fuse_meta=fuse_meta,
+                                   name="update_block")
+            (net, coords1), _ = scanned((net, coords0),
+                                        (pyramid, inp, coords0))
 
-        mask = MaskHead(name="update_mask")(net)
-        return convex_upsample(coords1 - coords0, mask)
+        with jax.named_scope("upsample"):
+            mask = MaskHead(name="update_mask")(net)
+            return convex_upsample(coords1 - coords0, mask)
 
 
 # ---- weight transplant ---------------------------------------------------

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..telemetry import trace  # no jax behind it; no-op spans when off
 from . import colorspace
 from . import preprocess as pp
 
@@ -48,10 +49,12 @@ class R21DTransform:
         self.ingest = ingest
 
     def __call__(self, bgr: np.ndarray) -> np.ndarray:
-        x = bgr.astype(np.float32) / 255.0
-        x = pp.bilinear_resize_no_antialias(x, (128, 171))
-        x = np.ascontiguousarray(pp.center_crop(x, 112)[:, :, ::-1])
-        return encode_wire(x, self.ingest)
+        with trace.span("decode.resize"):
+            x = bgr.astype(np.float32) / 255.0
+            x = pp.bilinear_resize_no_antialias(x, (128, 171))
+        with trace.span("decode.ingest"):
+            x = np.ascontiguousarray(pp.center_crop(x, 112)[:, :, ::-1])
+            return encode_wire(x, self.ingest)
 
 
 class S3DTransform:
@@ -62,11 +65,13 @@ class S3DTransform:
         self.ingest = ingest
 
     def __call__(self, bgr: np.ndarray) -> np.ndarray:
-        x = bgr.astype(np.float32) / 255.0
-        scale = 224.0 / min(x.shape[0], x.shape[1])
-        x = pp.bilinear_resize_by_scale(x, scale)
-        x = np.ascontiguousarray(pp.center_crop(x, 224)[:, :, ::-1])
-        return encode_wire(x, self.ingest)
+        with trace.span("decode.resize"):
+            x = bgr.astype(np.float32) / 255.0
+            scale = 224.0 / min(x.shape[0], x.shape[1])
+            x = pp.bilinear_resize_by_scale(x, scale)
+        with trace.span("decode.ingest"):
+            x = np.ascontiguousarray(pp.center_crop(x, 224)[:, :, ::-1])
+            return encode_wire(x, self.ingest)
 
 
 class ResizeCropTransform:
@@ -81,9 +86,12 @@ class ResizeCropTransform:
         self.ingest = ingest
 
     def __call__(self, rgb: np.ndarray) -> np.ndarray:
-        out = pp.pil_resize(rgb, self.size,
-                            interpolation=self.interpolation)
-        return encode_wire_u8(pp.center_crop(out, self.crop), self.ingest)
+        with trace.span("decode.resize"):
+            out = pp.pil_resize(rgb, self.size,
+                                interpolation=self.interpolation)
+        with trace.span("decode.ingest"):
+            return encode_wire_u8(pp.center_crop(out, self.crop),
+                                  self.ingest)
 
 
 class MinSideResize:

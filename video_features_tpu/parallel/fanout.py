@@ -252,7 +252,11 @@ class SharedFrameSource:
                 if tag == "frame":
                     raw, out_idx = payload
                     with profiler.stage("decode"):
-                        x = tf(raw) if tf is not None else raw
+                        if tf is None:
+                            x = raw
+                        else:
+                            with trace.span("decode.transform"):
+                                x = tf(raw)
                     yield x, out_idx / self.fps * 1000.0, out_idx
                 elif tag == "done":
                     return
@@ -483,7 +487,8 @@ class FrameBus:
                 if not wants and not pending:
                     break  # every plan satisfied
                 t0 = time.perf_counter()
-                with profiler.stage("decode"):
+                with profiler.stage("decode"), trace.span(
+                        "decode.read" if wants else "decode.skip"):
                     if wants:
                         frame = stream.read()
                         ok = frame is not None
@@ -507,7 +512,8 @@ class FrameBus:
                         arr = by_order.get(s.channel_order)
                         if arr is None:
                             t1 = time.perf_counter()
-                            with profiler.stage("decode"):
+                            with profiler.stage("decode"), \
+                                    trace.span("decode.ingest"):
                                 arr = convert_decoded(frame, s.channel_order)
                             self._decode_s += time.perf_counter() - t1
                             by_order[s.channel_order] = arr

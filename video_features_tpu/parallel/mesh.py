@@ -20,7 +20,10 @@ dryrun multichip validation path.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
+import itertools
+import threading
 from pathlib import Path
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
@@ -32,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # roofline=true (telemetry/roofline.py): per-program cost-card capture at
 # the dispatch boundary — one module-global read per dispatch when off
 from ..telemetry.roofline import observe_dispatch as _roofline_observe
+from ..telemetry import trace as _trace
 
 
 def get_mesh(n_devices: Optional[int] = None,
@@ -174,6 +178,25 @@ def cast_floating(tree: Any, dtype) -> Any:
     return jax.tree_util.tree_map(cast, tree)
 
 
+def step_program_name(apply_fn: Callable) -> str:
+    """``vft_<family>_<step>`` for a family's device step: the family is
+    the extractor module the function (or the ``partial``'s) lives in, the
+    step its name without the ``_device_`` prefix (``vft_r21d_forward_yuv420``,
+    ``vft_raft_forward``). A function from outside the package (tests,
+    tools) keeps its own name behind ``vft_``."""
+    import re
+    fn = apply_fn
+    while hasattr(fn, "func"):  # functools.partial
+        fn = fn.func
+    where = getattr(fn, "__module__", "") or ""
+    family = where.rsplit(".", 1)[-1] if "video_features_tpu" in where else ""
+    name = re.sub(r"^_?(device_)?", "",
+                  getattr(fn, "__name__", None) or type(fn).__name__)
+    if family and not name.startswith(family):
+        name = f"{family}_{name}"
+    return "vft_" + re.sub(r"[^A-Za-z0-9_]+", "_", name)
+
+
 class DataParallelApply:
     """Jitted, batch-sharded wrapper around ``apply_fn(params, batch)``.
 
@@ -207,15 +230,46 @@ class DataParallelApply:
                 is_leaf=lambda x: isinstance(x, P))
         self.params = jax.device_put(params, param_shardings)
         self._batch_sharding = batch_sharding
+        #: the jitted step's stable name: a profiler trace's "XLA Modules"
+        #: line says ``jit_<program>``, the host timeline's ``mesh.enqueue``
+        #: carries it as ``program``. jit takes the name from the callable:
+        #: an argument-less ``partial`` is a fresh object to hang it on and
+        #: keeps the step's signature, so the program's parameters are named
+        #: as before.
+        self.program = step_program_name(apply_fn)
+        step = functools.partial(apply_fn)
+        step.__name__ = step.__qualname__ = self.program
         self._fn = jax.jit(
-            apply_fn,
+            step,
             in_shardings=(param_shardings, batch_sharding),
             out_shardings=batch_sharding,
         )
+        #: dispatches of this runner, counted as they enter; the last one
+        #: made by the calling thread is ``last_seq``
+        self._seq = itertools.count()
+        self._local = threading.local()
 
     @property
     def n_devices(self) -> int:
         return int(np.prod(self.mesh.devices.shape))
+
+    @property
+    def last_seq(self) -> Optional[int]:
+        """``seq`` of the last dispatch THIS thread made (``mesh.enqueue``'s
+        arg): whoever later waits for that output says so on its
+        ``mesh.fetch`` span, which ties the wait to the dispatch."""
+        return getattr(self._local, "seq", None)
+
+    def _next_seq(self) -> int:
+        seq = self._local.seq = next(self._seq)
+        return seq
+
+    def _enqueue(self, padded, rows: int, seq: int):
+        """The one call into the jitted step, under ``mesh.enqueue``."""
+        with _trace.span("mesh.enqueue", seq=seq, rows=rows,
+                         padded_rows=int(padded.shape[0]),
+                         program=self.program):
+            return self._fn(self.params, padded)
 
     def padded_batch_size(self, batch_size: int) -> int:
         """Smallest multiple of the *data-axis* size >= batch_size (on a 2-D
@@ -285,25 +339,31 @@ class DataParallelApply:
         accelerators the DMA completes asynchronously, so the stage times
         the host-side staging copy + enqueue (a lower bound on wire
         time); on CPU it is the full copy."""
-        padded = self._pad(batch_np)
+        rows, seq = int(batch_np.shape[0]), self._next_seq()
+        with _trace.span("mesh.pad", seq=seq, rows=rows):
+            padded = self._pad(batch_np)
         _roofline_observe(self, padded)
         if not isinstance(padded, jax.Array):
             from ..utils.profiling import profiler
             with profiler.stage("h2d"):
                 padded = jax.device_put(padded, self._batch_sharding)
-        return self._fn(self.params, padded)
+        return self._enqueue(padded, rows, seq)
 
     def __call__(self, batch_np: np.ndarray, n_valid: Optional[int] = None
                  ) -> np.ndarray:
         """Run a (possibly ragged) batch; returns only the valid rows."""
         from ..utils.profiling import profiler
         n = batch_np.shape[0] if n_valid is None else n_valid
-        padded = self._pad(batch_np)  # host copy kept out of the timed stage
+        rows, seq = int(batch_np.shape[0]), self._next_seq()
+        with _trace.span("mesh.pad", seq=seq, rows=rows):
+            padded = self._pad(batch_np)  # host copy kept out of the stage
         _roofline_observe(self, padded)
         # np.asarray blocks on the device->host copy, so this stage is true
         # H2D + forward + D2H wall time
         with profiler.stage("forward"):
-            return np.asarray(self._fn(self.params, padded))[:n]
+            out = self._enqueue(padded, rows, seq)
+            with _trace.span("mesh.fetch", seq=seq):
+                return np.asarray(out)[:n]
 
     def stream(self, depth: int = 4,
                callback: Optional[Callable[[np.ndarray, Any], None]] = None
@@ -339,7 +399,7 @@ class FeatureStream:
         self.runner = runner
         self.depth = max(int(depth), 0)
         self.callback = callback
-        self._inflight: Any = deque()  # (device_array, n_valid, ctx)
+        self._inflight: Any = deque()  # (device_array, n_valid, ctx, seq)
         self._done: List[np.ndarray] = []
 
     def submit(self, batch_np: np.ndarray, n_valid: Optional[int] = None,
@@ -347,10 +407,12 @@ class FeatureStream:
         n = batch_np.shape[0] if n_valid is None else n_valid
         while self._inflight and len(self._inflight) >= self.depth:
             self._pop()  # drain BEFORE dispatching: bound holds during _pop
-        self.submit_device(self.runner.dispatch(batch_np), n, ctx)
+        dev = self.runner.dispatch(batch_np)
+        self.submit_device(dev, n, ctx,
+                           seq=getattr(self.runner, "last_seq", None))
 
     def submit_device(self, dev: jnp.ndarray, n_valid: int,
-                      ctx: Any = None) -> None:
+                      ctx: Any = None, seq: Optional[int] = None) -> None:
         """Enqueue an ALREADY-dispatched device array (multi-runner
         pipelines, e.g. i3d's per-stream chains, dispatch themselves); the
         stream still bounds retained results and materializes in order. A
@@ -360,17 +422,18 @@ class FeatureStream:
             ctx = None  # don't pin (possibly large) host batches in the queue
         while self._inflight and len(self._inflight) >= max(self.depth, 1):
             self._pop()
-        self._inflight.append((dev, n_valid, ctx))
+        self._inflight.append((dev, n_valid, ctx, seq))
+        _trace.counter("stream.inflight", len(self._inflight))
         if self.depth == 0:
             self._pop()
 
     def _pop(self) -> None:
         from ..utils.profiling import profiler
-        out, n, ctx = self._inflight.popleft()
+        out, n, ctx, seq = self._inflight.popleft()
         # the blocking host copy: under the profiler this stage is the
         # pipeline's *stall* time on the device, not raw device time — by
         # design everything else already happened in the background
-        with profiler.stage("forward"):
+        with profiler.stage("forward"), _trace.span("mesh.fetch", seq=seq):
             feats = np.asarray(out)[:n]
         if self.callback is not None:
             self.callback(feats, ctx)

@@ -49,6 +49,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ..telemetry import trace
+
 
 class ClipPacker:
     def __init__(self, runner, batch: int, depth: int = 4):
@@ -60,7 +62,7 @@ class ClipPacker:
         self._drain_lock = threading.Lock()     # serializes D2H
         self._dispatch_lock = threading.Lock()  # serializes group dispatch
         self._buf: List[tuple] = []          # [(handle, idx, stack), ...]
-        self._inflight: deque = deque()      # [(device_array, manifest)]
+        self._inflight: deque = deque()      # [(device_array, manifest, seq)]
         self._results: Dict[int, Dict[int, np.ndarray]] = {}
         self._counts: Dict[int, int] = {}    # clips added per handle
         self._pending: Dict[int, int] = {}   # clips not yet materialized
@@ -99,6 +101,7 @@ class ClipPacker:
             self._pending[handle] += 1
             if len(self._buf) >= self.batch:
                 to_dispatch, self._buf = self._buf, []
+            trace.counter("packer.buffered", len(self._buf))
         if to_dispatch is not None:
             # a dispatch failure contains OUR newest clip: propagate so the
             # caller's extractor aborts this video now (members poisoned)
@@ -151,12 +154,16 @@ class ClipPacker:
                             # the group — flush it ragged (the only ragged
                             # dispatch in the system)
                             to_flush, self._buf = self._buf, []
+                            trace.counter("packer.ragged_flush",
+                                          len(to_flush))
+                            trace.counter("packer.buffered", 0)
                         else:
                             # other videos are still decoding; their adds
                             # will fill the buffer. The timeout guards the
                             # race where the last feeder transitions to
                             # closing between our check and the wait.
-                            self._cond.wait(timeout=0.05)
+                            with trace.span("packer.fill_wait"):
+                                self._cond.wait(timeout=0.05)
                             continue
                 if to_flush is not None:
                     try:
@@ -181,7 +188,8 @@ class ClipPacker:
                 f"on device: {err}") from err
         if n == 0:
             return np.empty((0,), np.float32)
-        return np.stack([rows[i] for i in range(n)])
+        with trace.span("batch.collect", rows=n):
+            return np.stack([rows[i] for i in range(n)])
 
     # -- internals ---------------------------------------------------------
 
@@ -190,20 +198,26 @@ class ClipPacker:
         copy of a B=128 group is tens of MB — holding the lock there would
         stall every decode thread). The dispatch lock keeps the inflight
         order consistent with dispatch order."""
-        with self._dispatch_lock:
+        with trace.span("packer.lock_wait", lock="dispatch"):
+            self._dispatch_lock.acquire()
+        try:
             manifest = [(h, idx) for h, idx, _ in items]
             try:
                 # np.stack inside the try: a shape mismatch or MemoryError
                 # here has already consumed the clips from _buf, so it must
                 # poison the members exactly like a device failure
-                group = np.stack([s for _, _, s in items])
+                with trace.span("packer.stack", rows=len(items)):
+                    group = np.stack([s for _, _, s in items])
                 dev = self.runner.dispatch(group)
+                seq = getattr(self.runner, "last_seq", None)
             except Exception as e:
                 self._poison(manifest, e)
                 raise
             with self._lock:
-                self._inflight.append((dev, manifest))
+                self._inflight.append((dev, manifest, seq))
                 self._cond.notify_all()
+        finally:
+            self._dispatch_lock.release()
 
     def _poison(self, manifest, exc: Exception) -> None:
         """A group died on device: release its members' pending counts and
@@ -221,11 +235,15 @@ class ClipPacker:
         rows to their videos. D2H happens outside the main lock so decode
         threads keep feeding; the drain lock keeps materialization
         submit-ordered."""
-        with self._drain_lock:
+        # another worker may be inside the blocking D2H below: standing at
+        # this lock is a stall on the device like its `forward`, one removed
+        with trace.span("packer.lock_wait", lock="drain"):
+            self._drain_lock.acquire()
+        try:
             with self._lock:
                 if not self._inflight:
                     return
-                dev, manifest = self._inflight.popleft()
+                dev, manifest, seq = self._inflight.popleft()
             # ANY failure after the pop (the blocking D2H is the expected
             # one, but also e.g. a routing bug below) must poison the
             # members — once the group left _inflight, nobody else can
@@ -238,9 +256,11 @@ class ClipPacker:
                 # which is what the per-stage roofline breakdown
                 # (trace_report / bench_pipeline) needs attributed —
                 # without it a packed run's device time is invisible
-                with profiler.stage("forward"):
+                with profiler.stage("forward"), \
+                        trace.span("mesh.fetch", seq=seq):
                     host = np.asarray(dev)  # blocking D2H
-                with self._lock:
+                with trace.span("packer.route", rows=len(manifest)), \
+                        self._lock:
                     for row, (h, idx) in enumerate(manifest):
                         if h in self._results:
                             self._results[h][idx] = host[row]
@@ -249,3 +269,5 @@ class ClipPacker:
             except Exception as e:
                 self._poison(manifest, e)
                 raise
+        finally:
+            self._drain_lock.release()

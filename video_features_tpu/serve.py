@@ -524,8 +524,11 @@ class ServeLoop:
             return False
         payload = {"schema": RESPONSE_SCHEMA, "id": rid,
                    "time": round(time.time(), 3), **payload}
-        jsonl.write_json_atomic(
-            os.path.join(self.paths[DONE_DIR], f"{rid}.json"), payload)
+        from .telemetry import trace
+        from .telemetry.context import use_request
+        with use_request(rid), trace.span("serve.respond"):
+            jsonl.write_json_atomic(
+                os.path.join(self.paths[DONE_DIR], f"{rid}.json"), payload)
         return True
 
     def _expire(self, rid: str, req: dict, claimed_path: str,
@@ -588,10 +591,14 @@ class ServeLoop:
         from .telemetry import trace
         rid = os.path.basename(claimed_path)[:-len(".json")]
         t0 = time.perf_counter()
+        from .telemetry.context import use_request
         from .telemetry.recorder import _mon_snapshot, compile_cache_summary
         mon_before = _mon_snapshot()
         try:
-            with open(claimed_path, encoding="utf-8") as f:
+            # the worker's half of the claim: reading the request it was
+            # handed (the rename is the loop thread's half, _claim_next)
+            with use_request(rid), trace.span("serve.claim", part="read"), \
+                    open(claimed_path, encoding="utf-8") as f:
                 req = json.load(f)
             videos = [str(v) for v in req.get("video_paths") or []]
         except (OSError, ValueError) as e:
@@ -613,7 +620,6 @@ class ServeLoop:
             return
         statuses: Dict[str, Dict[str, str]] = {}
         expired = False
-        from .telemetry.context import use_request
         with self._state_lock:
             self._inflight_rids.add(rid)
         try:
@@ -698,7 +704,10 @@ class ServeLoop:
     def _claim_next(self) -> Optional[str]:
         """Claim the oldest pending request by atomic rename; None when
         the spool is empty (or every candidate was raced away)."""
+        from .telemetry import trace
+        from .telemetry.context import use_request
         req_dir = self.paths[REQUESTS_DIR]
+        t0 = time.perf_counter()
         try:
             names = [n for n in os.listdir(req_dir) if n.endswith(".json")]
         except OSError:
@@ -709,15 +718,21 @@ class ServeLoop:
                 key=lambda n: self._mtime(os.path.join(req_dir, n))):
             src = os.path.join(req_dir, name)
             dst = os.path.join(self.claim_dir, name)
+            rid = name[:-len(".json")]
             try:
                 # chaos hook (utils/inject.py `spool.claim`): a failed
                 # claim rename looks exactly like a lost race — the
                 # request stays spooled for the next pass/server
-                inject.fire("spool.claim", request=name[:-len(".json")])
+                inject.fire("spool.claim", request=rid)
                 os.rename(src, dst)
-                return dst
             except OSError:
                 continue  # another server (or a withdrawal) won the race
+            # one event per claim that succeeded, from the listing on: an
+            # empty poll leaves nothing on the timeline
+            with use_request(rid):
+                trace.complete("serve.claim", t0, time.perf_counter() - t0,
+                               part="rename", listed=len(names))
+            return dst
         return None
 
     def _reclaim_orphans(self) -> int:

@@ -17,9 +17,10 @@ artifact reads it back with :func:`current_request_id`:
                                   ``feature_health.schema.json``)
   ``_failures.jsonl`` record      ``request_id`` field (utils/faults.py,
                                   only when a request is in scope)
-  ``_trace.json`` span            ``request`` arg on ``video_attempt``
-                                  (utils/sinks.py) and the
-                                  ``serve.request`` umbrella (serve.py)
+  ``_trace.json`` span            ``rid`` on EVERY complete event, the
+                                  decode-ahead thread's included
+                                  (telemetry/trace.py; the ``request``
+                                  arg on ``video_attempt`` stays)
   fleet-queue lease               ``request_id`` stamp on the claim
                                   record (parallel/queue.py)
   ``done/{id}.json`` response     the id IS the filename (serve.py)
@@ -37,9 +38,9 @@ cost class as :func:`~.spans.current_span`.
 
 Propagation is thread-local on purpose: one request's videos run
 sequentially on the serve worker thread that claimed it (serve.py
-``_process``), and decode-ahead producer threads already re-install the
-consumer's span (``use_span``) — stage observations from unpropagated
-threads were never attributed per-video, and the same holds per-request.
+``_process``), and decode-ahead producer threads re-install the
+consumer's span (``use_span``) and its request (``use_request``,
+utils/io.py ``Prefetcher``).
 """
 from __future__ import annotations
 

@@ -447,6 +447,7 @@ class VideoSource:
         batched ``__iter__`` path — the two views must agree or per-frame
         resize/crop would silently be skipped for one of them.
         """
+        from ..telemetry import trace as _trace
         from .profiling import profiler
         if getattr(self, "_tmp_deleted", False):
             # cv2 on a missing path fails SILENTLY (read() -> None): a
@@ -470,13 +471,19 @@ class VideoSource:
             raise
         tf = self.transform
 
+        # `decode` stays the stage every reader knows; its children say
+        # which part of it: the cv2 read, the grab()-skip, the transform
         def emit(rgb, out_idx):
             with profiler.stage("decode"):
-                x = tf(rgb) if tf is not None else rgb
+                if tf is None:
+                    x = rgb
+                else:
+                    with _trace.span("decode.transform"):
+                        x = tf(rgb)
             return x, out_idx / self.fps * 1000.0, out_idx
 
         def timed_read():
-            with profiler.stage("decode"):
+            with profiler.stage("decode"), _trace.span("decode.read"):
                 return stream.read()
 
         try:
@@ -503,7 +510,8 @@ class VideoSource:
                             # this source frame is dropped by the fps
                             # filter: grab()-skip it (no conversion/copy,
                             # see _FrameStream.skip)
-                            with profiler.stage("decode"):
+                            with profiler.stage("decode"), \
+                                    _trace.span("decode.skip"):
                                 ok = stream.skip()
                             nxt = True if ok else None
                         else:
@@ -998,8 +1006,14 @@ class Prefetcher:
         # capture the constructing thread's telemetry span (if any): the
         # producer thread re-installs it so its decode-stage timings still
         # attribute to the right video's span (telemetry/spans.py)
-        from ..telemetry import current_span
+        from ..telemetry import current_request_id, current_span
+        from ..telemetry import trace as _trace
         self._span = current_span()
+        # likewise the request in scope and the consumer's open trace span:
+        # the producer's spans carry the request's id and hang under the
+        # span they were started for (one tree per request, across threads)
+        self._rid = current_request_id()
+        self._trace_parent = _trace.current_span_id()
 
     def __iter__(self):
         import queue as _queue
@@ -1021,24 +1035,23 @@ class Prefetcher:
 
         def produce():
             from ..telemetry import trace as _trace
-            from ..telemetry import use_span
+            from ..telemetry import use_request, use_span
             try:
-                with use_span(self._span):
+                with use_span(self._span), use_request(self._rid), \
+                        _trace.adopt(self._trace_parent):
                     it = iter(self.iterable)
                     while True:
                         # tracing (no-op when off): `prefetch.next` spans
                         # bracket this thread's decode+transform of one
-                        # batch; a blocked put means the CONSUMER fell
-                        # behind (device-bound), the dual of starved-get
-                        tr = _trace.active()
-                        t0 = _time.perf_counter() if tr is not None else 0.0
+                        # batch (its `decode` stages are their children);
+                        # a blocked put means the CONSUMER fell behind
+                        # (device-bound), the dual of starved-get
                         try:
-                            item = next(it)
+                            with _trace.span("prefetch.next"):
+                                item = next(it)
                         except StopIteration:
                             break
-                        if tr is not None:
-                            tr.complete("prefetch.next", t0,
-                                        _time.perf_counter() - t0)
+                        tr = _trace.active()
                         t1 = _time.perf_counter()
                         if not put_until_stopped(item):
                             return
@@ -1054,9 +1067,13 @@ class Prefetcher:
         t = threading.Thread(target=produce, name="vft-prefetch",
                              daemon=True)
         t.start()
+        from ..telemetry import trace as _trace
         try:
             while True:
-                item = q.get()
+                # the consumer's wait for the decode-ahead thread: where a
+                # worker stands still when the HOST is the slow side
+                with _trace.span("prefetch.get_wait"):
+                    item = q.get()
                 if item is self._DONE:
                     break
                 if isinstance(item, BaseException):

@@ -29,8 +29,71 @@ from __future__ import annotations
 
 import time
 from collections import defaultdict
-from contextlib import contextmanager
-from typing import Callable, Dict, Iterator, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
+
+
+class _NoopStage:
+    """What :meth:`StageProfiler.stage` returns when nothing listens: one
+    shared, state-free ``with`` (no generator, no clock read)."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+
+NOOP_STAGE = _NoopStage()
+
+
+class _Stage:
+    """One armed ``with profiler.stage(name)``: listeners are read at entry
+    (one installed mid-stage does not see a stage it did not see start)."""
+
+    __slots__ = ("_p", "_name", "_t0", "_hook", "_trace_hook", "_span")
+
+    def __init__(self, p: "StageProfiler", name: str) -> None:
+        self._p = p
+        self._name = name
+
+    def __enter__(self) -> None:
+        p = self._p
+        self._hook = p._hook
+        self._trace_hook = p._trace_hook
+        tracer = p._tracer
+        # the timeline's span opens first and closes last, so that spans
+        # opened inside the stage (decode.read under decode) are its children
+        self._span = tracer(self._name) if tracer is not None else None
+        if self._span is not None:
+            self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return None
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        p, name, t0 = self._p, self._name, self._t0
+        dt = time.perf_counter() - t0
+        if self._span is not None:
+            try:
+                self._span.__exit__(exc_type, exc, tb)
+            except Exception:
+                pass  # observability must never fail the pipeline
+        if p.enabled:
+            with p._lock:
+                p._times[name] += dt
+                p._counts[name] += 1
+        if self._hook is not None:
+            try:
+                self._hook(name, dt)
+            except Exception:
+                pass
+        if self._trace_hook is not None:
+            try:
+                self._trace_hook(name, t0, dt)
+            except Exception:
+                pass
+        return False
 
 
 class StageProfiler:
@@ -45,6 +108,7 @@ class StageProfiler:
         self._hook: Optional[Callable[[str, float], None]] = None
         self._trace_hook: Optional[Callable[[str, float, float],
                                             None]] = None
+        self._tracer: Optional[Callable[[str], Any]] = None
 
     def set_hook(self, hook: Optional[Callable[[str, float], None]]) -> None:
         """Install (or clear, with None) a per-observation callback
@@ -54,38 +118,30 @@ class StageProfiler:
 
     def set_trace_hook(self, hook: Optional[Callable[[str, float, float],
                                                      None]]) -> None:
-        """Install (or clear) ``hook(stage_name, t0_perf, seconds)`` —
-        the trace recorder's feed (telemetry/trace.py). Unlike the
-        aggregate hook it receives the START time too, so each stage
-        call becomes one complete timeline event."""
+        """Install (or clear) ``hook(stage_name, t0_perf, seconds)``: a
+        listener to the stage timeline that wants the START time too (the
+        benchmark harness's feed). It is a slot of its own: the trace
+        recorder (telemetry/trace.py) feeds through :meth:`set_span_tracer`,
+        so the two never unhook each other. A listener is also a
+        subscription to the program's whole span tree: while one is
+        installed and no ``trace=true`` recorder runs, telemetry/trace.py
+        records to memory (``trace.last_recording()`` hands it over)."""
         self._trace_hook = hook
+        if self is profiler:  # the process's timeline, not a private timer
+            from ..telemetry import trace
+            trace.follow_stage_listener(hook is not None)
 
-    @contextmanager
-    def stage(self, name: str) -> Iterator[None]:
-        hook = self._hook
-        trace_hook = self._trace_hook
-        if not self.enabled and hook is None and trace_hook is None:
-            yield
-            return
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            if self.enabled:
-                with self._lock:
-                    self._times[name] += dt
-                    self._counts[name] += 1
-            if hook is not None:
-                try:
-                    hook(name, dt)
-                except Exception:
-                    pass  # observability must never fail the pipeline
-            if trace_hook is not None:
-                try:
-                    trace_hook(name, t0, dt)
-                except Exception:
-                    pass
+    def set_span_tracer(self, tracer: Optional[Callable[[str], Any]]) -> None:
+        """Install (or clear) ``tracer(stage_name) -> context manager``:
+        the trace recorder opens one span per stage call through it, so a
+        stage is a node of the span tree (parent of what runs inside it)."""
+        self._tracer = tracer
+
+    def stage(self, name: str):
+        if not self.enabled and self._hook is None \
+                and self._trace_hook is None and self._tracer is None:
+            return NOOP_STAGE
+        return _Stage(self, name)
 
     def add(self, name: str, dt: float, n: int = 1) -> None:
         """Accumulate an externally-timed observation (the telemetry
@@ -141,7 +197,14 @@ profiler = StageProfiler()
 
 
 class TraceCapture:
-    """``jax.profiler`` trace over a region, no-op when dir is None."""
+    """``jax.profiler`` trace over a region, no-op when dir is None.
+
+    Only the device is traced. With the profiler's default options the
+    host and Python tracers run too, and on the v5e the runtime's own
+    threads then write ~2 M events a second (590 MB for eight seconds of
+    RAFT) while the device sits idle 21-26% where it is idle 0.3% untraced
+    (PERF.md section 6, PR 22): such a trace measures the tracer. The host
+    side of the timeline is ``trace=true`` (telemetry/trace.py)."""
 
     def __init__(self, trace_dir: Optional[str]) -> None:
         self.trace_dir = trace_dir
@@ -150,7 +213,12 @@ class TraceCapture:
     def __enter__(self):
         if self.trace_dir:
             import jax
-            jax.profiler.start_trace(self.trace_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 0
+            options.enable_hlo_proto = False
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=options)
             self._active = True
         return self
 

@@ -6,6 +6,7 @@ same op, which is itself golden-tested against the torch reference
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from video_features_tpu.kernels.cost_volume import cost_volume_xla
@@ -122,6 +123,95 @@ def test_corr_lookup_packed_degenerate_pyramid(rng):
     ref = np.asarray(corr_lookup_gather(pyramid, coords))
     ours = np.asarray(corr_lookup_packed(packed, metas, coords,
                                          interpret=True))
+    np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
+
+
+def _pooled_volume_pyramid(f1, f2, levels=4):
+    """The independent reference: the all-pairs volume, then torch
+    avg_pool2d(2, stride=2) over the volume itself (corr.py:13-27) — what
+    build_corr_pyramid did before its levels became correlations against
+    the pooled second feature map."""
+    b, h, w, c = f1.shape
+    corr = jnp.einsum("bpc,bqc->bpq", f1.reshape(b, h * w, c),
+                      f2.reshape(b, h * w, c),
+                      preferred_element_type=jnp.float32) / np.sqrt(c)
+    corr = corr.reshape(b, h * w, h, w)
+    pyramid = [corr]
+    for _ in range(levels - 1):
+        hl, wl = corr.shape[2] // 2 * 2, corr.shape[3] // 2 * 2
+        corr = jax.lax.reduce_window(
+            corr[:, :, :hl, :wl], 0.0, jax.lax.add, (1, 1, 2, 2),
+            (1, 1, 2, 2), [(0, 0)] * 4) / 4.0
+        pyramid.append(corr)
+    return pyramid
+
+
+#: /8 geometries the system produces (240x320, i3d's 224, 128x160, Sintel's
+#: 440x1024) and one whose odd sizes drop a row and a column and pool to a
+#: 0-sized level; channels 256 and 64 have a power-of-two root (f1 is
+#: scaled), 48 has not (the result is)
+PYRAMID_CASES = [(30, 40, 256), (28, 28, 256), (16, 20, 48), (55, 128, 64),
+                 (7, 9, 256)]
+
+
+@pytest.fixture(scope="module",
+                params=[(g, d) for g in PYRAMID_CASES
+                        for d in ("float32", "bfloat16")],
+                ids=lambda p: f"{p[0][0]}x{p[0][1]}x{p[0][2]}-{p[1]}")
+def both_pyramids(request):
+    """(build_corr_pyramid's, the pooled volume's, max|corr|), built once a
+    case for the three tests that read them."""
+    (h8, w8, c), dtype = request.param
+    rng = np.random.default_rng(h8 * 1000 + w8)
+    f1, f2 = (jnp.asarray(rng.normal(size=(1, h8, w8, c)).astype(np.float32)
+                          ).astype(dtype) for _ in range(2))
+    want = _pooled_volume_pyramid(f1, f2)
+    return build_corr_pyramid(f1, f2), want, float(jnp.max(jnp.abs(want[0])))
+
+
+def test_corr_pyramid_equals_the_pooled_volume(both_pyramids):
+    """pool(<f1[p], f2[q]>) = <f1[p], pool(f2)[q']>: every level within
+    1e-6 of max|corr| of the volume pooled directly, float32 and bfloat16
+    features (a pooled f2 rounded to bf16 would be 1e-3 off), same shapes
+    under the floor rule, the 0-sized level included."""
+    got, want, top = both_pyramids
+    assert [g.shape for g in got] == [w.shape for w in want]
+    assert all(g.dtype == jnp.float32 for g in got)
+    for lvl, (g, w) in enumerate(zip(got, want)):
+        if w.size:
+            assert float(jnp.max(jnp.abs(g - w))) <= 1e-6 * top, lvl
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+
+
+def test_stacked_plane_of_the_pyramid_has_exact_zero_pads(both_pyramids):
+    """The plane the fused lookup reads: equal to the pooled volume's, and
+    exactly zero in every pad cell (the reference's out-of-range rule)."""
+    from video_features_tpu.kernels.corr_lookup import stack_aligned_pyramid
+    got, want, top = both_pyramids
+    plane, metas = stack_aligned_pyramid(got)
+    want_plane, want_metas = stack_aligned_pyramid(want)
+    assert metas == want_metas and plane.shape == want_plane.shape
+    assert float(jnp.max(jnp.abs(plane - want_plane))) <= 1e-6 * top
+    cells, _ = stack_aligned_pyramid([jnp.ones_like(w) for w in want])
+    pads = np.asarray(cells) == 0
+    assert pads.any() and not np.asarray(plane)[pads].any()
+
+
+def test_corr_lookup_proj_on_the_pyramid_matches_the_pooled_volume(
+        both_pyramids, rng):
+    """The fused kernel (interpreter) over the new pyramid's plane against
+    the XLA composition over the pooled volume."""
+    from video_features_tpu.kernels.corr_lookup import (
+        corr_lookup_proj, corr_lookup_proj_ref, stack_aligned_pyramid)
+    got, want, _ = both_pyramids
+    h8, w8 = want[0].shape[2:]
+    coords = jnp.asarray(rng.uniform(-6.0, max(h8, w8) + 6.0,
+                                     size=(1, h8, w8, 2)).astype(np.float32))
+    wgt, bias = _proj_weight(rng)
+    stacked, metas = stack_aligned_pyramid(got)
+    ref = np.asarray(corr_lookup_proj_ref(want, coords, wgt, bias))
+    ours = np.asarray(corr_lookup_proj(stacked, metas, coords, wgt, bias,
+                                       interpret=True))
     np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
 
 

@@ -518,6 +518,23 @@ def test_no_operation_of_a_served_program_is_left_without_a_stage(lowered,
     assert not bare, bare[:10]
 
 
+def test_corr_pyramid_pools_feature_maps_and_never_the_volume(lowered):
+    """The pyramid's levels are correlations against the pooled second
+    feature map: the stage's only reduce-windows are the three 2x2 pools of
+    a (B, Hl, Wl, 256) map, and none has an output with a dimension of
+    P = H/8 * W/8 queries (pooling the all-pairs volume was a third of the
+    RAFT step on the chip)."""
+    _, compiled = lowered("raft")
+    queries = (64 // 8) * (96 // 8)  # _runner("raft")'s geometry
+    pools = [tuple(int(d) for d in m.group(1).split(","))
+             for m in re.finditer(
+                 r"= f32\[([\d,]*)\]\S* reduce-window\([^\n]*"
+                 r'op_name="[^"]*RAFT/corr_pyramid/', compiled)]
+    assert len(pools) == 3, pools
+    for shape in pools:
+        assert queries not in shape and shape[-1] == 256, shape
+
+
 @pytest.mark.parametrize("kernel, name", [
     ("corr_lookup_pallas", "corr_lookup_level"),
     ("corr_lookup_proj", "corr_lookup_proj"),
@@ -554,8 +571,10 @@ def test_pallas_kernels_carry_their_name(kernel, name):
 
 # -- scopes are metadata only -------------------------------------------------
 
-#: sha256 of the parent's (1ceebd0) optimized CPU program for `_runner`'s
-#: configuration, metadata and module name stripped, recorded under this jax
+#: sha256 of the optimized CPU program for `_runner`'s configuration, metadata
+#: and module name stripped, recorded under this jax: r21d's from 1ceebd0
+#: (no PR since has touched that program), raft's from PR 25, which changed
+#: the pyramid's operations by design (the asset says so)
 PARENT_HLO = json.loads((REPO / "tests" / "assets" /
                          "hlo_parent_pr24.json").read_text())
 
@@ -603,7 +622,8 @@ def test_optimized_hlo_is_the_parent_s_but_for_names(lowered, family,
         hashlib.sha256(theirs.encode()).hexdigest()
     recorded = PARENT_HLO[family]
     if recorded["jax"] == jax.__version__:
-        # the text the builder recorded from the parent commit itself
+        # the text a builder recorded from a commit itself (the asset
+        # says which)
         assert len(ours) == recorded["chars"]
         assert hashlib.sha256(ours.encode()).hexdigest() == \
             recorded["sha256"]

@@ -334,7 +334,16 @@ def stack_aligned_pyramid(pyramid: Sequence[jnp.ndarray]
     128-multiple — the zeros ARE the reference's out-of-range rule, see
     :func:`align_level`), pad all levels to the widest lane width, and
     concatenate along sublanes into ONE (B, P, Hsum, Wp) plane. Hoist this
-    OUT of the GRU scan (loop-invariant)."""
+    OUT of the GRU scan (loop-invariant).
+
+    On a v5e these pads and the concatenate are one fusion that writes the
+    plane in the layout the kernel reads, 0.12 ms a pair at 240x320 — over
+    half of what is left of RAFT's pyramid stage since the levels come from
+    pooled feature maps (models/raft.py build_corr_pyramid). Emitting the
+    plane from ONE dot against a zero-padded stack of the pooled maps was
+    measured and not kept (PR 25): the MXU's result has the query in its
+    rows, so the dot writes (B, h, P, w) and an unnamed 39 MB-a-pair copy
+    re-tiles it — 0.29 ms a pair against this form's 0.22."""
     aligned = [align_level(c) for c in pyramid]
     wp = max(c.shape[3] for c in aligned)
     aligned = [c if c.shape[3] == wp else

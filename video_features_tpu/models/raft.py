@@ -10,7 +10,11 @@ test_mode — raft.py:118-177):
     statistics even at eval (torch InstanceNorm2d defaults), so they are
     pure functions here.
   - All-pairs correlation ``corr = <f1, f2> / sqrt(256)`` -> 4-level
-    avg-pooled pyramid (corr.py:13-27).
+    avg-pooled pyramid (corr.py:13-27). Same values, other order: each
+    level is the correlation of ``f1`` against the 2x2-mean-pooled ``f2``
+    (pooling is linear in the target index; the reference's
+    AlternateCorrBlock, corr.py:63-91, pools ``fmap2`` too), so no pool
+    ever runs over the all-pairs volume (:func:`build_corr_pyramid`).
   - Per-iteration windowed lookup (radius 4 -> 81 taps/level, 324 channels)
     via bilinear sampling with zeros padding + align_corners=True semantics
     (corr.py:29-50, utils/utils.py:59-73). The reference enumerates window
@@ -26,7 +30,11 @@ test_mode — raft.py:118-177):
     but the last, raft.py:154-175 — same result, 19 fewer upsamples).
 
 Design notes (TPU): everything is fixed-shape; the correlation volume is the
-memory hot spot (B * (HW/64)^2 floats) exactly as in the reference; the
+memory hot spot (B * (HW/64)^2 floats) exactly as in the reference — and a
+layout hot spot: its minor dims are a level's (Hl, Wl), so every one of the
+B * HW/64 planes is at least one padded (8, 128) tile and anything that
+walks the volume pays per plane (pooling it for the pyramid measured a
+third of the step on a v5e; the pools run on the feature maps). The gather
 lookup is 4 ``take_along_axis`` gathers per corner which XLA lowers to
 dynamic-gather — no data-dependent shapes anywhere.
 
@@ -243,29 +251,68 @@ class MaskHead(nn.Module):
 
 # ---- correlation volume --------------------------------------------------
 
+def _pooled_fmaps(fmap2: jnp.ndarray, num_levels: int) -> List[jnp.ndarray]:
+    """The second feature map at every pyramid level, (B, Hl, Wl, C): level
+    0 as it came (bf16 in bf16 mode), every further level the float32 2x2
+    mean of the one before under torch avg_pool2d's floor rule (an odd
+    trailing row/col is dropped; a 1-wide level pools to a 0-sized one)."""
+    levels = [fmap2]
+    pooled = fmap2.astype(jnp.float32)
+    for _ in range(num_levels - 1):
+        hl, wl = pooled.shape[1] // 2 * 2, pooled.shape[2] // 2 * 2
+        pooled = jax.lax.reduce_window(
+            pooled[:, :hl, :wl], 0.0, jax.lax.add, (1, 2, 2, 1),
+            (1, 2, 2, 1), [(0, 0)] * 4) / 4.0
+        levels.append(pooled)
+    return levels
+
+
 def build_corr_pyramid(fmap1: jnp.ndarray, fmap2: jnp.ndarray,
                        num_levels: int = CORR_LEVELS) -> List[jnp.ndarray]:
-    """All-pairs correlation + avg-pool pyramid (corr.py:13-27, 52-60).
+    """All-pairs correlation pyramid (corr.py:13-27, 52-60).
 
-    fmaps: (B, H, W, C). Returns per level (B, H*W, Hl, Wl)."""
+    fmaps: (B, H, W, C). Returns per level float32 (B, H*W, Hl, Wl).
+
+    Every level is a correlation against the POOLED second feature map,
+    never a pool of the volume: average pooling is linear and acts on the
+    target index q only, so pool(<f1[p], f2[q]>) = <f1[p], pool(f2)[q']>
+    (the identity the reference's own AlternateCorrBlock uses,
+    corr.py:63-91). The pools then run over channel-minor feature maps,
+    0.6 MB a pair at 240x320. Pooling the (B, P, Hl, Wl) volume instead
+    cost a third of the RAFT step on a v5e: its minor dims (30, 40) ...
+    (3, 5) make every one of the B*P planes a padded (8, 128) tile, and
+    each reduce-window paid per plane, under 1% of its memory roofline."""
     b, h, w, c = fmap1.shape
     f1 = fmap1.reshape(b, h * w, c)
-    f2 = fmap2.reshape(b, h * w, c)
-    # f32 accumulation/output even from bf16 fmaps: the pyramid (and hence
-    # the lookup) keeps full precision in every mode; the MXU still takes
-    # bf16 inputs at native rate
-    corr = jnp.einsum("bpc,bqc->bpq", f1, f2,
-                      preferred_element_type=jnp.float32) / math.sqrt(c)
-    corr = corr.reshape(b, h * w, h, w)
-    pyramid = [corr]
-    for _ in range(num_levels - 1):
-        # torch avg_pool2d(2, stride=2): floor mode drops odd trailing row/col
-        hl, wl = corr.shape[2] // 2 * 2, corr.shape[3] // 2 * 2
-        corr = corr[:, :, :hl, :wl]
-        corr = jax.lax.reduce_window(
-            corr, 0.0, jax.lax.add, (1, 1, 2, 2), (1, 1, 2, 2),
-            [(0, 0)] * 4) / 4.0
-        pyramid.append(corr)
+    root = math.sqrt(c)
+    # a power of two (C = 256: 16) divides f1 exactly in every dtype, which
+    # saves the pass over the volume; any other root divides the float32
+    # result
+    scale_f1 = math.frexp(root)[0] == 0.5
+    if scale_f1:
+        f1 = f1 / jnp.asarray(root, f1.dtype)
+    pyramid = []
+    for lvl, f2 in enumerate(_pooled_fmaps(fmap2, num_levels)):
+        hl, wl = f2.shape[1:3]
+        # level 0: operands as they came, f32 accumulation/output even from
+        # bf16 fmaps — the pyramid (and hence the lookup) keeps full
+        # precision in every mode and the MXU still takes bf16 inputs at
+        # native rate. A pooled f2 is a float32 mean and has to reach the
+        # contraction unrounded: float32 operands under HIGHEST (the MXU's
+        # default pass would round it to bf16, 1e-3 of max|corr|)
+        pooled = lvl > 0
+        corr = jnp.einsum(
+            "bpc,bqc->bpq", f1.astype(jnp.float32) if pooled else f1,
+            f2.reshape(b, hl * wl, c), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST if pooled else None)
+        if not scale_f1:
+            corr = corr / root
+        # contract over the flattened target index and reshape after: the
+        # compiler then re-tiles the true-size volume to p-major and the
+        # pads of the lookup's plane write its final layout (a
+        # "bpc,bhwc->bphw" dot keeps p minor through the pads and pays a
+        # second pass over the 6.8x larger padded plane, measured on v5e)
+        pyramid.append(corr.reshape(b, h * w, hl, wl))
     return pyramid
 
 
@@ -542,7 +589,11 @@ class RAFT(nn.Module):
     conv stacks — encoders, motion encoder, GRU, flow/mask heads — in the
     MXU-native dtype while the precision-critical state stays f32: the corr
     pyramid (f32-accumulated einsum), the lookup, the iterated coords, norm
-    statistics, and the upsample softmax. Flow drift vs f32 is sub-0.1 px
+    statistics, and the upsample softmax. The pyramid's pooled levels are
+    float32 means of ``fmap2`` contracted under ``Precision.HIGHEST``
+    (build_corr_pyramid): level 0 is bit-identical to the pooled-volume
+    form's, levels 1-3 within 1e-7 of max|corr| (chip, PR 25) — the pools
+    moved, the precision did not. Flow drift vs f32 is sub-0.1 px
     (well under the I3D flow stream's ToUInt8 quantization step of ~0.16);
     the f32 default is bit-identical to before (every cast is a no-op).
 
@@ -586,7 +637,9 @@ class RAFT(nn.Module):
                 # does not hoist the pads out of the while body — unhoisted they
                 # ran 20x per forward and cost ~30% of the whole RAFT step
                 # (kernels/corr_lookup.py align_level; zero pads are exactly the
-                # reference's out-of-range zeros rule)
+                # reference's out-of-range zeros rule). What is hoisted is all
+                # of the stage now: four small dots, a re-tiling of each
+                # true-size level and these pads; the volume is never pooled
                 from ..kernels.corr_lookup import (align_level,
                                                    proj_lookup_supported,
                                                    stack_aligned_pyramid)

@@ -6,7 +6,9 @@
 Sets up the cell ``BENCHMARK.json`` names, warms up every shape its traffic
 uses, measures for ``--seconds`` seconds and prints one JSON object as the
 last line of its standard output: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, with ``--trace 1``, ``breakdown``. With
+``metrics``, ``device``, with ``--trace 1`` ``breakdown``, and last
+``compared`` (each number the reference check compared, with its limit;
+the same on the last lines of standard error). With
 ``--trace 0`` the metrics are the cell's end-to-end metrics, with ``--trace 1``
 its per-layer metrics. It exits non-zero and prints no such line when JAX
 finds no TPU, fewer chips than the cell asks for or a device the peaks table
@@ -30,7 +32,8 @@ BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(0, str(BENCH.parent))
 
-from vftbench import device, manifest, program, stats, tracing  # noqa: E402
+from vftbench import (device, manifest, program, stats, timeline,  # noqa: E402
+                      tracing)
 from vftbench.measurement import Measurement  # noqa: E402
 
 EXIT_NO_CHIP = 3
@@ -58,19 +61,41 @@ def process_started() -> float:
 
 def reference_check(cell: manifest.Cell, result: Dict[str, Any],
                     out_dir: Path) -> Dict[str, Any]:
-    """The served features of the fixed check video against the same model
-    at the configuration's ``reference_keys`` (float32 under matmul precision
-    "highest") on the same seeded weights, judged by
-    ``checks/<config>.py compare()``. Runs after the window: building the
-    float32 extractor changes JAX's global matmul precision."""
+    """The timed path's features of the fixed check input against a
+    reference, judged by ``checks/<config>.py compare()``. The reference is
+    ``references/<config>.py features(params, config, check_path)`` where
+    the configuration brings that file: the benchmark's own plain copy. It is
+    handed the parameter tree the window ran, in the type it was served in,
+    and the configuration, never the extractor: it re-derives the unrounded
+    weights and holds the tree against them (PERF.md section 8). Without the
+    file it is the program's own twin at the configuration's
+    ``reference_keys`` (float32) on the same seeded weights, built once the
+    timed extractor is dropped. Either runs under matmul precision "highest"
+    and after the window: building a float32 extractor changes JAX's global
+    matmul precision."""
     import jax
-    args = program.program_args(cell.config, out_dir / "run" / "reference",
-                                cell.config["reference_keys"])
+    extractor = result.pop("extractor")
+    key = str(extractor.feature_type)
+    features = cell.optional_config_function("references", "features")
     with jax.default_matmul_precision("highest"):
-        reference = program.build_extractor(args).extract(
-            result["check_video"])
+        if features is not None:
+            ran = f"references/{cell.config_name}.py, handed the timed " \
+                  "parameter tree"
+            params = extractor.runner.params
+            del extractor  # the reference gets no method of the program's
+            reference = features(params, cell.config, result["check_video"])
+        else:
+            ran = f"the program's twin at reference_keys " \
+                  f"{json.dumps(cell.config['reference_keys'])}"
+            del extractor  # its weights go before the twin's arrive
+            args = program.program_args(cell.config,
+                                        out_dir / "run" / "reference",
+                                        cell.config["reference_keys"])
+            reference = program.build_extractor(args).extract(
+                result["check_video"])
     compare = cell.config_function("checks", "compare")
-    return compare(result["check_feats"], reference, str(args.feature_type))
+    return {"reference": ran,
+            **compare(result["check_feats"], reference, key)}
 
 
 def metric_values(cell: manifest.Cell, entries: List[dict], m: Measurement
@@ -127,18 +152,19 @@ def main(argv: Optional[List[str]] = None, root: Path = manifest.ROOT) -> int:
         m: Measurement = result["measurement"]
         m.peaks = chip["peaks"]
         m.costs = cell.config_function("costs", "per_unit")(cell.config)
-        window = result["trace_window"]
-        if window is not None:
+        if m.trace_path is not None:
             # onto the trace's clock: seconds from its session's start
-            zero = window.zero_perf
+            zero = m.trace_zero_perf
+            uncertainty_s = m.trace_open_perf - zero
             m.trace = tracing.reduce_trace(
-                tracing.load_xplane(result["trace_path"]),
-                window.open_perf - zero, window.close_perf - zero,
+                tracing.load_xplane(m.trace_path),
+                m.trace_open_perf - zero, m.trace_close_perf - zero,
                 [(n, s - zero, d) for n, s, d in m.stage_spans],
-                window.uncertainty_s, chips=cell.chips)
+                uncertainty_s, chips=cell.chips)
             print(f"vftbench: traced {m.trace['window_s']:.3f} s; the two "
-                  f"clocks agree to within {window.uncertainty_s * 1e3:.1f} "
-                  "ms")
+                  f"clocks agree to within {uncertainty_s * 1e3:.1f} ms")
+            # once, for every reader that wants it; renames the breakdown
+            timeline.analysis(m)
         # read before the float32 twin below adds programs of its own
         m.memory_peak_bytes = device.memory_peak_bytes(chip["devices"])
         print(f"vftbench: memory peak {m.memory_peak_at_open_bytes / 1e9:.3f}"
@@ -159,7 +185,7 @@ def main(argv: Optional[List[str]] = None, root: Path = manifest.ROOT) -> int:
     parts = {
         "every request answered done with sound artifacts":
             not result["failed"] and result["attempted"] > 0,
-        "agrees with float32 on the check video": bool(verdict["ok"]),
+        "agrees with the reference on the check input": bool(verdict["ok"]),
         "no program compiled or loaded inside the window":
             result["compiles_in_window"] == 0,
         "the generator kept time (lateness p90 <= 20 ms)":
@@ -167,7 +193,10 @@ def main(argv: Optional[List[str]] = None, root: Path = manifest.ROOT) -> int:
     }
     for line in result["failed"][:10]:
         print(f"vftbench: FAILED {line}")
-    print(f"vftbench: reference check: {json.dumps(verdict)}")
+    # which reference ran; its numbers are the line's ``compared``
+    print(f"vftbench: reference check against {verdict['reference']}: "
+          + ("agrees" if verdict["ok"] else
+             f"DISAGREES {verdict.get('why', '')}".rstrip()))
     if late_p90 is not None:
         print(f"vftbench: generator lateness p50 "
               f"{stats.median(result['lateness_s']) * 1e3:.3f} ms, p90 "
@@ -197,6 +226,12 @@ def main(argv: Optional[List[str]] = None, root: Path = manifest.ROOT) -> int:
         line["device"]["window_s"] = m.trace["window_s"]
         line["breakdown"] = {"device_ops": m.trace["device_ops"],
                              "idle_gaps": m.trace["idle_gaps"]}
+    # every number the reference check compared, beside its limit: last in
+    # the line and on the last lines of standard error, which is what the
+    # driver keeps of a run that is not correct
+    compared = {name: {"value": verdict.get(name), "limit": limit}
+                for name, limit in verdict.get("bands", {}).items()}
+    line["compared"] = compared
     details = {"cell": cell.name, "seed": opts.seed, "seconds": opts.seconds,
                "trace": opts.trace, "verdict_parts": parts,
                "reference_check": verdict, "window_s": m.window_s,
@@ -213,6 +248,9 @@ def main(argv: Optional[List[str]] = None, root: Path = manifest.ROOT) -> int:
           f"set-up {m.setup_s:.3f} s")
     sys.stdout.flush()
     print(json.dumps(line))
+    for name, pair in compared.items():
+        print(f"vftbench: compared {name}: {pair['value']} (limit "
+              f"{pair['limit']})", file=sys.stderr)
     return 0
 
 

@@ -1,5 +1,9 @@
-"""Seeded video corpora: a plan (which video has how many frames) and the
-files, synthesised with ``cv2.VideoWriter`` and kept in the checkout.
+"""Seeded corpora: a plan (which file has how many frames) and the files,
+written by the corpus's kind (``benchmark/corpora/<kind>.py``, which
+``manifest.Cell.corpus_kind`` loads; ``video`` where the block names none)
+and kept in the checkout. A "frame" is the item the kind counts (a video
+frame, an audio sample, a token), ``fps`` its rate, and a unit's ``window``
+and ``stride`` are in those items.
 
 The plan is a fixed amount of work: the durations are the mid-quantiles of
 the mix's distribution, the same multiset for every seed, and the seed
@@ -16,6 +20,7 @@ import zlib
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 from statistics import NormalDist
+from types import ModuleType
 from typing import Any, Dict, List
 
 import numpy as np
@@ -63,54 +68,7 @@ def frames_for(units: int, unit: Dict[str, Any]) -> int:
     return int(unit["window"]) + (int(units) - 1) * int(unit["stride"])
 
 
-# -- synthesis ----------------------------------------------------------------
-
-def _texture(rng: np.random.Generator, h: int, w: int) -> np.ndarray:
-    """Smooth colour texture with detail at two scales, ``(h, w, 3)`` uint8."""
-    import cv2
-    coarse = rng.integers(0, 256, (h // 24 + 2, w // 24 + 2, 3), np.uint8)
-    fine = rng.integers(0, 256, (h // 4 + 2, w // 4 + 2, 3), np.uint8)
-    a = cv2.resize(coarse, (w, h), interpolation=cv2.INTER_CUBIC)
-    b = cv2.resize(fine, (w, h), interpolation=cv2.INTER_CUBIC)
-    return cv2.addWeighted(a, 0.65, b, 0.35, 0.0)
-
-
-def write_video(path: Path, frames: int, spec: Dict[str, Any],
-                rng: np.random.Generator) -> None:
-    """One video of ``frames`` frames: a textured background that drifts, a
-    textured patch that crosses it on its own path, and fresh noise on every
-    frame, so that decoding is not trivial and the flow is not zero."""
-    import cv2
-    w, h, fps = int(spec["width"]), int(spec["height"]), float(spec["fps"])
-    margin = 48
-    bg = _texture(rng, h + 2 * margin, w + 2 * margin)
-    ph, pw = h // 3, w // 4
-    patch = _texture(rng, ph, pw)
-    noise = rng.integers(0, 13, (8, h, w, 3), np.uint8)
-    phase = rng.uniform(0, 2 * np.pi, 4)
-    speed = rng.uniform(0.03, 0.09, 4)
-    writer = cv2.VideoWriter(str(path), cv2.VideoWriter_fourcc(
-        *str(spec.get("codec", "mp4v"))), fps, (w, h))
-    if not writer.isOpened():
-        raise RuntimeError(f"cv2 cannot open a {spec.get('codec', 'mp4v')} "
-                           f"writer for {path}")
-    try:
-        for t in range(frames):
-            ox = margin + int(round(0.9 * margin * np.sin(
-                speed[0] * t + phase[0])))
-            oy = margin + int(round(0.9 * margin * np.cos(
-                speed[1] * t + phase[1])))
-            frame = bg[oy:oy + h, ox:ox + w].copy()
-            px = int(round((w - pw) * (0.5 + 0.5 * np.sin(
-                speed[2] * t + phase[2]))))
-            py = int(round((h - ph) * (0.5 + 0.5 * np.cos(
-                speed[3] * t + phase[3]))))
-            frame[py:py + ph, px:px + pw] = patch
-            cv2.add(frame, noise[int(rng.integers(0, 8))], dst=frame)
-            writer.write(frame)
-    finally:
-        writer.release()
-
+# -- files --------------------------------------------------------------------
 
 def _spec_key(spec: Dict[str, Any]) -> str:
     return hashlib.sha256(json.dumps(spec, sort_keys=True).encode()
@@ -118,7 +76,8 @@ def _spec_key(spec: Dict[str, Any]) -> str:
 
 
 def _materialize(final: Path, entries: List[Dict[str, Any]],
-                 spec: Dict[str, Any], seed: int, tag: str) -> Path:
+                 spec: Dict[str, Any], seed: int, tag: str,
+                 kind: ModuleType) -> Path:
     """Write ``entries`` under ``final`` unless a finished copy is there.
     Built in a sibling directory and renamed, so a killed run leaves nothing
     that looks finished."""
@@ -132,8 +91,9 @@ def _materialize(final: Path, entries: List[Dict[str, Any]],
     building.mkdir(parents=True)
 
     def one(entry: Dict[str, Any]) -> None:
-        write_video(building / f"{entry['name']}.mp4", entry["frames"], spec,
-                    stream(seed, tag, entry["name"], entry["frames"]))
+        kind.write(building / f"{entry['name']}{kind.SUFFIX}",
+                   entry["frames"], spec,
+                   stream(seed, tag, entry["name"], entry["frames"]))
 
     with ThreadPoolExecutor(max_workers=min(8, os.cpu_count() or 1)) as pool:
         for result in pool.map(one, entries):
@@ -148,24 +108,30 @@ def corpus_dir(out_root: Path, spec: Dict[str, Any], seed: int) -> Path:
         f"{spec['owner']}-s{int(seed)}-{_spec_key(spec)}"
 
 
-def build(out_root: Path, spec: Dict[str, Any], seed: int
+def build(out_root: Path, spec: Dict[str, Any], seed: int, kind: ModuleType
           ) -> List[Dict[str, Any]]:
-    """The seeded corpus on disk: the plan with a ``path`` for every video."""
+    """The seeded corpus on disk: the plan with a ``path`` for every file."""
     entries = plan(spec, seed)
     root = _materialize(corpus_dir(out_root, spec, seed), entries, spec,
-                        seed, spec["owner"])
-    return [{**e, "path": str(root / f"{e['name']}.mp4")} for e in entries]
+                        seed, spec["owner"], kind)
+    return [{**e, "path": str(root / f"{e['name']}{kind.SUFFIX}")}
+            for e in entries]
 
 
-def build_fixed(out_root: Path, spec: Dict[str, Any], frame_counts: List[int]
-                ) -> Dict[int, str]:
-    """Seed-independent videos of the given lengths (warm-up and the check
-    video): the same files for every run of every seed."""
-    geometry = {k: spec[k] for k in ("width", "height", "fps")}
-    geometry["codec"] = spec.get("codec", "mp4v")
+def build_fixed(out_root: Path, spec: Dict[str, Any], frame_counts: List[int],
+                kind: ModuleType) -> Dict[int, str]:
+    """Seed-independent files of the given lengths (warm-up and the check
+    input): the same files for every run of every seed. What makes such a
+    file what it is are the kind's ``GEOMETRY`` keys (``None``: the block
+    has to give it) and, where the block names one, its ``kind``."""
+    geometry = {k: spec[k] if default is None else spec.get(k, default)
+                for k, default in kind.GEOMETRY.items()}
+    if "kind" in spec:
+        geometry["kind"] = spec["kind"]
     entries = [{"name": f"f{n:05d}", "frames": int(n)}
                for n in sorted(set(frame_counts))]
     key = _spec_key({**geometry, "frames": [e["frames"] for e in entries]})
     root = _materialize(Path(out_root) / "corpus" / f"fixed-{key}", entries,
-                        geometry, 0, "fixed")
-    return {e["frames"]: str(root / f"{e['name']}.mp4") for e in entries}
+                        geometry, 0, "fixed", kind)
+    return {e["frames"]: str(root / f"{e['name']}{kind.SUFFIX}")
+            for e in entries}

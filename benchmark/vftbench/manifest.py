@@ -4,6 +4,19 @@ The manifest is the only list: a cell is an entry of ``workloads``, a
 configuration an entry of ``configs`` whose ``file`` holds it as it is run,
 a traffic mix is ``benchmark/traffic/<name>.json``, and a per-layer metric
 is an entry of ``per_layer`` whose reader is ``benchmark/readers/<name>.py``.
+A configuration ``<config>`` has to bring ``costs/<config>.py`` (``per_unit``)
+and ``checks/<config>.py`` (``validate``, ``compare``). It may bring
+``inputs/<config>.py`` (``resident_batch(rng, shape, dtype)``: what a resident
+cell's groups hold; without it, seeded bytes) and ``references/<config>.py``
+(``features(params, config, check_path)``: the benchmark's plain reference,
+handed the parameter tree the window ran and the configuration, and
+``control(params, config, check_path)``, the same in the nearest lower
+precision, which ``compare()`` has to fail; without the file, the program's
+float32 twin at the configuration's ``reference_keys``). A corpus block may
+name a ``kind`` (absent: ``video``), whose writer is ``corpora/<kind>.py``
+(``SUFFIX``, ``GEOMETRY``, ``write(path, frames, spec, rng)``). Each of these
+is chosen by the presence of the file that carries the name, never by the
+name itself.
 A metric belongs to a cell when it has no ``workloads`` list or the list
 names the cell. An entry carries one ``moves``, so where cells with different
 end-to-end metrics want the same per-layer reading, a second entry named
@@ -15,7 +28,8 @@ import importlib.util
 import json
 import re
 from pathlib import Path
-from typing import Any, Callable, Dict, List
+from types import ModuleType
+from typing import Any, Callable, Dict, List, Optional
 
 #: the checkout: ``benchmark/vftbench/manifest.py`` -> two levels up
 ROOT = Path(__file__).resolve().parents[2]
@@ -59,16 +73,21 @@ def bench_dir(manifest: dict, root: Path) -> Path:
     return (Path(root) / manifest["command"][-1]).parent
 
 
-def load_function(path: Path, attr: str) -> Callable:
-    """``attr`` of the Python file at ``path``, loaded by path: the file's
-    name is the configuration's or the metric's exact name, dots and all."""
+def load_module(path: Path) -> ModuleType:
+    """The Python file at ``path``, loaded by path: the file's name is the
+    configuration's, the kind's or the metric's exact name, dots and all."""
     if not path.is_file():
         raise ManifestError(f"{path} does not exist")
     mod_name = "vftbench_file_" + re.sub(r"[^A-Za-z0-9]", "_", path.stem)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    fn = getattr(module, attr, None)
+    return module
+
+
+def load_function(path: Path, attr: str) -> Callable:
+    """``attr`` of the Python file at ``path``."""
+    fn = getattr(load_module(path), attr, None)
     if not callable(fn):
         raise ManifestError(f"{path} defines no function {attr}()")
     return fn
@@ -99,6 +118,27 @@ class Cell:
         """``benchmark/<kind>/<config>.py`` -> ``attr``."""
         return load_function(
             self.bench / kind / f"{self.config_name}.py", attr)
+
+    def optional_config_function(self, kind: str, attr: str
+                                 ) -> Optional[Callable]:
+        """``benchmark/<kind>/<config>.py`` -> ``attr`` where the file
+        exists, else ``None``: the caller then does what it always did. A
+        file that is there without the function is an error, not a
+        fallback."""
+        path = self.bench / kind / f"{self.config_name}.py"
+        return load_function(path, attr) if path.is_file() else None
+
+    def corpus_kind(self, block: Dict[str, Any]) -> ModuleType:
+        """``benchmark/corpora/<kind>.py`` of a ``corpus`` / ``check_video``
+        block; a block that names no ``kind`` holds videos."""
+        path = self.bench / "corpora" / f"{block.get('kind', 'video')}.py"
+        kind = load_module(path)
+        if not (isinstance(getattr(kind, "SUFFIX", None), str)
+                and isinstance(getattr(kind, "GEOMETRY", None), dict)
+                and callable(getattr(kind, "write", None))):
+            raise ManifestError(f"{path} has to define SUFFIX, GEOMETRY and "
+                                "write(path, frames, spec, rng)")
+        return kind
 
     def reader(self, metric: str) -> Callable:
         """``read`` of ``readers/<metric>.py``; a metric ``<tag>.<name>``
@@ -135,10 +175,15 @@ def check_manifest(manifest: dict, root: Path = ROOT) -> List[str]:
             cell = Cell(manifest, w["name"], root)
             for kind, attr in (("costs", "per_unit"), ("checks", "compare")):
                 cell.config_function(kind, attr)
+            cell.optional_config_function("inputs", "resident_batch")
+            for attr in ("features", "control"):
+                cell.optional_config_function("references", attr)
             if "driver" not in cell.traffic:
                 problems.append(f"{cell.traffic_name}: no driver")
             if cell.traffic["driver"] != "resident":
-                cell.corpus_spec()
+                cell.corpus_kind(cell.corpus_spec())
+            else:
+                cell.corpus_kind(cell.traffic["check_video"])
             reported = {m["name"] for m in cell.end_to_end}
             if "setup_s" not in reported or len(reported) < 2:
                 problems.append(f"{cell.name}: reports {sorted(reported)}; "

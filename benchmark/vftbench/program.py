@@ -3,7 +3,8 @@
 Everything the benchmark takes from ``video_features_tpu`` goes through this
 file: the config loader, the extractor registry, ``ServeLoop`` and the spool
 client, the extractor's runner (``dispatch``, ``bucket_batch_size``,
-``fixed_batch``), the global ``StageProfiler`` and the compile counters.
+``fixed_batch``), the global ``StageProfiler``, the in-memory
+``TraceRecorder`` and the compile counters.
 """
 from __future__ import annotations
 
@@ -133,3 +134,12 @@ def collect_stage_spans(spans: List[Tuple[str, float, float]]
     profiler.set_trace_hook(
         lambda name, t0, dt: spans.append((name, t0, dt)))
     return lambda: profiler.set_trace_hook(None)
+
+
+def start_recorder() -> Callable[[], None]:
+    """Start the program's span recorder in memory (``TraceRecorder(None)``:
+    nothing is written) for a traced run; the returned function closes it,
+    after which ``telemetry.trace.last_recording()`` hands its events
+    over."""
+    from video_features_tpu.telemetry.trace import TraceRecorder
+    return TraceRecorder(None).start().close

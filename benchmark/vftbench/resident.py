@@ -1,11 +1,12 @@
 """The model step with the host taken away: the extractor's own runner, fed
 device-resident batches of exactly the shape it dispatches for a full group.
 
-Synthetic by construction. The batches hold seeded random bytes, nothing is
-decoded, transferred or written inside the window, and the only host work is
-the runner's own per-dispatch code. Timing blocks of about a second end in
-one host read of the last output, which the device's in-order queue makes a
-fence for every dispatch before it.
+Synthetic by construction. The batches hold what
+``inputs/<config>.py resident_batch`` draws from the seed (without that file:
+random bytes), nothing is decoded, transferred or written inside the window,
+and the only host work is the runner's own per-dispatch code. Timing blocks
+of about a second end in one host read of the last output, which the
+device's in-order queue makes a fence for every dispatch before it.
 """
 from __future__ import annotations
 
@@ -39,6 +40,13 @@ def _blocks(runner, batches, per_block: int, m: Measurement, until: float
         m.completions.append((end, per_block * rows))
 
 
+def byte_batch(rng: np.random.Generator, shape: tuple, dtype) -> np.ndarray:
+    """What a resident group holds where the configuration brings no
+    ``inputs/<config>.py``: seeded bytes in the wire's type."""
+    return rng.integers(0, 256, shape, dtype=np.uint8).astype(dtype,
+                                                             copy=False)
+
+
 def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
         started: float) -> Dict[str, Any]:
     import jax
@@ -50,10 +58,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
     extractor = program.build_extractor(args)
     runner = extractor.runner
 
-    # one real video through the extractor shows what it puts on the wire
+    # one real input through the extractor shows what it puts on the wire
     (check_video,) = corpus.build_fixed(
         out_dir.parent, traffic["check_video"],
-        [corpus.frames_for(int(cell.config["check_units"]), unit)]).values()
+        [corpus.frames_for(int(cell.config["check_units"]), unit)],
+        cell.corpus_kind(traffic["check_video"])).values()
     seen: List[tuple] = []
     unwatch = program.watch_dispatch(runner, seen)
     try:
@@ -66,8 +75,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
           f"{os.cpu_count()}")
 
     rng = corpus.stream(seed, cell.traffic_name, "batches")
-    batches = [jax.device_put(rng.integers(0, 256, full, dtype=np.uint8)
-                              .astype(dtype, copy=False))
+    draw = cell.optional_config_function("inputs", "resident_batch") \
+        or byte_batch
+    batches = [jax.device_put(np.asarray(draw(rng, full, dtype), dtype))
                for _ in range(int(traffic["resident_batches"]))]
     for b in batches:  # compiles or loads the one program, twice over
         np.asarray(runner.dispatch(b))
@@ -78,6 +88,9 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
     per_dispatch = (time.perf_counter() - start) / len(batches)
     per_block = max(1, int(round(float(traffic["block_s"]) / per_dispatch)))
 
+    # a traced run records the program's own timeline of the window in
+    # memory: its spans around every dispatch (``mesh.pad``, ``mesh.enqueue``)
+    close_recorder = program.start_recorder() if trace else (lambda: None)
     m = Measurement()
     m.memory_peak_at_open_bytes = device.memory_peak_bytes(
         device.chips_of(cell.chips))
@@ -85,22 +98,32 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
     m.t0 = time.perf_counter()
     m.setup_s = m.t0 - started
     until = m.t0 + seconds
-    trace_path = window = None
     if trace:
         length = min(float(traffic["trace_s"]), 0.6 * seconds)
         _blocks(runner, batches, per_block, m, m.t0 + 0.3 * (seconds - length))
-        window = TraceWindow(out_dir / "trace")
+        # the profiler starts and stops between two blocks, inside the
+        # window, and no block runs meanwhile: those seconds are no part of
+        # the rate that was dispatched
+        paused = time.perf_counter()
+        window = TraceWindow(out_dir / "trace", m)
         shutil.rmtree(window.out_dir, ignore_errors=True)
         window.start()
+        m.paused_s += time.perf_counter() - paused
         _blocks(runner, batches, per_block, m, time.perf_counter() + length)
-        trace_path = window.stop()
+        paused = time.perf_counter()
+        window.stop()
+        m.paused_s += time.perf_counter() - paused
     _blocks(runner, batches, per_block, m, until)
     m.t1 = time.perf_counter()
     cpu_after = os.times()
     m.cpu_s = ((cpu_after.user + cpu_after.system)
                - (cpu_before.user + cpu_before.system))
+    close_recorder()
+    if trace:
+        print(f"vftbench: {window.took()}, between two blocks: "
+              f"{m.paused_s:.3f} s of the window's {m.window_s:.3f} s "
+              "dispatched nothing")
     return {"measurement": m, "attempted": len(m.dispatches), "failed": [],
             "compiles_in_window": program.compile_events() - compiles_before,
             "lateness_s": [], "check_video": check_video,
-            "check_feats": check_feats, "trace_path": trace_path,
-            "trace_window": window}
+            "check_feats": check_feats, "extractor": extractor}

@@ -32,9 +32,10 @@ POLL_S = 0.002
 class Session:
     """One ``ServeLoop`` over a fresh spool under ``run_dir``."""
 
-    def __init__(self, cell: Cell, run_dir: Path) -> None:
+    def __init__(self, cell: Cell, run_dir: Path, suffix: str) -> None:
         from video_features_tpu import serve
         self.serve = serve
+        self.suffix = suffix  # of a request's file: the corpus kind's
         self.run_dir = Path(run_dir)
         self.links = self.run_dir / "links"
         self.links.mkdir(parents=True)
@@ -51,7 +52,7 @@ class Session:
         self._thread.start()
 
     def _link(self, rid: str) -> str:
-        return str(self.links / f"{rid}.mp4")
+        return str(self.links / f"{rid}{self.suffix}")
 
     def submit(self, rid: str, video_path: str) -> None:
         """One request for one video under a stem of its own: the sink skips
@@ -128,14 +129,14 @@ def warm_up(session: Session, check_video: str) -> Dict[str, np.ndarray]:
     return feats
 
 
-def trace_at(window: TraceWindow, at: float, length: float) -> Path:
+def trace_at(window: TraceWindow, at: float, length: float) -> None:
     """Trace ``length`` seconds from ``at`` on; runs on a thread of its own
     so that the client loop keeps polling while the profiler starts and
     writes."""
     time.sleep(max(0.0, at - time.perf_counter()))
     window.start()
     time.sleep(length)
-    return window.stop()
+    window.stop()
 
 
 def offer_load(session: Session, m: Measurement, videos: List[dict],
@@ -222,17 +223,18 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
     shutil.rmtree(run_dir, ignore_errors=True)
     run_dir.mkdir(parents=True)
     spec = cell.corpus_spec()
+    kind = cell.corpus_kind(spec)
 
     def build_corpus():
-        return (corpus.build(out_dir.parent, spec, seed),
+        return (corpus.build(out_dir.parent, spec, seed, kind),
                 corpus.build_fixed(out_dir.parent, spec, [corpus.frames_for(
-                    int(cell.config["check_units"]), unit)]))
+                    int(cell.config["check_units"]), unit)], kind))
 
     # the corpus is encoded on threads of its own while the extractor is
     # built: neither waits for the other
     side = ThreadPoolExecutor(max_workers=1, thread_name_prefix="vftbench")
     making = side.submit(build_corpus)
-    session = Session(cell, run_dir)
+    session = Session(cell, run_dir, kind.SUFFIX)
     built, check = making.result()
     videos = [{**v, "units": corpus.units_of(v["frames"], unit)}
               for v in built]
@@ -256,9 +258,12 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
             target = int(round(float(traffic["outstanding_per_worker"])
                                * session.loop.workers))
         if trace:
+            # the program's span tree in memory, started here and not by
+            # the stage listener's subscription below
+            unhook.append(program.start_recorder())
             unhook.append(program.collect_stage_spans(m.stage_spans))
             unhook.append(program.watch_dispatch(session.runner, dispatches))
-        window = TraceWindow(out_dir / "trace") if trace else None
+        window = TraceWindow(out_dir / "trace", m) if trace else None
         traced: List[Any] = []  # the future of the traced sub-window
 
         def window_opens() -> None:
@@ -274,9 +279,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
 
         offered = offer_load(session, m, videos, order, due, target, ramp_s,
                              seconds, window_opens)
-        for undo in unhook:
+        for undo in reversed(unhook):
             undo()
-        trace_path = traced[0].result() if traced else None
+        if traced:
+            traced[0].result()  # raises what the tracing thread raised
+            print(f"vftbench: {window.took()}, on a thread of its own")
     except BaseException:
         session.loop.stop()
         raise
@@ -294,4 +301,4 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, out_dir: Path,
             "lateness_s": ([r["taken_up"] - r["due"] for r in requests]
                            if due is not None else []),
             "check_video": check_video, "check_feats": check_feats,
-            "trace_path": trace_path, "trace_window": window}
+            "extractor": session.loop.extractor}

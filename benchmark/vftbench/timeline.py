@@ -4,18 +4,16 @@ and CPU, the share of a request no span names, the program's scope of every
 device operation, a clock bracket tied by the work itself, and idle gaps
 attributed to the leaf span of the thread that next enqueued.
 
-How the events get here without an edit to the harness (a PR may only add
-files to the benchmark). In a ``--trace 1`` run the harness subscribes to the
-program's stage timeline (``program.collect_stage_spans`` ->
-``profiler.set_trace_hook``); since PR 24 that subscription also starts the
-program's recorder in memory, and ``trace.last_recording()`` hands the events
-over after the window. The profiler's file is found where ``run.py`` had it
-written (``benchmark_out/<cell>/trace``) and taken only if its device-busy
-seconds are ``m.trace["busy_s"]``. :func:`analysis` does all of it once for
-a :class:`~.measurement.Measurement`, renames ``m.trace``'s ``device_ops`` and
-``idle_gaps`` in place (the line's ``breakdown`` is built from them after the
-readers ran) and returns ``None`` on a program without the recorder: every
-new reader then finds nothing to read.
+How the events get here. In a ``--trace 1`` run both drivers start the
+program's recorder in memory (``program.start_recorder``) and
+``trace.last_recording()`` hands the events over after the window. The
+profiler's file and the readings of ``perf_counter`` around ``start_trace``
+are on the measurement (``tracing.TraceWindow``). ``run.py`` calls
+:func:`analysis` once after it has reduced the trace; it renames
+``m.trace``'s ``device_ops`` and ``idle_gaps`` in place (the line's
+``breakdown`` is built from them), keeps its result on the measurement for
+the readers, and returns ``None`` on a program without the recorder: every
+reader of the timeline then finds nothing to read.
 
 Everything below :func:`analysis` is arithmetic on lists and is what the
 tests exercise. Host times are seconds on ``time.perf_counter()``, trace
@@ -30,7 +28,7 @@ import traceback
 from pathlib import Path
 from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
-from . import manifest, stats, tracing, xspace
+from . import stats, tracing, xspace
 
 #: spans that only hold other spans: their self time is time no span names
 UMBRELLAS = ("serve.request", "video_attempt", "family")
@@ -305,55 +303,20 @@ def attribute_gap(gap: Tuple[float, float], timeline: Timeline,
 
 # -- one analysis per measurement ---------------------------------------------
 
-def find_trace(m, root: Path = manifest.ROOT
-               ) -> Optional[Tuple[bytes, Dict[str, Dict[str, List[Any]]]]]:
-    """The profiler's file of THIS run, as bytes and as device planes: the
-    newest ``*.xplane.pb`` under ``benchmark_out/*/trace`` whose device-busy
-    seconds in the sub-window are ``m.trace["busy_s"]`` (``run.py`` clears a
-    cell's trace directory before it traces, so a cell holds one file; the
-    two loaders round a picosecond differently, hence not to the digit)."""
-    found = sorted(Path(root).glob("benchmark_out/*/trace/**/*.xplane.pb"),
-                   key=lambda p: p.stat().st_mtime, reverse=True)
-    t0 = m.trace["clock_uncertainty_s"] * 1e9
-    t1 = t0 + m.trace["window_s"] * 1e9
-    for path in found[:4]:
-        raw = xspace.read_bytes(path)
-        planes = xspace.load_ops(raw)
-        ops = xspace.ops_line(planes)
-        if not ops:
-            continue
-        busy = tracing.total(tracing.clip(tracing.busy_union(
-            (o.start_ns, o.start_ns + o.dur_ns) for o in ops), t0, t1)) / 1e9
-        if math.isclose(busy, m.trace["busy_s"], rel_tol=1e-5):
-            return raw, planes
-    return None
-
-
-def coarse_bracket(m, dispatch_times: List[float]) -> Tuple[float, float]:
-    """Sound bounds on the offset from the harness's own clock readings.
-
-    The trace's zero lies inside the ``start_trace`` call, which returned
-    ``m.trace["clock_uncertainty_s"]`` after it was made. The resident driver
-    makes the call right after a timing block's fence and dispatches again
-    right after it returned: the fence and the next dispatch that lie that
-    far apart bracket it. The served driver makes it on a thread that slept
-    until ``t0 + 0.3 * (seconds - length)`` and the traced stretch is at
-    least ``length`` long, so zero is no earlier than ``t0 + 0.3 * (window -
-    traced seconds)``; nothing of the harness bounds it from above, the work
-    has to."""
-    wait = m.trace["clock_uncertainty_s"]
-    if m.block_rates:
-        fences = sorted(at for at, _ in m.completions)
-        for fence in fences:
-            after = [at for at in dispatch_times if at >= fence]
-            if after and min(after) - fence >= wait:
-                return fence, min(after)
-    return m.t0 + 0.3 * (m.window_s - m.trace["window_s"]), math.inf
+def read_trace(m) -> Optional[Tuple[bytes, Dict[str, Dict[str, List[Any]]]]]:
+    """The profiler's file of this run (``m.trace_path``), as bytes and as
+    device planes; ``None`` where there is none or it holds no operation."""
+    if m.trace_path is None:
+        return None
+    raw = xspace.read_bytes(Path(m.trace_path))
+    planes = xspace.load_ops(raw)
+    return (raw, planes) if xspace.ops_line(planes) else None
 
 
 def analysis(m) -> Optional[Dict[str, Any]]:
-    """Everything the new readers read, computed once per measurement and
-    kept on it. ``None`` where the program keeps no recording; a part that
+    """Everything the timeline's readers read, computed once per
+    measurement (``run.py`` calls it after the trace is reduced) and kept on
+    it. ``None`` where the program keeps no recording; a part that
     cannot be had (no profiler file, no device plane) is ``None`` inside.
     Never raises: a traced run must not fail for what it adds."""
     if hasattr(m, "_timeline_analysis"):
@@ -377,19 +340,18 @@ def _analyse(m) -> Optional[Dict[str, Any]]:
     dispatches = timeline.dispatches() if timeline is not None else []
     out["dispatches"] = dispatches
     if timeline is None:
-        print("vftbench: timeline: the program's recorder did not run in "
-              "this window (no stage listener: the resident driver); the "
-              "device's side is read all the same")
+        print("vftbench: timeline: the program's recorder recorded nothing "
+              "in this window; the device's side is read all the same")
     else:
         print(f"vftbench: timeline: {len(timeline.spans)} spans and "
               f"{len(timeline.counters)} counter samples from the program's "
               f"recorder, {len(dispatches)} dispatches")
     if m.trace is None:
         return out
-    traced = find_trace(m)
+    traced = read_trace(m)
     if traced is None:
-        print("vftbench: timeline: no profiler file matches this run's "
-              "device-busy seconds; scopes and clocks are left out")
+        print("vftbench: timeline: no profiler file with device operations; "
+              "scopes and clocks are left out")
         return out
     raw, planes = traced
     ops = xspace.ops_line(planes)
@@ -410,8 +372,10 @@ def _analyse(m) -> Optional[Dict[str, Any]]:
     enqueues = [(d["start"], d["end"], d["seq"],
                  (d["program"], d["padded_rows"])) for d in dispatches] or \
         [(at, at, i, padded) for i, (at, _, padded) in enumerate(m.dispatches)]
-    entered = [d["at"] for d in dispatches] or [d[0] for d in m.dispatches]
-    coarse = coarse_bracket(m, entered)
+    # sound bounds from the harness's own readings: the trace's zero lies
+    # inside the ``start_trace`` call, between the reading of
+    # ``perf_counter`` before it and the one after it returned
+    coarse = (m.trace_zero_perf, m.trace_open_perf)
     hint = None
     session = xspace.session_unix_ns(raw)
     recorder = last_recorder()
@@ -422,9 +386,6 @@ def _analyse(m) -> Optional[Dict[str, Any]]:
     fences = [at for at, _ in m.completions] if m.block_rates else []
     lo, hi, shift = clock_bracket(modules, enqueues, fetches, fences, coarse,
                                   hint)
-    if not math.isfinite(hi):  # nothing tied it: what the harness knew
-        hi = lo + m.trace["clock_uncertainty_s"]
-        shift = None
     device.update(offset_lo=lo, offset_hi=hi, shift=shift,
                   clock_bound_s=hi - lo)
     print(f"vftbench: timeline: the clocks are tied to within "

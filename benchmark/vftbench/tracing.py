@@ -20,7 +20,7 @@ from __future__ import annotations
 import re
 import time
 from pathlib import Path
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Sequence, Tuple
 
 Event = Tuple[str, float, float]  # name, start_ns, duration_ns
 
@@ -36,18 +36,15 @@ SOUND_GAP = 4.0
 class TraceWindow:
     """``start()`` ... ``stop()`` around a few steady seconds. The sub-window
     is ``[open_perf, close_perf]``: after ``start_trace`` has returned and
-    before ``stop_trace`` is called, so the trace covers all of it."""
+    before ``stop_trace`` is called, so the trace covers all of it. What the
+    reduction and the timeline need later is kept on the measurement ``m``:
+    the file, ``perf_counter`` on both sides of ``start_trace`` and before
+    ``stop_trace``."""
 
-    def __init__(self, out_dir: Path) -> None:
+    def __init__(self, out_dir: Path, m) -> None:
         self.out_dir = Path(out_dir)
-        self.zero_perf: Optional[float] = None  # the session's start, nearly
-        self.open_perf: Optional[float] = None
-        self.close_perf: Optional[float] = None
-
-    @property
-    def uncertainty_s(self) -> float:
-        """How far ``zero_perf`` can be from the session's true start."""
-        return self.open_perf - self.zero_perf
+        self.m = m
+        self.stop_s = 0.0  # how long ``stop_trace`` took
 
     def start(self) -> None:
         import jax
@@ -55,19 +52,25 @@ class TraceWindow:
         options.python_tracer_level = 0
         options.host_tracer_level = 0
         options.enable_hlo_proto = False
-        self.zero_perf = time.perf_counter()
+        self.m.trace_zero_perf = time.perf_counter()
         jax.profiler.start_trace(str(self.out_dir), profiler_options=options)
-        self.open_perf = time.perf_counter()
+        self.m.trace_open_perf = time.perf_counter()
 
-    def stop(self) -> Path:
+    def stop(self) -> None:
         import jax
-        self.close_perf = time.perf_counter()
+        self.m.trace_close_perf = time.perf_counter()
         jax.profiler.stop_trace()
+        self.stop_s = time.perf_counter() - self.m.trace_close_perf
         found = sorted(self.out_dir.rglob("*.xplane.pb"))
         if not found:
             raise RuntimeError(f"the profiler wrote no .xplane.pb under "
                                f"{self.out_dir}")
-        return found[-1]
+        self.m.trace_path = found[-1]
+
+    def took(self) -> str:
+        start_s = self.m.trace_open_perf - self.m.trace_zero_perf
+        return (f"start_trace took {start_s:.3f} s and stop_trace "
+                f"{self.stop_s:.3f} s")
 
 
 # -- loading ------------------------------------------------------------------

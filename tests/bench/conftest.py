@@ -17,17 +17,29 @@ if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 
 
+def copy_benchmark(root: Path):
+    """The real ``benchmark/`` copied under ``root``; returns the copy, the
+    real manifest and every file's bytes for :func:`nothing_edited`."""
+    shutil.copytree(BENCH, root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = root / "benchmark"
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    return bench, json.loads((REPO / "BENCHMARK.json").read_text()), before
+
+
+def nothing_edited(bench: Path, before: dict) -> None:
+    after = {p: p.read_bytes() for p in bench.rglob("*")
+             if p.is_file() and p in before}
+    assert after == before, "adding a cell edited an existing file"
+
+
 def add_tiny_cells(root: Path) -> dict:
     """Copy the benchmark to ``root`` and ADD, without editing one existing
     file under ``benchmark/``: a configuration (with its costs and checks), two
     traffic mixes, three cells, an end-to-end metric and six per-layer metrics,
     one of them with a reader of its own.
     Returns the new manifest. This is all a later PR does to bring a cell."""
-    shutil.copytree(BENCH, root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    bench = root / "benchmark"
-    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
-    manifest = json.loads((REPO / "BENCHMARK.json").read_text())
+    bench, manifest, before = copy_benchmark(root)
 
     config = json.loads((bench / "configs" / "r21d-18.json").read_text())
     config["name"] = "r21d-tiny"
@@ -98,10 +110,46 @@ def add_tiny_cells(root: Path) -> dict:
             "name": name, "unit": unit, "better": better, "source": source,
             "layer": layer, "moves": moves, "workloads": [cell]})
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
-    after = {p: p.read_bytes() for p in bench.rglob("*")
-             if p.is_file() and p in before}
-    assert after == before, "adding a cell edited an existing file"
+    nothing_edited(bench, before)
     return manifest
+
+
+WAV_FAMILY = Path(__file__).parent / "fixtures" / "wav-family"
+
+
+def add_wav_cells(root: Path) -> dict:
+    """Copy the benchmark to ``root`` and ADD a family whose request is not a
+    video (``fixtures/wav-family``, laid over the copy file for file): the
+    ``wav`` corpus kind, the ``vggish-tiny`` configuration with its costs,
+    checks, resident inputs and plain reference, two traffic mixes and two
+    cells that join the manifest's metrics by name. No existing file of the
+    benchmark is edited, no entry of the manifest but the ``workloads`` lists
+    the cells append their names to."""
+    bench, manifest, before = copy_benchmark(root)
+    added = json.loads((WAV_FAMILY / "manifest.json").read_text())
+    for path in WAV_FAMILY.rglob("*"):
+        target = bench / path.relative_to(WAV_FAMILY)
+        if path.is_file() and path.name != "manifest.json":
+            assert not target.exists(), target
+            target.parent.mkdir(exist_ok=True)
+            shutil.copy(path, target)
+    manifest["configs"] += added["configs"]
+    manifest["workloads"] += added["workloads"]
+    for metric in manifest["end_to_end"] + manifest["per_layer"]:
+        for cell, names in added["joins"].items():
+            if metric["name"] in names:
+                metric["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    nothing_edited(bench, before)
+    return manifest
+
+
+@pytest.fixture
+def wav_root(tmp_path):
+    root = tmp_path / "checkout"
+    root.mkdir()
+    add_wav_cells(root)
+    return root
 
 
 @pytest.fixture

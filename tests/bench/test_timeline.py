@@ -316,6 +316,10 @@ def fake_measurement(raw, tmp_path):
     m = Measurement()
     m.trace = tracing.reduce_trace(tracing.load_xplane(path), BASE,
                                    BASE + 400 * US, [], BASE)
+    # what TraceWindow keeps on the measurement: the file, and the clock
+    # around start_trace (the trace's zero, 50.0 on the host, lies between)
+    m.trace_path = path
+    m.trace_zero_perf, m.trace_open_perf = 50.0 - 0.2 * BASE, 50.0 + 0.8 * BASE
     m.t0, m.t1 = 50.0 - 0.3 * 600 * US, 50.0 + 700 * US
     m.completions = [(50.0005, 8)]
     m.cpu_s = 0.0005
@@ -351,14 +355,11 @@ def test_analysis_renames_the_breakdown_and_ties_the_clocks(
     monkeypatch.setattr(timeline, "program_recording", lambda:
                         timeline.Timeline(program_side(50.0), perf0=0.0))
     monkeypatch.setattr(timeline, "last_recorder", lambda: None)
-    real = timeline.find_trace  # this run's file lies under tmp_path
-    monkeypatch.setattr(timeline, "find_trace",
-                        lambda m_: real(m_, root=tmp_path))
     found = timeline.analysis(m)
     assert timeline.analysis(m) is found  # once per measurement
     device_part = found["device"]
-    # the served driver's coarse bracket is open above; the fetches close
-    # it 1 us over the offset, the harness's reading bounds it below
+    # the harness's own bracket is the start_trace call, 1 ms; the fetches
+    # close it 1 us over the offset, the first enqueue bounds it below
     assert device_part["shift"] == 0
     assert device_part["offset_lo"] <= 50.0 <= device_part["offset_hi"]
     assert device_part["offset_hi"] - 50.0 == pytest.approx(1 * US)
@@ -430,13 +431,13 @@ def test_the_manifest_names_each_new_metric_once_and_resolves_it():
                         "mesh.ragged_flush_share"):
             assert mine.count(name) == 1, name
         served.reader(name)
-    # r21d-resident keeps the three per-layer metrics it had: an older test
-    # of the benchmark pins that set (test_harness.py), and this PR may not
-    # edit it. The readers a `step.` entry would name are here all the same
+    # r21d-resident has the entries whose readers PR 24 brought (PR 26: the
+    # resident driver records the program's timeline too)
     resident = manifest.Cell(man, "r21d-resident")
-    assert len(resident.per_layer) == 3
+    mine = [e["name"] for e in resident.per_layer]
     for name in ("step.device.clock_bound_ms", "step.model.layer1_share",
                  "step.model.unscoped_share"):
+        assert mine.count(name) == 1, name
         assert callable(resident.reader(name))
     # every metric the ledger has for PR 22 is still there, in place
     assert names[:14] == [

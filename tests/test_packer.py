@@ -284,3 +284,132 @@ def test_r21d_cross_video_outputs_identical(tmp_path):
         assert plain[name].shape == packed[name].shape, name
         np.testing.assert_allclose(packed[name], plain[name],
                                    atol=1e-5, rtol=1e-5, err_msg=name)
+
+
+# -- SegmentPacker: several documents' windows in one token row -----------------
+
+class _LineRunner:
+    """Stands for the token step: line ``s - 1`` of a row is (sum of the ids
+    of segment ``s``, its token count), so a routed line says whose it is."""
+    fixed_batch = 2
+
+    def __init__(self, segments):
+        self.segments = segments
+        self.groups = []
+
+    def dispatch(self, group):
+        self.groups.append(np.array(group))
+        ids, seg = group[:, 0], group[:, 1]
+        lines = np.zeros((len(group), self.segments, 2), np.float32)
+        for s in range(1, self.segments + 1):
+            lines[:, s - 1, 0] = np.where(seg == s, ids, 0).sum(axis=1)
+            lines[:, s - 1, 1] = (seg == s).sum(axis=1)
+        return lines
+
+
+def _segments(seed, lengths):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 100, n).astype(np.int32) for n in lengths]
+
+
+def test_segment_packer_fills_rows_and_routes_lines_back():
+    from video_features_tpu.parallel.packer import SegmentPacker
+    runner = _LineRunner(segments=4)
+    packer = SegmentPacker(runner, batch=2, row_len=16, max_segments=4)
+    docs = {"a": _segments(0, (5, 7)), "b": _segments(1, (6, 16, 3)),
+            "c": _segments(2, (2,))}
+    got = {}
+
+    def worker(name):
+        h = packer.open_video()
+        for tokens in docs[name]:
+            packer.add(h, tokens)
+        got[name] = packer.close_video(h)
+
+    threads = [threading.Thread(target=worker, args=(n,)) for n in docs]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    for name, windows in docs.items():
+        assert got[name].shape == (len(windows), 2)
+        assert [tuple(line) for line in got[name]] == [
+            (float(w.sum()), float(len(w))) for w in windows]
+    for group in runner.groups:
+        assert group.shape[1:] == (2, 16) and group.dtype == np.int32
+        for ids, seg in group:
+            # segments 1, 2, ... as contiguous runs, padding (0) behind them
+            filled = int((seg > 0).sum())
+            assert (seg[filled:] == 0).all() and (ids[filled:] == 0).all()
+            assert (np.diff(seg[:filled]) >= 0).all() and seg.max() <= 4
+    assert sum(int((g[:, 1] > 0).sum()) for g in runner.groups) == 39
+
+
+def test_segment_packer_seals_a_row_at_its_bounds_and_forgets_an_abort():
+    from video_features_tpu.parallel.packer import SegmentPacker
+    runner = _LineRunner(segments=2)
+    packer = SegmentPacker(runner, batch=1, row_len=8, max_segments=2)
+    gone = packer.open_video()
+    packer.add(gone, np.array([9, 9, 9], np.int32))
+    packer.abort_video(gone)               # leaves the open row empty again
+    h = packer.open_video()
+    for tokens in ([1, 1], [2], [3, 3, 3], [4] * 6):
+        packer.add(h, np.array(tokens, np.int32))
+    lines = packer.close_video(h)
+    assert [tuple(line) for line in lines] == [(2, 2), (2, 1), (9, 3),
+                                               (24, 6)]
+    # [1,1]+[2] (two segments: the bound), [3,3,3] (the next does not
+    # fit), [4]*6: three rows, none holds the aborted document's tokens
+    assert [int((g[0, 1] > 0).sum()) for g in runner.groups] == [3, 3, 6]
+    with pytest.raises(ValueError, match="a segment of 9 tokens"):
+        packer.add(packer.open_video(), np.arange(9, dtype=np.int32))
+
+
+def test_a_flush_waits_for_the_group_that_is_being_copied_out():
+    """A served loop: six workers, one short document each, again and again,
+    on a device that takes 40 ms a dispatch. A group that some thread is
+    copying out has left ``_inflight`` but not the device: a document that
+    arrives meanwhile must wait for it and leave with the others that
+    gathered, not alone behind it (1.07 documents a dispatch before this
+    was counted; 1, 5, 1, 5, ... since)."""
+    from video_features_tpu.parallel.packer import SegmentPacker
+
+    class Late:
+        def __init__(self, lines, ready):
+            self.lines, self.ready = lines, ready
+
+        def __array__(self, dtype=None, copy=None):
+            time.sleep(max(0.0, self.ready - time.perf_counter()))
+            return self.lines
+
+    class SlowRunner:
+        fixed_batch = 4
+
+        def __init__(self):
+            self.free, self.documents, self.lock = 0.0, [], threading.Lock()
+
+        def dispatch(self, group):
+            with self.lock:
+                self.free = max(time.perf_counter(), self.free) + 0.04
+                self.documents.append(int(group[:, 1].max(axis=1).sum()))
+                return Late(np.zeros((len(group), 8, 1), np.float32),
+                            self.free)
+
+    runner = SlowRunner()
+    packer = SegmentPacker(runner, batch=4, row_len=4096, max_segments=8)
+    until = time.perf_counter() + 1.5
+
+    def worker():
+        while time.perf_counter() < until:
+            h = packer.open_video()
+            packer.add(h, np.ones(300, np.int32))
+            assert packer.close_video(h).shape == (1, 1)
+            time.sleep(0.002)   # the response, the next claim
+
+    threads = [threading.Thread(target=worker) for _ in range(6)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert not any(t.is_alive() for t in threads)
+    assert np.mean(runner.documents) >= 2.0, runner.documents

@@ -138,6 +138,11 @@ SEMANTIC_KEYS = frozenset({
     # flow-family knobs (iteration counts and flow nets change outputs)
     "flow_type", "flow_iters", "flow_weights_path",
     "flow_model_weights_path", "iters", "finetuned_on",
+    # the token family: the published architecture it runs, this chip's
+    # share of a layer (which experts add to the result), and the bound on
+    # a row's segments (max_segments only where a row would overflow it,
+    # but then it moves a window into another row's rounding)
+    "architecture", "layer_shards", "layer_shard_rank", "max_segments",
     # kernel dispatch (implementations are near- but not bit-identical)
     "corr_lookup_impl", "fuse_convc1", "vision_attn",
     # CLIP text side + prediction rendering inputs

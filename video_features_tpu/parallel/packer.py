@@ -61,12 +61,16 @@ class ClipPacker:
         self._cond = threading.Condition(self._lock)
         self._drain_lock = threading.Lock()     # serializes D2H
         self._dispatch_lock = threading.Lock()  # serializes group dispatch
-        self._buf: List[tuple] = []          # [(handle, idx, stack), ...]
+        # one entry per ROW: (members, stack); a member is (handle, idx,
+        # slot): result ``idx`` of ``handle`` is line ``slot`` of the row's
+        # output, or the whole of it where slot is None (a clip's row)
+        self._buf: List[tuple] = []
         self._inflight: deque = deque()      # [(device_array, manifest, seq)]
         self._results: Dict[int, Dict[int, np.ndarray]] = {}
         self._counts: Dict[int, int] = {}    # clips added per handle
         self._pending: Dict[int, int] = {}   # clips not yet materialized
         self._errors: Dict[int, Exception] = {}  # poisoned-group handles
+        self._draining = 0   # groups popped from _inflight, D2H not done
         self._open = 0
         self._closing = 0
         self._next_handle = 0
@@ -87,21 +91,33 @@ class ClipPacker:
         """Append one clip stack; dispatches when the shared group fills."""
         to_dispatch = None
         with self._lock:
-            err = self._errors.get(handle)
-            if err is not None:
-                # an earlier group containing our clips already failed:
-                # stop this video now (the caller's except-path aborts it)
-                # instead of decoding + dispatching clips whose only
-                # possible outcome is a close_video failure
-                raise RuntimeError(
-                    "a packed clip group containing this video's clips "
-                    f"failed on device: {err}") from err
-            self._buf.append((handle, self._counts[handle], stack))
+            self._raise_if_poisoned(handle)
+            self._buf.append(([(handle, self._counts[handle], None)], stack))
             self._counts[handle] += 1
             self._pending[handle] += 1
-            if len(self._buf) >= self.batch:
-                to_dispatch, self._buf = self._buf, []
-            trace.counter("packer.buffered", len(self._buf))
+            to_dispatch = self._take_full_group()
+        self._dispatch_and_bound(to_dispatch)
+
+    def _raise_if_poisoned(self, handle: int) -> None:
+        """Under the lock: an earlier group containing this video's clips
+        already failed, so stop the video now (the caller's except-path
+        aborts it) instead of decoding and dispatching clips whose only
+        possible outcome is a close_video failure."""
+        err = self._errors.get(handle)
+        if err is not None:
+            raise RuntimeError(
+                "a packed clip group containing this video's clips failed "
+                f"on device: {err}") from err
+
+    def _take_full_group(self) -> Optional[List[tuple]]:
+        """Under the lock: the buffered rows, if they fill a group."""
+        group = None
+        if len(self._buf) >= self.batch:
+            group, self._buf = self._buf, []
+        trace.counter("packer.buffered", len(self._buf))
+        return group
+
+    def _dispatch_and_bound(self, to_dispatch: Optional[List[tuple]]) -> None:
         if to_dispatch is not None:
             # a dispatch failure contains OUR newest clip: propagate so the
             # caller's extractor aborts this video now (members poisoned)
@@ -123,13 +139,21 @@ class ClipPacker:
         other worker's close_video. Rows of its already-dispatched clips
         are dropped at drain time (the results entry is gone)."""
         with self._lock:
-            self._buf = [e for e in self._buf if e[0] != handle]
+            self._drop_buffered(handle)
             self._results.pop(handle, None)
             self._counts.pop(handle, None)
             self._pending.pop(handle, None)
             self._errors.pop(handle, None)
             self._open -= 1
             self._cond.notify_all()
+
+    def _drop_buffered(self, handle: int) -> None:
+        """Under the lock: forget an aborted video's rows not yet sent."""
+        self._buf = [e for e in self._buf if e[0][0][0] != handle]
+
+    def _seal_open_row(self) -> None:
+        """Under the lock, when every open video is closing: move what a
+        subclass still assembles into ``_buf``. Clips are whole rows."""
 
     def close_video(self, handle: int) -> np.ndarray:
         """Block until every clip of ``handle`` materialized; return the
@@ -148,7 +172,15 @@ class ClipPacker:
                     # of surfacing it.
                     if self._pending[handle] == 0 or handle in self._errors:
                         break
-                    if not self._inflight:
+                    # a group some thread is copying out still occupies
+                    # the device's queue: a ragged flush behind it would
+                    # only send what has arrived so far on its own (one
+                    # document a dispatch in a served loop, PERF.md
+                    # section 7); wait for that copy at the drain lock
+                    # below, and flush what has gathered by then
+                    if not self._inflight and not self._draining:
+                        if self._closing >= self._open:
+                            self._seal_open_row()
                         if self._buf and self._closing >= self._open:
                             # every open video is closing: nobody will fill
                             # the group — flush it ragged (the only ragged
@@ -201,13 +233,13 @@ class ClipPacker:
         with trace.span("packer.lock_wait", lock="dispatch"):
             self._dispatch_lock.acquire()
         try:
-            manifest = [(h, idx) for h, idx, _ in items]
+            manifest = [members for members, _ in items]
             try:
                 # np.stack inside the try: a shape mismatch or MemoryError
                 # here has already consumed the clips from _buf, so it must
                 # poison the members exactly like a device failure
                 with trace.span("packer.stack", rows=len(items)):
-                    group = np.stack([s for _, _, s in items])
+                    group = np.stack([s for _, s in items])
                 dev = self.runner.dispatch(group)
                 seq = getattr(self.runner, "last_seq", None)
             except Exception as e:
@@ -224,10 +256,11 @@ class ClipPacker:
         record the error so each member's ``close_video`` raises instead of
         spinning forever on clips that will never materialize."""
         with self._lock:
-            for h, _idx in manifest:
-                if h in self._pending:
-                    self._pending[h] -= 1
-                    self._errors[h] = exc
+            for members in manifest:
+                for h, _idx, _slot in members:
+                    if h in self._pending:
+                        self._pending[h] -= 1
+                        self._errors[h] = exc
             self._cond.notify_all()
 
     def _drain_oldest(self) -> None:
@@ -244,6 +277,7 @@ class ClipPacker:
                 if not self._inflight:
                     return
                 dev, manifest, seq = self._inflight.popleft()
+                self._draining += 1
             # ANY failure after the pop (the blocking D2H is the expected
             # one, but also e.g. a routing bug below) must poison the
             # members — once the group left _inflight, nobody else can
@@ -261,13 +295,85 @@ class ClipPacker:
                     host = np.asarray(dev)  # blocking D2H
                 with trace.span("packer.route", rows=len(manifest)), \
                         self._lock:
-                    for row, (h, idx) in enumerate(manifest):
-                        if h in self._results:
-                            self._results[h][idx] = host[row]
-                            self._pending[h] -= 1
+                    for row, members in enumerate(manifest):
+                        for h, idx, slot in members:
+                            if h in self._results:
+                                self._results[h][idx] = host[row] \
+                                    if slot is None else host[row, slot]
+                                self._pending[h] -= 1
                     self._cond.notify_all()
             except Exception as e:
                 self._poison(manifest, e)
                 raise
+            finally:
+                with self._lock:
+                    self._draining -= 1
+                    self._cond.notify_all()
         finally:
             self._drain_lock.release()
+
+
+class SegmentPacker(ClipPacker):
+    """Rows of ``row_len`` tokens filled with segments from several
+    documents, then packed into groups as clips are.
+
+    A segment is one window of a document, at most ``row_len`` token ids. It
+    goes into the open row behind the segments already there, under the next
+    segment id (1, 2, ...; 0 is padding), so a row is ``(2, row_len) int32``:
+    ids and segment ids, each segment a contiguous run. A row is sealed
+    when the next segment does not fit or it holds ``max_segments``; the
+    runner returns one line per segment id for every row, and line ``s - 1``
+    goes back to the document that owns segment ``s``. First fit into the one
+    open row only: documents arrive in no order worth sorting for.
+    """
+
+    def __init__(self, runner, batch: int, row_len: int, max_segments: int,
+                 depth: int = 4):
+        super().__init__(runner, batch, depth)
+        self.row_len = int(row_len)
+        self.max_segments = int(max_segments)
+        self._row: List[tuple] = []     # [(handle, idx, tokens)] of the open row
+        self._row_fill = 0
+
+    def add(self, handle: int, tokens: np.ndarray) -> None:
+        """Append one segment; seals the open row when it does not fit and
+        dispatches when the sealed rows fill a group."""
+        tokens = np.asarray(tokens, np.int32)
+        if not 0 < len(tokens) <= self.row_len:
+            raise ValueError(f"a segment of {len(tokens)} tokens for rows "
+                             f"of {self.row_len}")
+        with self._lock:
+            self._raise_if_poisoned(handle)
+            if self._row_fill + len(tokens) > self.row_len \
+                    or len(self._row) >= self.max_segments:
+                self._seal_open_row()
+            self._row.append((handle, self._counts[handle], tokens))
+            self._row_fill += len(tokens)
+            self._counts[handle] += 1
+            self._pending[handle] += 1
+            if self._row_fill == self.row_len:
+                self._seal_open_row()
+            to_dispatch = self._take_full_group()
+        self._dispatch_and_bound(to_dispatch)
+
+    def _seal_open_row(self) -> None:
+        if not self._row:
+            return
+        row = np.zeros((2, self.row_len), np.int32)
+        at, members = 0, []
+        for slot, (handle, idx, tokens) in enumerate(self._row):
+            row[0, at:at + len(tokens)] = tokens
+            row[1, at:at + len(tokens)] = slot + 1
+            at += len(tokens)
+            members.append((handle, idx, slot))
+        trace.counter("packer.row_fill", at, series="tokens")
+        trace.counter("packer.row_fill", self.row_len, series="capacity")
+        self._buf.append((members, row))
+        self._row, self._row_fill = [], 0
+
+    def _drop_buffered(self, handle: int) -> None:
+        """An aborted document's segments leave the open row; a sealed row
+        is sent as it is (its lines for the aborted document are dropped
+        when they come back)."""
+        self._row = [e for e in self._row if e[0] != handle]
+        self._row_fill = sum(len(e[2]) for e in self._row)

@@ -95,7 +95,9 @@ def _softmax_fold(q, acc, ck, cv, scale, valid):
 
 def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         block_size: int = 512, causal: bool = False,
-                        scale: Optional[float] = None) -> jnp.ndarray:
+                        scale: Optional[float] = None,
+                        segment_ids: Optional[jnp.ndarray] = None
+                        ) -> jnp.ndarray:
     """Single-device memory-efficient attention (B, T, H, D) -> same.
 
     The intra-device complement of :func:`ring_attention`: a ``lax.scan``
@@ -104,7 +106,8 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     FlashAttention recurrence expressed at the XLA level. Use it when one
     device's sequence shard is itself too long to score densely; compose
     with ring/Ulysses for the cross-device axis. T need not divide
-    block_size (keys pad with a mask).
+    block_size (keys pad with a mask). ``segment_ids`` (B, T) packs several
+    documents into a row: a query attends only to keys of its own segment.
     """
     b, t, h, d = q.shape
     scale = (d ** -0.5) if scale is None else scale
@@ -117,19 +120,28 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kb = jnp.moveaxis(kp.reshape(b, n_blocks, bs, h, d), 1, 0)
     vb = jnp.moveaxis(vp.reshape(b, n_blocks, bs, h, d), 1, 0)
     q_pos = jnp.arange(t)
+    blocks = (kb, vb)
+    if segment_ids is not None:
+        # (n_blocks, B, bs) beside the keys; the pad is in no query's segment
+        blocks += (jnp.moveaxis(jnp.pad(
+            segment_ids, ((0, 0), (0, pad)), constant_values=-1
+        ).reshape(b, n_blocks, bs), 1, 0),)
 
     def step(acc, blk):
         o, m, l, i = acc
-        ck, cv = blk
+        ck, cv = blk[:2]
         k_pos = i * bs + jnp.arange(bs)
         valid = k_pos[None, :] < t
         if causal:
             valid = valid & (q_pos[:, None] >= k_pos[None, :])
+        if segment_ids is not None:   # (B, 1, T, bs) over (B, H, T, bs)
+            valid = valid & (segment_ids[:, None, :, None]
+                             == blk[2][:, None, None, :])
         o, m, l = _softmax_fold(q, (o, m, l), ck, cv, scale, valid)
         return (o, m, l, i + 1), None
 
     o0, m0, l0 = _fold_init(b, h, t, d)
-    (o, _, l, _), _ = jax.lax.scan(step, (o0, m0, l0, 0), (kb, vb))
+    (o, _, l, _), _ = jax.lax.scan(step, (o0, m0, l0, 0), blocks)
     return _fold_finalize(o, l, q.dtype)
 
 

@@ -18,6 +18,7 @@ _DISPATCH = {
     "vggish": ("vggish", "ExtractVGGish"),
     "raft": ("raft", "ExtractRAFT"),
     "pwc": ("pwc", "ExtractPWC"),
+    "granite_hybrid": ("granite_hybrid", "ExtractGraniteHybrid"),
 }
 
 #: families that consume the AUDIO track: in a multi-family run they

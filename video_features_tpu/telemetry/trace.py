@@ -161,6 +161,7 @@ KNOWN_SPAN_NAMES = (
 KNOWN_COUNTER_NAMES = (
     "packer.buffered",      # clips in the shared buffer after an add/flush
     "packer.ragged_flush",  # rows of the one ragged dispatch, when it fires
+    "packer.row_fill",      # a sealed token row: series tokens / capacity
     "stream.inflight",      # un-materialized outputs of a FeatureStream
 )
 

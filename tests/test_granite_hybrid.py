@@ -326,7 +326,7 @@ def test_the_two_expert_shares_add_up_to_the_uncut_layer():
         assert np.array_equal(chosen, want_chosen)
         total = total + moe.held_experts(
             u, gates, chosen, held["experts_in"], held["experts_out"], lo,
-            valid)
+            valid, whole.num_local_experts)
     assert relative(np.asarray(total), np.asarray(want)) < F32_BAND
 
 
@@ -406,6 +406,29 @@ def test_the_extractor_agrees_with_the_reference(extractor, weights, tmp_path):
     feats, counts = ref.features(extractor.arch, *weights, doc, ROW, ROW)
     assert relative(got["granite_hybrid"], feats) < F32_BAND
     assert np.array_equal(got["expert_tokens"], counts)
+
+
+def test_a_document_counts_its_fullest_layers_held_assignments(
+        extractor, tmp_path):
+    """``moe.assignments``: series ``held`` and ``all``, one sample each per
+    document, on the program's own timeline."""
+    from video_features_tpu.telemetry import trace
+    from video_features_tpu.utils.profiling import profiler
+    (doc,) = documents(7, (130,))
+    profiler.set_trace_hook(lambda name, t0, dt: None)  # records in memory
+    try:
+        got = extractor.extract(token_file(tmp_path / "doc.tokens", doc))
+        extractor.extract(token_file(tmp_path / "none.tokens", []))
+    finally:
+        profiler.set_trace_hook(None)
+    samples = [e["args"] for e in trace.last_recording().events()
+               if e.get("ph") == "C" and e["name"] == "moe.assignments"]
+    a_layer = got["expert_tokens"].sum(axis=0)          # (10, 8)
+    arch = extractor.arch
+    assert (arch.first_expert, arch.experts_held) == (0, 4)
+    assert samples == [{"held": int(a_layer[:, :4].sum(axis=1).max())},
+                       {"all": 130 * 3}]
+    assert 0 < samples[0]["held"] < samples[1]["all"]
 
 
 def test_serve_loop_turns_token_files_into_feature_files(tmp_path):

@@ -118,14 +118,26 @@ class ExtractGraniteHybrid(BaseExtractor):
             raise
         lines = self._packer.close_video(handle).reshape(
             len(windows), self.arch.feature_dim + self.arch.counter_dim)
-        counts = np.rint(lines[:, self.arch.feature_dim:]).astype(np.int32)
+        counts = np.rint(lines[:, self.arch.feature_dim:]).astype(
+            np.int32).reshape(len(windows), len(self.arch.layer_types),
+                              self.arch.num_local_experts)
+        if len(windows):
+            # how near this document's routing runs to the routed layer's
+            # compact buffer (``ops/moe.py held_rows``): the assignments of
+            # its fullest layer to the experts held here, beside all of one
+            # layer's
+            a_layer = counts.sum(axis=0)                # (layers, experts)
+            first = self.arch.first_expert
+            trace.counter("moe.assignments", int(
+                a_layer[:, first:first + self.arch.experts_held]
+                .sum(axis=1).max()), series="held")
+            trace.counter("moe.assignments", int(a_layer[0].sum()),
+                          series="all")
         if self.show_pred:
             self.maybe_show_pred(ids, windows)
         return {self.feature_type: np.ascontiguousarray(
                     lines[:, :self.arch.feature_dim], np.float32),
-                "expert_tokens": counts.reshape(
-                    len(windows), len(self.arch.layer_types),
-                    self.arch.num_local_experts)}
+                "expert_tokens": counts}
 
     def maybe_show_pred(self, ids: np.ndarray, windows) -> None:
         """The five likeliest next tokens after each window's last position,

@@ -331,7 +331,7 @@ def token_states(arch: Arch, params: Mapping[str, Any], rows: jnp.ndarray,
                                      arch.num_experts_per_tok, router_dtype)
             routed = moe.held_experts(u, gates, picks, w["experts_in"],
                                       w["experts_out"], arch.first_expert,
-                                      valid)
+                                      valid, arch.num_local_experts)
         with scope("GraniteHybrid", "shared_mlp"):
             out = routed + moe.gated_unit(u, w["shared_in"], w["shared_out"])
             x = (x.astype(jnp.float32) + arch.residual_multiplier

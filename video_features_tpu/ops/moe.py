@@ -6,15 +6,25 @@ selected experts only those in ``[first, first + held)`` live here (expert
 parallelism: the others are on the chips that share the layer), and the
 layer returns ``sum_e gate_e * expert_e(u)`` over the selected experts that
 are held: what the absent ones would add is left out, by the plain reference
-too. No capacity and no dropped token: the assignments are sorted by expert
-into a buffer that has room for every one of them, and each expert's rows go
-through one grouped matrix product (``jax.lax.ragged_dot``), never a dense
-product over all experts masked afterwards.
+too. Each expert's rows go through one grouped matrix product
+(``jax.lax.ragged_dot``), never a dense product over all experts masked
+afterwards.
+
+No capacity and no dropped token. The assignments are sorted by expert, the
+held ones first, into a buffer whose length follows the share of the experts
+held here (:func:`held_rows`: 1.25 times the share a uniform router would
+send, at most every assignment). The held count is computed on the device
+from the routing. Where it fits, the row gather, both products and the
+fusion between them are that buffer long and the return trip reads from it
+(device scope ``moe/held``); where it does not, the same steps run over a
+buffer with room for every assignment (``moe/all``) and give the same bits.
+A layer that holds every expert has the one length and no condition.
 
 An expert is a gated unit: ``(silu(u W[:, :I]) * (u W[:, I:])) V``.
 """
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -44,16 +54,35 @@ def gated_unit(u: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray
                    preferred_element_type=jnp.float32)
 
 
+#: Room over the uniform share of the assignments, ``held / wide``, in the
+#: compact buffer. The benchmark's check (``benchmark/checks/``) refuses a run
+#: whose local share is more than 0.05 off the expected one: for half the
+#: experts that band ends at 0.55 of the assignments, and 0.5 * 1.25 = 0.625
+#: leaves it and a layer's own skew room. A layer past it is still exact: it
+#: takes the full-length path.
+HELD_ROOM = 1.25
+
+
+def held_rows(assignments: int, held: int, wide: int) -> int:
+    """The static length of the buffer the sorted assignments go through
+    when ``held`` of the router's ``wide`` experts live here: the uniform
+    share times :data:`HELD_ROOM`, up to a multiple of 1,024 rows, and never
+    more than all ``assignments``."""
+    room = math.ceil(assignments * held / wide * HELD_ROOM)
+    return min(assignments, -(-room // 1024) * 1024)
+
+
 def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
                  w_in: jnp.ndarray, w_out: jnp.ndarray, first: int,
-                 valid: jnp.ndarray) -> jnp.ndarray:
+                 valid: jnp.ndarray, wide: int) -> jnp.ndarray:
     """This chip's part of the routed layer, (T, D) float32.
 
-    ``u`` (T, D); ``gates`` / ``experts`` (T, K) from :func:`route`;
-    ``w_in`` (E, D, 2I) and ``w_out`` (E, I, D) are experts ``first`` ..
-    ``first + E - 1``; ``valid`` (T,) is false for padding, which is routed
-    nowhere. Assignments to an expert that is not held sort behind the held
-    ones and fall outside every group, so they are never multiplied."""
+    ``u`` (T, D); ``gates`` / ``experts`` (T, K) from :func:`route` over
+    ``wide`` experts; ``w_in`` (E, D, 2I) and ``w_out`` (E, I, D) are experts
+    ``first`` .. ``first + E - 1``; ``valid`` (T,) is false for padding,
+    which is routed nowhere. Assignments to an expert that is not held sort
+    behind the held ones and fall outside every group, so they are never
+    multiplied; past :func:`held_rows` they are not gathered either."""
     t, k = experts.shape
     held = w_in.shape[0]
     local = experts - first
@@ -61,16 +90,38 @@ def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
     group = jnp.where(here, local, held).reshape(-1)            # (T*K,)
     order = jnp.argsort(group, stable=True)
     sizes = (group[:, None] == jnp.arange(held)).sum(axis=0, dtype=jnp.int32)
-    rows = u[order // k]                                        # (T*K, D)
-    # the products accumulate in float32 and hand over in the compute type
-    hidden = jax.lax.ragged_dot(rows, w_in, sizes,
-                                preferred_element_type=u.dtype)
-    gate, up = jnp.split(hidden, 2, axis=-1)
-    out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_out, sizes,
-                             preferred_element_type=u.dtype)
-    # back to (token, choice) order; rows past the last group hold nothing
-    # that was computed and are masked, not trusted to be zero
     place = jnp.argsort(order)      # the permutation's inverse, by a sort
-    picked = jnp.where(here[..., None], out[place].reshape(t, k, -1), 0)
+
+    def through(length: int) -> jnp.ndarray:
+        """Every assignment's expert output, (T * K, D) in (token, choice)
+        order, by way of the first ``length`` sorted assignments, which
+        have to hold every held one."""
+        rows = u[order[:length] // k]                           # (length, D)
+        # the products accumulate in float32 and hand over in the compute
+        # type
+        hidden = jax.lax.ragged_dot(rows, w_in, sizes,
+                                    preferred_element_type=u.dtype)
+        gate, up = jnp.split(hidden, 2, axis=-1)
+        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_out, sizes,
+                                 preferred_element_type=u.dtype)
+        # an assignment that is not held has its place behind the held ones:
+        # clamped into the buffer, to a row the caller masks
+        return out[place if length == t * k
+                   else jnp.minimum(place, length - 1)]
+
+    def under(name: str, length: int):
+        def branch():
+            with jax.named_scope(name):
+                return through(length)
+        return branch
+
+    n = held_rows(t * k, held, wide)
+    # only what depends on the length sits under the condition: what both
+    # branches share the compiler moves out of them, under either's scope
+    back = through(n) if n == t * k else jax.lax.cond(
+        sizes.sum() <= n, under("held", n), under("all", t * k))
+    # rows past the last group hold nothing that was computed and are
+    # masked, not trusted to be zero
+    picked = jnp.where(here[..., None], back.reshape(t, k, -1), 0)
     weight = jnp.where(here, gates, 0.0)
     return jnp.sum(weight[..., None] * picked.astype(jnp.float32), axis=1)

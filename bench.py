@@ -323,7 +323,7 @@ def bench_i3d_pwc_ours(stack: int = I3D_STACK, iters: int = 10,
     cost volumes bf16; flow tensors, warp grid and flow heads f32 — drift
     0.015 px max, an order under the flow stream's ToUInt8 quantization).
 
-    Round-5 interleaved A/B (scripts/bench_i3d_variants.py, medians of 4
+    Round-5 interleaved A/B (medians of 4
     alternating rounds on v5e): raft-s4f 6.28 / pwc-f32 5.86 / pwc-bf16
     6.78 / x2 stacks 11.33 / x4 stacks 12.08 / x8 10.90 stacks/s — so
     n_stacks=4 (what _pwc_stacks_per_forward auto-picks at this geometry)
@@ -2003,14 +2003,14 @@ def main() -> None:
     # the fast production configuration of the same work unit
     i3d_note = ("round-4 step: fused lookup+convc1 kernel + 4 stacks/RAFT-"
                 "forward. The +48% vs BENCH_r03 was established INTERLEAVED "
-                "in one process (scripts/bench_i3d_variants.py: round-3 "
+                "in one process (round-3 "
                 "config 3.94 vs round-4 6.34 stacks/s, medians of 4 "
                 "alternating rounds); this row is the sequential re-run")
     pwc_note = ("round-5: the DEFAULT i3d config (flow_type=pwc, as in the "
                 "reference) finally measured AND optimized: bf16 PWC conv "
                 "stacks (models/pwc.py dtype; flow/warp math f32, 0.015 px "
-                "drift) + 4 stacks/forward. Interleaved A/B medians "
-                "(bench_i3d_variants.py): raft-s4f 6.28 / pwc-f32 5.86 / "
+                "drift) + 4 stacks/forward. Interleaved A/B medians: "
+                "raft-s4f 6.28 / pwc-f32 5.86 / "
                 "pwc-bf16x4 12.08 stacks/s — pwc default is now measured, "
                 "not inherited")
     for label, value, flow_kind, cost_key, note in (

@@ -245,10 +245,10 @@ def stage_raft(stage_dir: Path, platform: str,
                if ev.get("kind") == "corr_lookup"]
     check(lookups, "no corr_lookup event in _telemetry.jsonl")
     facts["corr_lookup"] = [{k: ev.get(k) for k in
-                             ("impl", "fused", "compiled", "fallback")}
+                             ("impl", "compiled", "fallback")}
                             for ev in lookups]
     if platform == "tpu":
-        check(all(ev.get("impl") == "pallas" and ev.get("fused")
+        check(all(ev.get("impl") == "proj"
                   and ev.get("compiled") and not ev.get("fallback")
                   for ev in lookups),
               f"raft did not run the compiled fused kernel: "
@@ -338,11 +338,13 @@ def _kernel_check(h8: int, w8: int, pairs: int, where: str,
             cl.corr_lookup_proj(*cl.stack_aligned_pyramid(pyramid), coords,
                                 weight, bias, interpret=interpret),
             cl.corr_lookup_proj_ref(pyramid, coords, weight, bias)),
-        "pallas": lambda: (
+        "level": lambda: (
             cl.corr_lookup_pallas(pyramid, coords, interpret=interpret),
             cl.corr_lookup_onehot(pyramid, coords)),
     }
-    rec["ok"] = True
+    # what a forward at this geometry runs on this backend: proj on the chip
+    rec["impl"] = cl.prepare_lookup(pyramid)[1].impl
+    rec["ok"] = interpret or rec["impl"] == "proj"
     for name, run in checks.items():
         try:
             got, ref = (np.asarray(x) for x in run())
@@ -483,9 +485,9 @@ def parent(ap: argparse.ArgumentParser, opts: argparse.Namespace) -> int:
     print(f"result: {RESULT}", flush=True)
     for rec in records:
         for k in rec.get("kernels", []):
-            print(f"kernel {k['geometry']} x{k['pairs']}: "
+            print(f"kernel {k['geometry']} x{k['pairs']} impl={k.get('impl')}: "
                   f"proj={k.get('proj_max_abs', k.get('proj_error'))} "
-                  f"pallas={k.get('pallas_max_abs', k.get('pallas_error'))}",
+                  f"level={k.get('level_max_abs', k.get('level_error'))}",
                   flush=True)
     if not all_ok:
         print("chip_smoke: FAILED", file=sys.stderr)

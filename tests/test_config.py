@@ -209,42 +209,17 @@ def test_resize_key_validation(tmp_path):
         sanity_check(cfg)
 
 
-def test_corr_lookup_config_promotion(monkeypatch, tmp_path):
-    """VERDICT next-round #7: the corr-lookup dispatch is a CONFIG key
-    applied at init (models/raft.py configure_corr_lookup); the env vars
-    remain highest-precedence overrides for trace-time perf probes."""
-    from video_features_tpu.models import raft as rm
-    monkeypatch.delenv("VFT_CORR_LOOKUP", raising=False)
-    monkeypatch.delenv("VFT_FUSE_CONVC1", raising=False)
-    # isolate + auto-restore the process-global dispatch state
-    monkeypatch.setitem(rm._CORR_CONFIG, "impl", None)
-    monkeypatch.setitem(rm._CORR_CONFIG, "fuse_convc1", None)
-
-    assert rm._corr_impl() == "gather"  # CPU auto default
-    assert rm._fuse_convc1() is True
-
-    rm.configure_corr_lookup("onehot", False)  # config keys win over auto
-    assert rm._corr_impl() == "onehot"
-    assert rm._fuse_convc1() is False
-
-    monkeypatch.setenv("VFT_CORR_LOOKUP", "gather")  # env overrides config
-    monkeypatch.setenv("VFT_FUSE_CONVC1", "1")
-    assert rm._corr_impl() == "gather"
-    assert rm._fuse_convc1() is True
-
-    with pytest.raises(ValueError):
-        rm.configure_corr_lookup("bogus")
-
-    base = dict(video_paths="a.mp4", output_path=str(tmp_path / "o"),
-                tmp_path=str(tmp_path / "t"))
-    cfg = load_config("raft", {**base, "corr_lookup_impl": "pallas",
-                               "fuse_convc1": True})
-    sanity_check(cfg)  # valid keys pass launch validation
-    with pytest.raises(ValueError):
-        sanity_check(load_config("raft", {**base,
-                                          "corr_lookup_impl": "bogus"}))
-    with pytest.raises(ValueError):
-        sanity_check(load_config("raft", {**base, "fuse_convc1": "yes"}))
+def test_flow_configs_carry_no_lookup_key_and_every_key_is_classified():
+    """RAFT's lookup form is the code's choice (kernels/corr_lookup.py
+    prepare_lookup): the two keys that once selected it are in no config,
+    and every key the flow-bearing configs do carry is still classified for
+    the feature cache's fingerprint (vft-lint VFT001)."""
+    from video_features_tpu import cache
+    for family in ("raft", "i3d"):
+        cfg = load_config(family)
+        assert not {"corr_lookup_impl", "fuse_convc1"} & set(cfg)
+        assert not (cache.SEMANTIC_KEYS & cache.NON_SEMANTIC_KEYS)
+        assert not set(cfg) - cache.SEMANTIC_KEYS - cache.NON_SEMANTIC_KEYS
 
 
 def test_history_alerts_key_validation(tmp_path):

@@ -87,42 +87,20 @@ def test_corr_lookup_onehot_integer_coords(rng):
     np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
 
 
-def test_corr_lookup_pallas_matches_gather(rng):
-    pyramid, coords, _ = _pyramid_and_coords(rng)
+@pytest.mark.parametrize("geometry", [
+    dict(),
+    # tiny inputs pool down to 1x1 and then 0x0 levels: the kernel has to
+    # reproduce the gather's all-zeros semantics for both
+    dict(h8=6, w8=5, c=16),
+], ids=["12x10", "degenerate"])
+def test_corr_lookup_pallas_matches_gather(rng, geometry):
+    pyramid, coords, _ = _pyramid_and_coords(rng, **geometry)
+    if geometry:
+        shapes = [tuple(c.shape[2:]) for c in pyramid]
+        assert (1, 1) in shapes and (0, 0) in shapes, shapes
     ref = np.asarray(corr_lookup_gather(pyramid, coords))
     ours = np.asarray(corr_lookup_pallas(pyramid, coords, interpret=True))
     assert ours.shape == ref.shape
-    np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
-
-
-def test_corr_lookup_packed_matches_gather(rng):
-    """The lane-dense packed fused kernel (VFT_CORR_LOOKUP=packed, the
-    measured negative-result alternative) keeps exact lookup semantics."""
-    from video_features_tpu.kernels.corr_lookup import (corr_lookup_packed,
-                                                        pack_pyramid)
-    pyramid, coords, _ = _pyramid_and_coords(rng)
-    packed, metas = pack_pyramid(pyramid)
-    ref = np.asarray(corr_lookup_gather(pyramid, coords))
-    ours = np.asarray(corr_lookup_packed(packed, metas, coords,
-                                         interpret=True))
-    assert ours.shape == ref.shape
-    np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
-
-
-def test_corr_lookup_packed_degenerate_pyramid(rng):
-    """Tiny inputs pool down to 1x1 and then 0x0 levels; the packed kernel
-    must reproduce the gather's all-zeros semantics for both (the fused
-    kernel stores an explicit zero placeholder plane, corr_lookup.py
-    _plan_level)."""
-    from video_features_tpu.kernels.corr_lookup import (corr_lookup_packed,
-                                                        pack_pyramid)
-    pyramid, coords, _ = _pyramid_and_coords(rng, h8=6, w8=5, c=16)
-    shapes = [tuple(c.shape[2:]) for c in pyramid]
-    assert (1, 1) in shapes and (0, 0) in shapes, shapes
-    packed, metas = pack_pyramid(pyramid)
-    ref = np.asarray(corr_lookup_gather(pyramid, coords))
-    ours = np.asarray(corr_lookup_packed(packed, metas, coords,
-                                         interpret=True))
     np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
 
 
@@ -279,25 +257,49 @@ def test_corr_lookup_proj_degenerate_pyramid(rng):
     np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
 
 
-def test_pack_pyramid_geometry(rng):
-    """The lane-dense packing stays dense: one 128-lane line carries
-    multiple narrow image rows, all levels' row-groups share ONE fused
-    lane plane, and zero fill covers phantom rows + lane tails (the
-    zeros-padding rule)."""
-    from video_features_tpu.kernels.corr_lookup import pack_pyramid
-    pyramid, _, _ = _pyramid_and_coords(rng, b=2, h8=28, w8=28, c=16)
-    packed, metas = pack_pyramid(pyramid)
-    # RAFT-224 finest level: 4 rows of 28 cols per 128-lane line, 7 groups
-    m0 = metas[0]
-    assert (m0.j, m0.g, m0.k, m0.off) == (4, 7, 128, 0)
-    b, p = pyramid[0].shape[:2]
-    assert packed.shape == (b * p, sum(m.g * m.k for m in metas))
-    assert metas[1].off == 7 * 128
-    # spot value: query (b=1, p=5), image row 9 col 3 -> group 2, sub-row 1
-    want = float(pyramid[0][1, 5, 9, 3])
-    got = float(packed[p + 5, 2 * 128 + 1 * 28 + 3])
-    assert got == want
-    # level-0 lane tail beyond j*wl is zero fill in every group
-    for g in range(7):
-        tail = packed[:, g * 128 + 112:(g + 1) * 128]
-        assert float(jnp.abs(tail).max()) == 0.0
+@pytest.mark.parametrize("backend, h8, w8, impl, fallback", [
+    ("cpu", 30, 40, "gather", None),
+    ("tpu", 30, 40, "proj", None),
+    ("tpu", 28, 28, "proj", None),
+    ("tpu", 55, 128, "proj", None),
+    # 1080x1920: the stacked plane (264 x 256 cells) passes the 8-query tile
+    ("tpu", 135, 240, "level", "stacked 135x240 pyramid plane"),
+    # 2160x3840: no 8-query tile holds even the level-0 plane
+    ("tpu", 270, 480, "onehot", "270x480 level-0 plane"),
+])
+def test_prepare_lookup_decides_from_backend_and_geometry(
+        monkeypatch, backend, h8, w8, impl, fallback):
+    """The lookup's one decision reads the backend and the level-0 plane's
+    shape, and hands the pyramid back in the form it chose. Shapes only:
+    the volume at (270, 480) would be 67 GB."""
+    from video_features_tpu.kernels import corr_lookup as cl
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    raw = tuple(jax.ShapeDtypeStruct((1, h8 * w8, h8 >> i, w8 >> i),
+                                     jnp.float32) for i in range(4))
+    forms = []
+
+    def prepared(*levels):
+        pyramid, form = cl.prepare_lookup(levels)
+        forms.append(form)
+        return pyramid
+
+    out = jax.eval_shape(prepared, *raw)
+    (form,) = forms
+    assert form.impl == impl
+    assert (form.fallback is None) if fallback is None \
+        else fallback in form.fallback
+    hash(form)  # static: RAFT's scan body carries it as a module field
+    if impl == "proj":
+        assert out.shape == (1, h8 * w8,
+                             cl.stacked_plane_cells(h8, w8) // out.shape[3],
+                             -(-w8 // 128) * 128)
+        assert [m.off for m in form.metas] == list(np.cumsum(
+            [0] + [m.hlp for m in form.metas[:-1]]))
+    elif impl == "level":
+        assert form.metas == ()
+        assert [o.shape[2:] for o in out] == [
+            (-(-(h8 >> i) // 8) * 8, -(-(w8 >> i) // 128) * 128)
+            for i in range(4)]
+    else:
+        assert form.metas == ()
+        assert [o.shape for o in out] == [r.shape for r in raw]

@@ -86,7 +86,7 @@ def test_corr_pyramid_and_lookup_match_torch():
     gx, gy = np.meshgrid(np.arange(20.0), np.arange(16.0))
     coords = (np.stack([gx, gy], axis=-1)[None] +
               rng.uniform(-2, 2, size=(1, 16, 20, 2))).astype(np.float32)
-    got = np.asarray(raft_model.corr_lookup(pyr, jnp.asarray(coords)))
+    got = np.asarray(raft_model.corr_lookup_gather(pyr, jnp.asarray(coords)))
 
     t1 = torch.from_numpy(f1).permute(0, 3, 1, 2)
     t2 = torch.from_numpy(f2).permute(0, 3, 1, 2)
@@ -186,12 +186,34 @@ def test_iters_config_knob(tmp_path):
     assert build(iters=2).model.iters == 2
 
 
+def _as_on_a_tpu(monkeypatch, proj_fits=True):
+    """Force a kernel form on the CPU through the lookup's one decision
+    function: it sees a TPU backend (and, for the per-level form, a stacked
+    plane that fits no tile) while it decides, and nothing else does, so
+    ``kernels.interpret_mode()`` still runs ``pallas_call`` in the
+    interpreter."""
+    import jax
+    from video_features_tpu.kernels import corr_lookup as cl
+    real = cl.prepare_lookup
+
+    def prepare(pyramid):
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            if not proj_fits:
+                m.setattr(cl, "proj_lookup_supported", lambda p: False)
+            return real(pyramid)
+
+    monkeypatch.setattr(cl, "prepare_lookup", prepare)
+
+
 def test_fused_convc1_path_matches_default(rng, monkeypatch):
-    """The fused lookup+convc1 scan path (VFT_CORR_LOOKUP=pallas, the TPU
-    default — interpret mode here) produces the same flow as the gather
-    path, through the full model: same param tree (the _Convc1Params twin
-    shares nn.Conv's path/shapes), same numerics up to matmul reorder."""
+    """The full model through ``proj`` (the fused lookup+convc1 scan path,
+    the TPU's form — interpret mode here) and through the per-level form
+    produces the same flow as through ``gather``: same param tree (the
+    _Convc1Params twin shares nn.Conv's path/shapes), same numerics up to
+    matmul reorder."""
     from video_features_tpu.models import raft as rm
+    from video_features_tpu.telemetry.spans import VideoSpan
 
     params = rm.init_params(iters=4)
     assert params["update_block"]["encoder"]["convc1"]["kernel"].shape \
@@ -201,14 +223,25 @@ def test_fused_convc1_path_matches_default(rng, monkeypatch):
     x2 = jnp.asarray(rng.integers(
         0, 255, size=(1, 64, 72, 3)).astype(np.float32))
     model = rm.RAFT(iters=4)
-    want = np.asarray(model.apply({"params": params}, x1, x2))
-    monkeypatch.setenv("VFT_CORR_LOOKUP", "pallas")
-    monkeypatch.setenv("VFT_FUSE_CONVC1", "1")
-    got = np.asarray(model.apply({"params": params}, x1, x2))
+
+    def flow():
+        with VideoSpan("v.mp4") as span:
+            out = np.asarray(model.apply({"params": params}, x1, x2))
+        (event,) = [e for e in span.record["events"]
+                    if e["kind"] == "corr_lookup"]
+        return out, event["impl"]
+
+    want, impl = flow()
+    assert impl == "gather"
+    with monkeypatch.context() as m:
+        _as_on_a_tpu(m)
+        got, impl = flow()
+    assert impl == "proj"
     np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-3)
-    # and the explicitly-unfused pallas path still matches too
-    monkeypatch.setenv("VFT_FUSE_CONVC1", "0")
-    unfused = np.asarray(model.apply({"params": params}, x1, x2))
+    with monkeypatch.context() as m:
+        _as_on_a_tpu(m, proj_fits=False)
+        unfused, impl = flow()
+    assert impl == "level"
     np.testing.assert_allclose(unfused, want, atol=1e-3, rtol=1e-3)
 
 
@@ -256,36 +289,66 @@ def test_precision_bfloat16_wires_model_dtype(tmp_path, monkeypatch):
 
 
 def test_corr_lookup_states_what_ran(rng, monkeypatch, capsys):
-    """Feature values are the same on every lookup branch, so the branch is
-    stated: the plan at init (impl / fused / compiled-or-interpreted), a
-    ``corr_lookup`` span event per traced forward, and a printed line
-    whenever a size gate replaces the planned kernel."""
+    """Feature values are the same on every lookup form, so the form is
+    stated: a ``corr_lookup`` span event per traced forward (impl /
+    compiled-or-interpreted / fallback), and a printed line whenever a size
+    gate replaced ``proj``."""
+    from video_features_tpu.kernels import corr_lookup as cl
     from video_features_tpu.telemetry.spans import VideoSpan
-    monkeypatch.delenv("VFT_CORR_LOOKUP", raising=False)
-    monkeypatch.delenv("VFT_FUSE_CONVC1", raising=False)
-    monkeypatch.setitem(raft_model._CORR_CONFIG, "impl", None)
-    monkeypatch.setitem(raft_model._CORR_CONFIG, "fuse_convc1", None)
-    assert raft_model.corr_lookup_plan() == {
-        "impl": "gather", "fused": False, "compiled": None}
-    raft_model.configure_corr_lookup("pallas", None)
-    # off-TPU pallas_call is the interpreter, and the statement says so
-    assert raft_model.corr_lookup_plan() == {
-        "impl": "pallas", "fused": True, "compiled": False}
-    raft_model.announce_corr_lookup("raft")
-    assert "impl=pallas fused with convc1, in the Pallas interpreter" \
-        in capsys.readouterr().out
+    params = raft_model.init_params(iters=1)
+    x = jnp.asarray(rng.integers(0, 255, size=(1, 32, 32, 3))
+                    .astype(np.float32))
+    model = raft_model.RAFT(iters=1)
 
-    # the VMEM size gate: the one-hot twin runs, and says that it did
-    f1 = jnp.asarray(rng.normal(size=(1, 4, 4, 8)).astype(np.float32))
-    pyramid = raft_model.build_corr_pyramid(f1, f1)
-    coords = jnp.asarray(rng.uniform(0, 4, size=(1, 4, 4, 2))
-                         .astype(np.float32))
-    monkeypatch.setattr(raft_model, "_pallas_supported", lambda p: False)
-    with VideoSpan("v.mp4") as span:
-        got = raft_model.corr_lookup(pyramid, coords)
-    np.testing.assert_allclose(
-        got, raft_model.corr_lookup_gather(pyramid, coords), atol=1e-5)
-    assert "impl=pallas fell back to onehot (XLA)" in capsys.readouterr().out
-    (event,) = [e for e in span.record["events"]
-                if e["kind"] == "corr_lookup"]
-    assert event["impl"] == "pallas" and "onehot" in event["fallback"]
+    def stated():
+        with VideoSpan("v.mp4") as span:
+            flow = np.asarray(model.apply({"params": params}, x, x))
+        (event,) = [e for e in span.record["events"]
+                    if e["kind"] == "corr_lookup"]
+        return flow, {k: event[k] for k in ("impl", "compiled", "fallback")}
+
+    want, event = stated()
+    assert event == {"impl": "gather", "compiled": None, "fallback": None}
+    assert "corr lookup" not in capsys.readouterr().out
+
+    # off-TPU pallas_call is the interpreter, and the statement says so
+    with monkeypatch.context() as m:
+        _as_on_a_tpu(m)
+        _, event = stated()
+    assert event == {"impl": "proj", "compiled": False, "fallback": None}
+    assert "corr lookup" not in capsys.readouterr().out
+
+    # both VMEM size gates: the one-hot twin runs, and says that it did
+    with monkeypatch.context() as m:
+        _as_on_a_tpu(m, proj_fits=False)
+        m.setattr(cl, "pallas_lookup_supported", lambda p: False)
+        got, event = stated()
+    np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-3)
+    assert event["impl"] == "onehot" and event["compiled"] is None
+    assert "4x4 level-0 plane fits no legal VMEM tile" in event["fallback"]
+    assert "corr lookup: impl=onehot in place of proj: a 4x4 level-0 " \
+        "plane fits no legal VMEM tile" in capsys.readouterr().out
+
+
+def test_lookup_environment_variables_are_inert(monkeypatch):
+    """The environment selects no lookup form: the variables an older build
+    read at trace time change neither the decision nor the traced
+    program."""
+    import jax
+    from video_features_tpu.kernels import corr_lookup as cl
+    model = raft_model.RAFT(iters=1)
+    x = jnp.zeros((1, 32, 32, 3), jnp.float32)
+    params = jax.eval_shape(lambda: raft_model.init_params(1))
+    pyramid = tuple(jnp.zeros((1, 16, 4 >> i, 4 >> i)) for i in range(4))
+
+    def traced():
+        return (str(jax.make_jaxpr(lambda p: model.apply(
+            {"params": p}, x, x))(params)), cl.prepare_lookup(pyramid)[1])
+
+    for name in ("VFT_CORR_LOOKUP", "VFT_FUSE_CONVC1"):
+        monkeypatch.delenv(name, raising=False)
+    before = traced()
+    monkeypatch.setenv("VFT_CORR_LOOKUP", "onehot")
+    monkeypatch.setenv("VFT_FUSE_CONVC1", "0")
+    assert traced() == before
+    assert before[1] == cl.LookupForm("gather")

@@ -437,7 +437,6 @@ def _runner(family):
     if family == "raft":
         from video_features_tpu.extractors import raft as ex
         from video_features_tpu.models import raft as m
-        m.configure_corr_lookup(None, None)
         model = m.RAFT(iters=2, dtype=jnp.bfloat16)
         params = cast_floating(m.init_params(), jnp.bfloat16)
         return (DataParallelApply(partial(ex._raft_forward, model), params,
@@ -538,7 +537,6 @@ def test_corr_pyramid_pools_feature_maps_and_never_the_volume(lowered):
 @pytest.mark.parametrize("kernel, name", [
     ("corr_lookup_pallas", "corr_lookup_level"),
     ("corr_lookup_proj", "corr_lookup_proj"),
-    ("corr_lookup_packed", "corr_lookup_packed"),
 ])
 def test_pallas_kernels_carry_their_name(kernel, name):
     import jax
@@ -551,7 +549,7 @@ def test_pallas_kernels_carry_their_name(kernel, name):
     if kernel == "corr_lookup_pallas":
         def fn():
             return k.corr_lookup_pallas(pyramid, coords, 4, interpret=True)
-    elif kernel == "corr_lookup_proj":
+    else:
         aligned = [k.align_level(c) for c in pyramid]
         stacked, meta = k.stack_aligned_pyramid(aligned)
 
@@ -560,12 +558,6 @@ def test_pallas_kernels_carry_their_name(kernel, name):
                                       jnp.zeros((324, 256), jnp.float32),
                                       jnp.zeros((256,), jnp.float32),
                                       interpret=True)
-    else:
-        packed, metas = k.pack_pyramid(pyramid)
-
-        def fn():
-            return k.corr_lookup_packed(packed, metas, coords, 4,
-                                        interpret=True)
     assert f"name={name}" in str(jax.make_jaxpr(fn)())
 
 

@@ -144,7 +144,7 @@ SEMANTIC_KEYS = frozenset({
     # but then it moves a window into another row's rounding)
     "architecture", "layer_shards", "layer_shard_rank", "max_segments",
     # kernel dispatch (implementations are near- but not bit-identical)
-    "corr_lookup_impl", "fuse_convc1", "vision_attn",
+    "vision_attn",
     # CLIP text side + prediction rendering inputs
     "bpe_path", "pred_texts",
     # VGGish post-processing

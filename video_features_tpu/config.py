@@ -37,10 +37,10 @@ CACHE_ROOT = Path(__file__).resolve().parent.parent / ".cache"
 #: eight YAMLs unless it is declared here (or in LAUNCH_KEYS below):
 #: a key that is neither is a default nobody documented.
 OPTIONAL_KEYS = frozenset({
-    "batch_size", "bpe_path", "clip_batch_size", "corr_lookup_impl",
+    "batch_size", "bpe_path", "clip_batch_size",
     "extraction_fps", "extraction_total", "finetuned_on", "flow_iters",
     "flow_model_weights_path", "flow_stack_batch", "flow_type",
-    "flow_weights_path", "fps_mode", "frontend", "fuse_convc1", "ingest",
+    "flow_weights_path", "fps_mode", "frontend", "ingest",
     "iters", "model_name", "model_parallel", "pca_weights_path",
     "postprocess", "pred_texts", "resize", "resize_to_smaller_edge",
     "side_size", "stack_size", "step_size", "streams", "vision_attn",
@@ -538,20 +538,6 @@ def sanity_check(args: Config, *, require_videos: bool = True) -> None:
     if rz is not None and rz not in ("auto", "host", "device"):
         raise ValueError(f"resize={rz!r}: expected 'auto', 'host' or "
                          "'device'")
-
-    # RAFT corr-lookup dispatch keys (models/raft.py configure_corr_lookup,
-    # applied at extractor init — the config-first promotion of the old
-    # trace-time env vars; VFT_CORR_LOOKUP/VFT_FUSE_CONVC1 stay as
-    # perf-probe overrides)
-    cli_impl = args.get("corr_lookup_impl")
-    if cli_impl is not None and cli_impl not in ("gather", "onehot",
-                                                 "pallas", "packed"):
-        raise ValueError(f"corr_lookup_impl={cli_impl!r}: expected null "
-                         "(auto), 'gather', 'onehot', 'pallas' or 'packed'")
-    fc1 = args.get("fuse_convc1")
-    if fc1 is not None and not isinstance(fc1, bool):
-        raise ValueError(f"fuse_convc1={fc1!r}: expected true, false or "
-                         "null (auto)")
 
     fps_mode = args.get("fps_mode", "select") or "select"
     if fps_mode not in ("select", "reencode"):

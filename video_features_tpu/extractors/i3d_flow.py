@@ -93,7 +93,7 @@ def _stacks_per_forward(t: int, h: int, w: int, budget: int,
                         cap: int = 4) -> int:
     """How many stacks' pair batches to fuse into one flow forward.
 
-    Round-4 measurement (scripts/bench_i3d_variants.py, interleaved): 1 ->
+    Round-4 measurement (interleaved): 1 ->
     2 -> 4 stacks per RAFT forward measured 3.94 -> 4.41 -> 4.50 stacks/s
     unfused and 5.90 -> 6.34 fused at 64f@224px on v5e — more queries per
     launch amortize per-dispatch and per-scan-iteration fixed costs.
@@ -148,12 +148,6 @@ class FlowStream:
                 f"flow_stack_batch={self.stack_batch}: need >= 1 or 'auto'")
         crop = parent.central_crop_size
         if parent.flow_type == "raft":
-            # corr-lookup dispatch from config keys (validated in
-            # sanity_check), installed before the first traced forward —
-            # env vars stay perf-probe overrides (models/raft.py)
-            raft_model.configure_corr_lookup(args.get("corr_lookup_impl"),
-                                             args.get("fuse_convc1"))
-            raft_model.announce_corr_lookup("i3d flow stream (raft)")
             # the reference hardcodes the sintel checkpoint for the i3d flow
             # sub-model (extract_i3d.py:178); flow_iters trades flow accuracy
             # for speed (fewer GRU refinement steps) — default is the
@@ -263,8 +257,7 @@ class FlowStream:
             # PWC budget models the decoder live set, not RAFT's all-pairs
             # pyramid (_pwc_stacks_per_forward). Round-5 interleaved A/B
             # at 64f@224px on v5e: 1 -> 2 stacks/forward took bf16 PWC
-            # from 6.78 to 11.33 stacks/s (scripts/bench_i3d_variants.py
-            # p1b/p2b medians).
+            # from 6.78 to 11.33 stacks/s.
             k = _pwc_stacks_per_forward(
                 t, *group.shape[2:4], self._budget,
                 bytes_per_el=jnp.dtype(self._flow_dtype).itemsize)

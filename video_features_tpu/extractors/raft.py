@@ -33,13 +33,6 @@ class ExtractRAFT(OpticalFlowExtractor):
 
     def __init__(self, args: Config) -> None:
         super().__init__(args)
-        # corr-lookup dispatch from config (validated in sanity_check);
-        # installed here — before anything is traced — so the old
-        # set-the-env-before-first-trace footgun cannot occur. The env
-        # vars remain perf-probe overrides (models/raft.py).
-        raft_model.configure_corr_lookup(args.get("corr_lookup_impl"),
-                                         args.get("fuse_convc1"))
-        raft_model.announce_corr_lookup("raft")
         finetuned_on = args.get("finetuned_on", "sintel")
         if finetuned_on not in ("sintel", "kitti"):
             raise NotImplementedError(

@@ -7,27 +7,16 @@ memory-bound hot loop, the RAFT correlation-pyramid lookup, as a
 grid_sample gather (reference models/raft/raft_src/corr.py:29-50). Here:
 
   - :mod:`corr_lookup` — the windowed bilinear pyramid lookup recast as
-    one-hot matmul contractions (gather-free, rides the MXU), as a fused
-    Pallas kernel and a pure-XLA twin. Selected by the
-    ``corr_lookup_impl`` config key (models/raft.py
-    configure_corr_lookup, applied at extractor init; the
-    ``VFT_CORR_LOOKUP`` env var is the trace-time override) —
-    ``pallas`` (TPU default, the 20x one) | ``onehot`` | ``gather``
-    (CPU default).
+    one-hot matmul contractions (gather-free, rides the MXU): two Pallas
+    kernels (``proj``, fused with the motion encoder's convc1, and
+    ``level``) and a pure-XLA twin (``onehot``), beside the reference's
+    ``gather``. ``corr_lookup.prepare_lookup`` picks the form from the
+    backend and the plane's size; nothing else selects one.
   - :mod:`cost_volume` — the 81-channel windowed cost volume as the XLA
     shifted-window formulation. A Pallas twin was built, hardware-
     validated, measured TIED with XLA across every real PWC shape in f32
     and bf16, and deleted in round 5 (measured negative result recorded
     in that module's docstring).
-
-Measured on a TPU v5e before PR 0, with a D2H-fenced timer
-(parallel/mesh.py settle), on an installation that no longer exists — a
-claim to re-measure, not a current number:
-
-  corr lookup, end-to-end 20-iteration RAFT forward (16 pairs @224px):
-    gather 4,097 ms / one-hot 331 ms / fused Pallas 200 ms. The 81-tap
-    4-corner scalar gathers are the worst access pattern the TPU has; the
-    MXU contraction forms win by 12-20x, so Pallas is the TPU default.
 """
 from __future__ import annotations
 

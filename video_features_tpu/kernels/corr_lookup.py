@@ -20,40 +20,36 @@ reduce the (10, 10) corner window to the (9, 9) tap window with scalar
 weights per query. Channel order matches the reference quirk (x-offset
 slowest; corr.py:37-43 adds its meshgrid "dy" to x).
 
-Implementations with identical numerics:
+Four forms with the same values, and one function that picks among them
+(:func:`prepare_lookup`, from the backend and the level-0 plane's shape;
+no config key, environment variable or caller selects a form):
 
-  - :func:`corr_lookup_onehot` — pure jnp/XLA (runs anywhere);
-  - :func:`corr_lookup_level_pallas` / :func:`corr_lookup_pallas` — fused
-    Pallas kernel per level over lane-PADDED planes: the one-hots are built
-    in VMEM and contracted in-kernel, so the (P, 10, Hl) selector tensors
-    never touch HBM. **TPU default** (fastest measured).
-  - :func:`corr_lookup_packed` — ONE fused kernel for ALL levels over a
-    lane-DENSE repacked pyramid (``VFT_CORR_LOOKUP=packed``). Kept as a
-    measured negative result — see below.
+  - ``gather`` — the reference's formulation (models/raft.py
+    corr_lookup_gather): every backend but the TPU, and the parity
+    reference of the tests;
+  - ``proj`` — :func:`corr_lookup_proj`: ONE Pallas kernel for all levels
+    over a sublane-stacked plane, fused with the motion encoder's 1x1
+    ``convc1``. The TPU's form wherever the stacked plane fits a VMEM tile;
+  - ``level`` — :func:`corr_lookup_pallas`: one Pallas kernel a level over
+    lane-padded planes. The TPU's form once the stacked plane is too large
+    (a 1080x1920 input) while each level still fits;
+  - ``onehot`` — :func:`corr_lookup_onehot`: the formulation above in plain
+    XLA, no tiling constraint. The TPU's form past both size gates, and the
+    kernels' twin in the tests and on the chip (chip_smoke.py stage 5:
+    both kernels within 1e-4 of it at (30, 40), (28, 28), (8, 8),
+    (55, 128) under the extractors' precision=float32 matmul-precision
+    pin; under bfloat16 the contraction drifts ~8e-3, that mode's
+    contract).
 
-Round-3 negative result (recorded so nobody re-litigates it from theory):
-the per-level default lane-pads narrow planes (28 -> 128 at RAFT-224's
-finest level), so round 2 hypothesized a ~4.6x useless-DMA tax as the
-throughput floor. Round 3 built the lane-dense alternative — J=4 image
-rows per 128-lane line, all levels' row-groups fused into one (Q, 1408)
-plane, 5.8x fewer bytes per GRU iteration (282 MB vs 1.64 GB), one kernel
-launch instead of four — and measured the flagship I3D RGB+Flow bench on
-v5e across six structural variants (fused 1-call Pallas, per-level 4-call
-Pallas, pure-XLA einsum form, tile sweeps 32..512, empty-body DMA floor,
-select-vs-dot row routing): EVERY dense variant landed at 3.47-3.60
-stacks/s vs 3.95 for the padded default, same-day A/B. An empty kernel
-body over the same blocks cost the same as the full kernel. Conclusion:
-the lookup is bound by per-query selection work (mask/select VPU ops +
-grid machinery), NOT by HBM bytes — the padded layout wins because its
-selectors are plain 2-compare iota one-hots, while any dense packing must
-additionally route J-packed rows (G-way selects or an extra mask pass),
-which costs more than the bytes it saves.
+A lane-dense packing of the pyramid (several image rows a 128-lane line,
+5.8x fewer bytes an iteration) was built and lost to the padded planes:
+the lookup is bound by per-query selection work, not by bytes. Commit
+da49f76 is the last that holds it.
 """
 from __future__ import annotations
 
 import functools
-import os
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -201,10 +197,9 @@ _MAX_TILE_P = 256
 
 def pallas_lookup_supported(pyramid: Sequence[jnp.ndarray]) -> bool:
     """Whether the per-level kernel can tile these planes within the probed
-    VMEM envelope: even an 8-query tile must fit the budget. False only for
-    extreme inputs (~>5800 px on a side at RAFT's /8 feature stride) where
-    ``_VMEM_BLOCK_BYTES // plane_bytes`` underflows and the 8-query floor
-    would demand a >16 MiB block. Callers fall back to
+    VMEM envelope: even an 8-query tile must fit the budget, which holds up
+    to 65,536 aligned cells a plane (a 2160x3840 input's 272x512 level-0
+    plane does not). :func:`prepare_lookup` then hands the raw levels to
     :func:`corr_lookup_onehot`, the tiling-free twin."""
     for c in pyramid:
         hl, wl = c.shape[2], c.shape[3]
@@ -225,6 +220,10 @@ def corr_lookup_level_pallas(corr: jnp.ndarray, px0: jnp.ndarray,
     corr = align_level(corr)  # no-op when the caller pre-aligned
     b, p, hl, wl = corr.shape
     n = 2 * radius + 1
+    if hl == 0 or wl == 0:
+        # degenerate level (tiny inputs pool to 0x0): every tap reads the
+        # zeros-padding region
+        return jnp.zeros((b, p, n * n), jnp.float32)
     if tile_p is None:
         # as many queries per program as the VMEM budget allows: fewer,
         # bigger programs matter because the coarse levels are
@@ -298,14 +297,13 @@ def corr_lookup_pallas(pyramid: Sequence[jnp.ndarray], coords: jnp.ndarray,
 
 # ---- fused lookup + convc1 projection (round-4 TPU default) --------------
 #
-# Round-4 profiling (scripts/bench_i3d_variants.py --trace): the four
-# per-level lookup kernels cost ~100 ms of a 215 ms I3D RGB+Flow step and
-# ALL levels cost the same ~25 ms despite 4-64x different plane sizes —
-# the binding cost is per-query work on the 128-lane-padded width
-# (selector build + blend + 9-lane-wide stores), which is level-size
-# independent. Downstream, the (B, H, W, 324) lookup output is a relayout
+# Round-4 profiling: the four per-level lookup kernels cost ~100 ms of a
+# 215 ms I3D RGB+Flow step and ALL levels cost the same ~25 ms despite
+# 4-64x different plane sizes — the binding cost is per-query work on the
+# 128-lane-padded width (selector build + blend + 9-lane-wide stores),
+# which is level-size independent. Downstream, the (B, H, W, 324) lookup output is a relayout
 # boundary XLA cannot see through (~17 ms/step of reshape passes feeding
-# the motion encoder's convc1, models/raft.py:177-180).
+# the motion encoder's convc1, models/raft.py BasicMotionEncoder).
 #
 # This kernel removes both ends at once:
 #   - the bilinear blend folds INTO the selectors (9 weighted rows instead
@@ -443,14 +441,13 @@ def _proj_kernel(cx_ref, cy_ref, corr_ref, w_ref, b_ref, out_ref, taps_ref,
     out_ref[0] = jnp.maximum(acc + b_ref[...], 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("metas", "radius", "interpret",
-                                             "tile_p"))
+@functools.partial(jax.jit, static_argnames=("metas", "radius", "interpret"))
 def _corr_lookup_proj_flat(stacked: jnp.ndarray,
                            metas: Tuple[ProjMeta, ...],
                            cx: jnp.ndarray, cy: jnp.ndarray,
                            weight: jnp.ndarray, bias: jnp.ndarray,
-                           radius: int = 4, interpret: bool = False,
-                           tile_p: Optional[int] = None) -> jnp.ndarray:
+                           radius: int = 4, interpret: bool = False
+                           ) -> jnp.ndarray:
     """Flat-query fused lookup+projection: stacked (1, Q, Hsum, Wp) plane,
     cx/cy (1, Q) level-0 centers, weight (L*(2r+1)^2, C), bias (C,).
     Returns (1, Q, C) = relu(lookup @ weight + bias)."""
@@ -463,9 +460,7 @@ def _corr_lookup_proj_flat(stacked: jnp.ndarray,
             f"stacked corr plane ({hsum}x{wp}) too large for any legal "
             "VMEM tile; use the unfused path (proj_lookup_supported "
             "gates this dispatch)")
-    if tile_p is None:
-        tile_p = min(_MAX_TILE_P, max(8, _VMEM_BLOCK_BYTES // plane))
-    tp = _best_tile(q, tile_p)
+    tp = _best_tile(q, min(_MAX_TILE_P, max(8, _VMEM_BLOCK_BYTES // plane)))
     qq = -(-q // tp) * tp
     if qq != q:
         stacked = jnp.pad(stacked, ((0, 0), (0, qq - q), (0, 0), (0, 0)))
@@ -511,20 +506,13 @@ def corr_lookup_proj(stacked: jnp.ndarray, metas: Tuple[ProjMeta, ...],
     coords: (B, H, W, 2) level-0 (x, y); weight (L*(2r+1)^2, C) rows in
     the lookup's channel order; bias (C,). Returns (B, H, W, C) float32 =
     ``relu(corr_lookup(pyramid, coords) @ weight + bias)`` with the pair
-    batch folded into the query dim (the lookup is purely per-query).
-
-    ``VFT_PROJ_TILE_P`` (perf probes) overrides the VMEM-derived query
-    tile. Read HERE, outside the jit, and passed as a static argument —
-    an env read inside the jitted body would be frozen into the first
-    trace and silently ignored for every later value."""
+    batch folded into the query dim (the lookup is purely per-query)."""
     b, h, w, _ = coords.shape
     cx = coords[..., 0].reshape(1, b * h * w)
     cy = coords[..., 1].reshape(1, b * h * w)
     flat = stacked.reshape(1, b * h * w, *stacked.shape[2:])
-    env = os.environ.get("VFT_PROJ_TILE_P", "").strip()
     out = _corr_lookup_proj_flat(flat, metas, cx, cy, weight, bias,
-                                 radius, interpret,
-                                 tile_p=int(env) if env else None)
+                                 radius, interpret)
     return out.reshape(b, h, w, -1)
 
 
@@ -537,248 +525,39 @@ def corr_lookup_proj_ref(pyramid: Sequence[jnp.ndarray], coords: jnp.ndarray,
     return jax.nn.relu(jnp.einsum("bhwk,kc->bhwc", corr, weight) + bias)
 
 
-# ---- lane-dense packed pyramid (opt-in: VFT_CORR_LOOKUP=packed) ----------
-#
-# Measured ~10% SLOWER end-to-end than the per-level default on v5e (see
-# the module docstring's negative-result record) — retained because the
-# layout is the textbook fix for the padding tax and the measurement that
-# refutes it should stay reproducible.
+# ---- the one decision ------------------------------------------------------
 
-class LevelMeta(NamedTuple):
-    """Static packing geometry of one pyramid level."""
-    hl: int   # image rows
-    wl: int   # image cols
-    j: int    # rows packed per 128-lane line
-    g: int    # row-groups (ceil(hl / j))
-    k: int    # packed lane width (j*wl rounded up to 128)
-    off: int = 0  # lane offset of this level in the fused (Q, K_total) plane
+class LookupForm(NamedTuple):
+    """Which of the four forms a pyramid was prepared for. Static and
+    hashable: RAFT's scan body carries it as its one lookup field."""
+    impl: str  # 'proj' | 'level' | 'onehot' | 'gather'
+    metas: Tuple[ProjMeta, ...] = ()  # 'proj': the stacked plane's levels
+    fallback: Optional[str] = None  # why a size gate replaced 'proj'
 
 
-def _plan_level(hl: int, wl: int) -> LevelMeta:
-    if hl == 0 or wl == 0:
-        # degenerate level (tiny inputs pool to nothing): every tap reads
-        # the zeros-padding region, so a placeholder one-lane-line plane of
-        # zeros reproduces the gather semantics exactly
-        return LevelMeta(0, 0, 1, 1, 128)
-    j = min(hl, max(1, 128 // wl))
-    g = -(-hl // j)
-    k = -(-(j * wl) // 128) * 128
-    return LevelMeta(hl, wl, j, g, k)
+def prepare_lookup(pyramid: Sequence[jnp.ndarray]
+                   ) -> Tuple[Any, LookupForm]:
+    """The lookup's one decision: which form runs on these raw
+    (B, P, Hl, Wl) levels, and the pyramid in that form. Reads the backend
+    and the level-0 plane's shape, nothing else. Off a TPU ``gather`` (raw
+    levels). On a TPU ``proj`` where the stacked plane fits a VMEM tile
+    (the plane; its metas ride the form), else ``level`` where every level
+    does (aligned levels), else ``onehot`` (raw levels), with the reason
+    the gate gave in ``fallback``.
 
-
-def pack_level(corr: jnp.ndarray) -> Tuple[jnp.ndarray, LevelMeta]:
-    """(B, P, Hl, Wl) level -> ((B*P, G*K) lane-dense row-group planes,
-    meta). Row-group g of query q lives in lanes [g*K, g*K + K).
-
-    Zero fill everywhere the packed layout exceeds the image plane (phantom
-    rows of the last group, lane tail beyond J*Wl): a window corner landing
-    there selects a zero, which IS the reference's zeros-padding rule
-    (corr.py bilinear_sampler zeros mode)."""
-    b, p, hl, wl = corr.shape
-    m = _plan_level(hl, wl)
-    if m.hl == 0:
-        return jnp.zeros((b * p, m.g * m.k), corr.dtype), m
-    x = corr.reshape(b * p, hl, wl)
-    if m.g * m.j != hl:
-        x = jnp.pad(x, ((0, 0), (0, m.g * m.j - hl), (0, 0)))
-    x = x.reshape(b * p, m.g, m.j * wl)
-    if m.k != m.j * wl:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, m.k - m.j * wl)))
-    return x.reshape(b * p, m.g * m.k), m
-
-
-def fused_lookup_supported(pyramid: Sequence[jnp.ndarray]) -> bool:
-    """Whether the packed fused kernel can tile these levels within the
-    probed VMEM envelope (one query's packed planes must fit
-    _VMEM_BLOCK_BYTES) with a sane unroll (the G-way select-accumulate is
-    statically unrolled; G grows with input size — 28 groups at 448 px —
-    and past ~16 the routing chain is hopeless anyway, see the module
-    docstring's negative result). Callers fall back to
-    corr_lookup_onehot."""
-    metas = [_plan_level(c.shape[2], c.shape[3]) for c in pyramid]
-    per_q = sum(m.g * m.k for m in metas) * 4
-    return per_q <= _VMEM_BLOCK_BYTES and max(m.g for m in metas) <= 16
-
-
-def pack_pyramid(pyramid: Sequence[jnp.ndarray]
-                 ) -> Tuple[jnp.ndarray, Tuple[LevelMeta, ...]]:
-    """All levels -> ONE (B*P, K_total) lane-dense plane + per-level metas
-    carrying each level's lane offset (one contiguous block DMA per grid
-    step). Hoist this OUT of the GRU scan — XLA does not hoist relayouts
-    out of while bodies."""
-    packed, metas = zip(*(pack_level(c) for c in pyramid))
-    offs = []
-    off = 0
-    for m in metas:
-        offs.append(m._replace(off=off))
-        off += m.g * m.k
-    return jnp.concatenate(packed, axis=1), tuple(offs)
-
-
-def _packed_kernel(cx_ref, cy_ref, corr_ref, out_ref, *, radius: int,
-                   metas: Tuple[LevelMeta, ...]):
-    """One grid step: TILE_Q queries x ALL pyramid levels.
-
-    Block shapes: cx/cy (TQ, 1, 1); corr (TQ, K_total) — ONE contiguous
-    lane-dense plane carrying every level's row-groups (level l group g at
-    lanes [off_l + g*K_l, ...), selected in-kernel by static lane slices,
-    free at the 128-lane tile granularity); out (TQ, L*n*n) with per-level
-    tap channel k = xx*n + yy (x-offset slowest — the reference's order),
-    levels concatenated in pyramid order."""
-    n = 2 * radius + 1
-    cx = cx_ref[...]  # (TQ, 1, 1)
-    cy = cy_ref[...]
-    corr_all = corr_ref[...].astype(jnp.float32)  # (TQ, K_total)
-    tq = corr_all.shape[0]
-    d10 = jax.lax.broadcasted_iota(
-        jnp.int32, (1, n + 1, 1), 1).astype(jnp.float32)
-    for lvl, m in enumerate(metas):
-        if m.hl == 0:  # degenerate level: all taps hit the zeros padding
-            zeros = jnp.zeros((tq, n), jnp.float32)
-            for i in range(n):
-                out_ref[:, (lvl * n + i) * n:(lvl * n + i + 1) * n] = zeros
-            continue
-        px0 = cx * (1.0 / (1 << lvl)) - radius
-        py0 = cy * (1.0 / (1 << lvl)) - radius
-        ix = jnp.floor(px0)
-        iy = jnp.floor(py0)
-        r = iy + d10   # (TQ, 10, 1) window-corner row indices
-        # lane coordinate -> (sub-row j, column w); Mosaic iota is
-        # integer-only, so the decomposition runs in f32 (exact: all values
-        # are small integers, and IEEE division of exact quotients is exact)
-        kf = jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, m.k), 2).astype(jnp.float32)
-        j_of_k = jnp.floor(kf / m.wl)
-        w_of_k = kf - m.wl * j_of_k
-
-        def plane(g):
-            # static lane slice (free at 128-lane tile granularity), then
-            # a rank-expand so the group plane broadcasts over the 10 rows.
-            # Explicit lax ops: jnp's mixed None/slice indexing can lower
-            # through gather, which Mosaic rejects.
-            sl = jax.lax.slice_in_dim(corr_all, m.off + g * m.k,
-                                      m.off + (g + 1) * m.k, axis=1)
-            return jax.lax.expand_dims(sl, (1,))  # (TQ, 1, K)
-
-        if m.g == 1:
-            # whole plane in one lane line set: row index IS the sub-row.
-            # No modulo here — a negative r must match nothing, not wrap.
-            jr = r
-            u = plane(0)  # broadcasts over the 10 rows
-        else:
-            g_of_r = jnp.floor(r / m.j)
-            jr = r - m.j * g_of_r
-            # G-way select-accumulate picks each corner row's group plane
-            # (G <= 8; out-of-range groups match nothing -> zero row, the
-            # zeros-padding rule again). This routing is the measured cost
-            # that eats the DMA savings — see the module docstring.
-            u = jnp.zeros((tq, n + 1, m.k), jnp.float32)
-            for g in range(m.g):
-                u = u + jnp.where(g_of_r == g, plane(g), 0.0)
-        v = jnp.where(j_of_k == jr, u, 0.0)          # (TQ, 10, K)
-        xb = (w_of_k == ix + d10).astype(jnp.float32)  # (TQ, 10, K)
-        window = jax.lax.dot_general(                 # (TQ, 10x, 10y)
-            xb, v, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        fx = px0 - ix  # (TQ, 1, 1), broadcasts over the window dims
-        fy = py0 - iy
-        blended = ((1 - fx) * (1 - fy) * window[:, :n, :n]
-                   + fx * (1 - fy) * window[:, 1:, :n]
-                   + (1 - fx) * fy * window[:, :n, 1:]
-                   + fx * fy * window[:, 1:, 1:])  # (TQ, n_x, n_y)
-        base = lvl * n * n
-        for i in range(n):  # static lane-sliced stores (Mosaic rejects
-            # 9-wide lane concats but accepts sliced stores)
-            out_ref[:, base + i * n:base + (i + 1) * n] = blended[:, i, :]
-
-
-#: Scoped-VMEM target for one packed grid step. v5e's scoped limit is
-#: 16 MiB (hardware-observed OOM reports say so exactly); 12 MiB leaves
-#: margin for the surrounding program, which matters INSIDE the RAFT GRU
-#: scan — the same kernel allocates more scoped VMEM in a while body than
-#: standalone (measured 20.16 MiB in-scan at TQ=256 vs compiling clean
-#: standalone).
-_VMEM_TARGET = 12 * 1024 * 1024
-_MAX_TILE_Q = 512
-
-
-@functools.partial(jax.jit, static_argnames=("metas", "radius", "interpret",
-                                             "tile_q", "out_dtype"))
-def _corr_lookup_packed_flat(packed: jnp.ndarray,
-                             metas: Tuple[LevelMeta, ...],
-                             cx: jnp.ndarray, cy: jnp.ndarray,
-                             radius: int = 4, interpret: bool = False,
-                             tile_q: Optional[int] = None,
-                             out_dtype=jnp.float32) -> jnp.ndarray:
-    """Flat-query fused lookup: packed (Q, K_total) fused plane; cx/cy (Q,)
-    level-0 centers. Returns (Q, L*(2r+1)^2)."""
-    q = cx.shape[0]
-    n = 2 * radius + 1
-    per_q = sum(m.g * m.k for m in metas) * 4
-    if tile_q is None:
-        env = os.environ.get("VFT_CORR_TILE_Q", "").strip()
-        if env:  # perf-probe override (trace-time, like VFT_CORR_LOOKUP)
-            tile_q = int(env)
-    if tile_q is None:
-        # scoped-VMEM model per query, calibrated against measured Mosaic
-        # OOM reports (in-scan, the worst case): double-buffered corr blocks
-        # (2x per_q) plus ~(7 + G_max) live (TQ, n+1, K) f32 selector/
-        # accumulator tensors at the widest level — the G-way routing keeps
-        # its operands live, so the model scales with the unroll (in-scan
-        # OOM arithmetic: 20.16 MiB at TQ=256 for the RAFT-224 pyramid
-        # with G_max=7 = 78.8 KiB/query)
-        k_max = max(m.k for m in metas)
-        g_max = max(m.g for m in metas)
-        inter = (7 + g_max) * (n + 1) * 4 * k_max
-        tile_q = min(_MAX_TILE_Q,
-                     max(8, _VMEM_TARGET // (2 * per_q + inter)))
-    if per_q > _VMEM_BLOCK_BYTES:
-        # a single query's packed planes exceed the probed VMEM envelope
-        # (inputs ~>5800 px on a side): no legal tile exists, so refuse
-        # loudly rather than fault in Mosaic — callers can use the XLA
-        # one-hot twin at such sizes
-        raise ValueError(
-            f"corr planes too large for the fused kernel ({per_q} B/query "
-            f"> {_VMEM_BLOCK_BYTES} B VMEM budget); use corr_lookup_onehot")
-    tq = _best_tile(q, tile_q)
-    qq = -(-q // tq) * tq
-    if qq != q:
-        packed = jnp.pad(packed, ((0, qq - q), (0, 0)))
-        cx = jnp.pad(cx, (0, qq - q))
-        cy = jnp.pad(cy, (0, qq - q))
-    k_total = packed.shape[1]
-    coord_spec = pl.BlockSpec((tq, 1, 1), lambda qi: (qi, 0, 0),
-                              memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        functools.partial(_packed_kernel, radius=radius, metas=metas),
-        name="corr_lookup_packed",  # the kernel's own name in a device trace
-        grid=(qq // tq,),
-        in_specs=[coord_spec, coord_spec,
-                  pl.BlockSpec((tq, k_total), lambda qi: (qi, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((tq, len(metas) * n * n),
-                               lambda qi: (qi, 0), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((qq, len(metas) * n * n), out_dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(cx[:, None, None].astype(jnp.float32),
-      cy[:, None, None].astype(jnp.float32), packed)
-    return out[:q]
-
-
-def corr_lookup_packed(packed: jnp.ndarray,
-                       metas: Tuple[LevelMeta, ...], coords: jnp.ndarray,
-                       radius: int = 4, interpret: bool = False,
-                       tile_q: Optional[int] = None) -> jnp.ndarray:
-    """Fused lookup over a pre-packed pyramid (see :func:`pack_pyramid`).
-
-    coords: (B, H, W, 2) level-0 (x, y) with B folded into Q = B*H*W at
-    pack time (the lookup is purely per-query). Returns
-    (B, H, W, L*(2r+1)^2) in the reference's level/tap channel order."""
-    b, h, w, _ = coords.shape
-    cx = coords[..., 0].reshape(b * h * w)
-    cy = coords[..., 1].reshape(b * h * w)
-    out = _corr_lookup_packed_flat(packed, metas, cx, cy, radius,
-                                   interpret, tile_q)
-    return out.reshape(b, h, w, -1)
+    Call it ONCE, outside the GRU scan: the pads are loop-invariant and XLA
+    does not hoist them out of the while body (unhoisted they cost ~30% of
+    the RAFT forward, see :func:`align_level`)."""
+    if jax.default_backend() != "tpu":
+        return pyramid, LookupForm("gather")
+    if proj_lookup_supported(pyramid):
+        stacked, metas = stack_aligned_pyramid(pyramid)
+        return stacked, LookupForm("proj", metas)
+    hl, wl = pyramid[0].shape[2:]
+    if pallas_lookup_supported(pyramid):
+        return (tuple(align_level(c) for c in pyramid),
+                LookupForm("level", fallback=(
+                    f"the stacked {hl}x{wl} pyramid plane fits no legal "
+                    "VMEM tile")))
+    return pyramid, LookupForm("onehot", fallback=(
+        f"a {hl}x{wl} level-0 plane fits no legal VMEM tile"))

@@ -46,7 +46,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,8 @@ import numpy as np
 from flax import linen as nn
 
 from .common import BNInf, scope
+from ..kernels import corr_lookup as lookup_kernels, interpret_mode
+from ..kernels.corr_lookup import LookupForm
 from ..weights import torch_import as ti
 
 CORR_LEVELS = 4
@@ -140,25 +142,25 @@ class _Convc1Params(nn.Module):
 class BasicMotionEncoder(nn.Module):
     """update.py:86-104.
 
-    ``fuse_meta`` (static) switches convc1 into the fused Pallas
-    lookup+projection kernel: ``corr`` is then the sublane-stacked pyramid
-    plane (kernels/corr_lookup.py stack_aligned_pyramid) and ``coords``
-    the level-0 query centers — the (B, H, W, 324) lookup intermediate
-    never materializes (round-4 profiling: its relayout boundary cost
-    ~17 ms per 64-pair forward on v5e)."""
-    fuse_meta: Optional[Tuple[Any, ...]] = None
+    ``lookup`` (static) says what ``corr`` is. Under ``proj`` it is the
+    sublane-stacked pyramid plane (kernels/corr_lookup.py
+    stack_aligned_pyramid) and ``coords`` the level-0 query centers:
+    convc1 runs inside the fused Pallas lookup+projection kernel and the
+    (B, H, W, 324) lookup intermediate never materializes (round-4
+    profiling: its relayout boundary cost ~17 ms per 64-pair forward on
+    v5e). Under every other form it is that intermediate."""
+    lookup: LookupForm = LookupForm("gather")
 
     @nn.compact
     def __call__(self, flow: jnp.ndarray, corr: jnp.ndarray,
-                 coords: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-        if self.fuse_meta is not None:
-            from ..kernels import interpret_mode
-            from ..kernels.corr_lookup import corr_lookup_proj
+                 coords: jnp.ndarray) -> jnp.ndarray:
+        if self.lookup.impl == "proj":
             k, b = _Convc1Params(name="convc1")()
             with jax.named_scope("lookup"):
-                cor = corr_lookup_proj(corr, self.fuse_meta, coords,
-                                       k.reshape(k.shape[2], k.shape[3]), b,
-                                       interpret=interpret_mode())
+                cor = lookup_kernels.corr_lookup_proj(
+                    corr, self.lookup.metas, coords,
+                    k.reshape(k.shape[2], k.shape[3]), b,
+                    interpret=interpret_mode())
                 cor = cor.astype(flow.dtype)
         else:
             cor = nn.relu(nn.Conv(256, (1, 1), name="convc1")(corr))
@@ -205,23 +207,18 @@ class UpdateIter(nn.Module):
     the mask head is applied separately, see RAFT.__call__). Shaped as a
     ``lax.scan`` body: (carry, broadcast-inputs) -> (carry, None).
 
-    ``corr_meta`` (static) marks the broadcast ``pyramid`` input as
-    lane-dense-packed for the fused Pallas lookup (kernels/corr_lookup.py
-    pack_pyramid); ``None`` means raw (B, P, Hl, Wl) levels. ``fuse_meta``
-    (static) marks it as the sublane-stacked plane of the fused
-    lookup+convc1 kernel (the TPU default since round 4)."""
-    corr_meta: Optional[Tuple[Any, ...]] = None
-    fuse_meta: Optional[Tuple[Any, ...]] = None
+    ``lookup`` (static) is the form :func:`kernels.corr_lookup.prepare_lookup`
+    handed the broadcast ``pyramid`` input in; the body runs that form and
+    decides nothing."""
+    lookup: LookupForm = LookupForm("gather")
 
     @nn.compact
     def __call__(self, carry, inputs):
         net, coords1 = carry
         pyramid, inp, coords0 = inputs
         flow = (coords1 - coords0).astype(net.dtype)
-        if self.fuse_meta is not None:
-            motion = BasicMotionEncoder(fuse_meta=self.fuse_meta,
-                                        name="encoder")(
-                flow, pyramid, coords1)
+        if self.lookup.impl == "proj":
+            corr = pyramid  # the encoder's fused kernel looks up and projects
         else:
             # the lookup runs in f32 (coords + pyramid precision); under
             # bf16 mode its (B,H,W,324) output and the flow join the hidden
@@ -229,10 +226,18 @@ class UpdateIter(nn.Module):
             # dtype. coords stay f32 through the carry: delta promotes back
             # on add.
             with jax.named_scope("lookup"):
-                corr = corr_lookup(pyramid, coords1,
-                                   packed_meta=self.corr_meta
-                                   ).astype(net.dtype)
-            motion = BasicMotionEncoder(name="encoder")(flow, corr)
+                if self.lookup.impl == "level":
+                    corr = lookup_kernels.corr_lookup_pallas(
+                        pyramid, coords1, CORR_RADIUS,
+                        interpret=interpret_mode())
+                elif self.lookup.impl == "onehot":
+                    corr = lookup_kernels.corr_lookup_onehot(
+                        pyramid, coords1, CORR_RADIUS)
+                else:
+                    corr = corr_lookup_gather(pyramid, coords1)
+                corr = corr.astype(net.dtype)
+        motion = BasicMotionEncoder(lookup=self.lookup, name="encoder")(
+            flow, corr, coords1)
         x = jnp.concatenate([inp, motion], axis=-1)
         net = SepConvGRU(name="gru")(net, x)
         delta = FlowHead(name="flow_head")(net)
@@ -316,181 +321,23 @@ def build_corr_pyramid(fmap1: jnp.ndarray, fmap2: jnp.ndarray,
     return pyramid
 
 
-def _fused_supported(pyramid: Sequence[jnp.ndarray]) -> bool:
-    from ..kernels.corr_lookup import fused_lookup_supported
-    return fused_lookup_supported(pyramid)
-
-
-def _pallas_supported(pyramid: Sequence[jnp.ndarray]) -> bool:
-    from ..kernels.corr_lookup import pallas_lookup_supported
-    return pallas_lookup_supported(pyramid)
-
-
-#: process-level corr-lookup dispatch defaults, set from CONFIG KEYS
-#: (``corr_lookup_impl`` / ``fuse_convc1`` in raft.yml / i3d.yml) at
-#: extractor init via :func:`configure_corr_lookup` — i.e. before the
-#: first traced forward, by construction
-_CORR_CONFIG: dict = {"impl": None, "fuse_convc1": None}
-
-_CORR_IMPLS = ("gather", "onehot", "pallas", "packed")
-
-
-def configure_corr_lookup(impl=None, fuse_convc1=None) -> None:
-    """Install the config-level corr-lookup dispatch choice.
-
-    Called by the RAFT-bearing extractors at init with the validated
-    ``corr_lookup_impl``/``fuse_convc1`` config keys. ``None`` leaves a
-    knob at its platform auto choice (Pallas+fused on TPU, gather
-    elsewhere). The ``VFT_CORR_LOOKUP``/``VFT_FUSE_CONVC1`` env vars
-    remain the highest-precedence override — for trace-time perf probes
-    (scripts/bench_i3d_variants.py A/Bs) — but config, not environment,
-    is now the supported interface, and it is applied before anything
-    can have been traced."""
-    if impl is not None:
-        if impl not in _CORR_IMPLS:
-            raise ValueError(f"corr_lookup_impl={impl!r}: expected one of "
-                             f"{_CORR_IMPLS} or null (auto)")
-        _CORR_CONFIG["impl"] = impl
-    if fuse_convc1 is not None:
-        _CORR_CONFIG["fuse_convc1"] = bool(fuse_convc1)
-
-
-def _corr_impl() -> str:
-    """Corr-lookup implementation choice, resolved at trace time:
-    env override > config key (configure_corr_lookup) > platform auto."""
-    import os
-    impl = os.environ.get("VFT_CORR_LOOKUP", "").strip().lower()
-    if not impl:
-        impl = _CORR_CONFIG["impl"] or (
-            "pallas" if jax.default_backend() == "tpu" else "gather")
-    if impl not in _CORR_IMPLS:
-        raise ValueError(f"VFT_CORR_LOOKUP={impl!r}: expected "
-                         "'gather', 'onehot', 'pallas' or 'packed'")
-    return impl
-
-
-def _fuse_convc1() -> bool:
-    """Fused lookup+convc1 kernel switch on the pallas path (default ON;
-    false opts out to the per-level unfused kernels — the round-3
-    configuration, kept for A/B). Same precedence as :func:`_corr_impl`:
-    env override > config key > auto."""
-    import os
-    env = os.environ.get("VFT_FUSE_CONVC1", "").strip().lower()
-    if env:
-        return env not in ("0", "false", "no")
-    cfg = _CORR_CONFIG["fuse_convc1"]
-    return True if cfg is None else cfg
-
-
-def corr_lookup_plan() -> Dict[str, Any]:
-    """What the next traced forward dispatches, as far as config, env
-    override and backend decide it: ``impl``; ``fused`` (convc1 inside
-    the lookup kernel); ``compiled`` — True when ``pallas_call`` goes to
-    Mosaic, False when it runs in the Pallas interpreter (off-TPU), None
-    for the XLA implementations. Feature values are the same on every
-    branch, so the branch has to be stated: no output check can tell.
-    The geometry-dependent size gates add a ``fallback`` at trace time
-    (:func:`_state_corr_lookup`)."""
-    from ..kernels import interpret_mode
-    impl = _corr_impl()
-    kernel = impl in ("pallas", "packed")
-    return {"impl": impl, "fused": impl == "pallas" and _fuse_convc1(),
-            "compiled": (not interpret_mode()) if kernel else None}
-
-
-def announce_corr_lookup(who: str) -> None:
-    """One line at extractor init (the RAFT-bearing extractors call it
-    after :func:`configure_corr_lookup`)."""
-    plan = corr_lookup_plan()
-    how = {True: "compiled by Mosaic", False: "in the Pallas interpreter "
-           "(no TPU backend)", None: "plain XLA"}[plan["compiled"]]
-    print(f"{who}: corr lookup impl={plan['impl']}"
-          f"{' fused with convc1' if plan['fused'] else ''}, {how}")
-
-
-def _state_corr_lookup(plan: Dict[str, Any],
-                       fallback: Optional[str] = None) -> None:
-    """Trace-time record of the lookup a forward was built with: a
-    ``corr_lookup`` event on the current video span (telemetry=true),
-    and a printed line whenever a size gate replaced the planned kernel.
-    Runs once per traced shape, not per call."""
+def _state_corr_lookup(form: LookupForm) -> None:
+    """Trace-time record of the lookup a forward was built with. Feature
+    values are the same on every form, so the form has to be stated: no
+    output check can tell. A ``corr_lookup`` event on the current video
+    span (telemetry=true) with ``impl``, ``compiled`` (True when
+    ``pallas_call`` goes to Mosaic, False when it runs in the Pallas
+    interpreter off a TPU, None for the XLA forms) and ``fallback``; and a
+    printed line whenever a size gate replaced ``proj``. Runs once per
+    traced shape, not per call."""
     from .. import telemetry
-    telemetry.event("corr_lookup", **plan, fallback=fallback)
-    if fallback is not None:
-        print(f"corr lookup: impl={plan['impl']} fell back to {fallback}")
-
-
-def corr_lookup(pyramid: Sequence[jnp.ndarray], coords: jnp.ndarray,
-                radius: int = CORR_RADIUS,
-                packed_meta: Optional[Tuple[Any, ...]] = None) -> jnp.ndarray:
-    """Windowed bilinear lookup — implementation dispatcher.
-
-    ``packed_meta`` not None means ``pyramid`` holds lane-dense-packed
-    levels (kernels/corr_lookup.py pack_pyramid) and routes straight to the
-    fused Pallas kernel — the RAFT scan path, where the pack is hoisted out
-    of the 20-iteration GRU loop.
-
-    The ``corr_lookup_impl`` CONFIG key selects ``gather``, ``onehot``,
-    ``pallas`` or ``packed`` (kernels/corr_lookup.py; ``packed`` is the
-    lane-dense fused-kernel alternative kept as a measured negative
-    result — ~10% slower end-to-end than ``pallas`` on v5e despite 5.8x
-    fewer DMA bytes). Unset picks ``pallas`` on TPU and ``gather``
-    elsewhere. The key is validated at launch (config.sanity_check) and
-    installed at extractor init (:func:`configure_corr_lookup`) — before
-    the first traced forward, so there is no set-before-first-trace
-    ordering to get wrong. ``VFT_CORR_LOOKUP`` remains the
-    highest-precedence override for in-process perf probes.
-
-    Measured END-TO-END on a TPU v5e before PR 0, with a D2H-fenced timer
-    (parallel/mesh.py settle), on an installation that no longer exists:
-    full 20-iteration RAFT forward, 16 pairs @224px: gather 4,097 ms,
-    one-hot 331 ms, fused Pallas 200 ms. The scalar-indexed corner gathers
-    are a catastrophic access pattern for the TPU's vector memory; the
-    MXU contraction forms are 12-20x faster, so Pallas is the TPU default
-    and gather remains the parity/debug path (and the CPU default, where
-    XLA lowers it well).
-
-    Checked compiled on the chip by chip_smoke.py (stage 5) at the /8
-    geometries the system produces — (30, 40), (28, 28), (8, 8),
-    (55, 128): the fused projection kernel and the per-level kernel sit
-    within 1e-4 of their XLA twins under the extractors' precision=float32
-    matmul-precision pin. Under precision=bfloat16 the contraction
-    legitimately drifts ~8e-3 (MXU bf16), which is that mode's
-    contract."""
-    impl = _corr_impl()
-    if packed_meta is not None:
-        from ..kernels import interpret_mode
-        from ..kernels.corr_lookup import corr_lookup_packed
-        return corr_lookup_packed(pyramid, packed_meta, coords, radius,
-                                  interpret=interpret_mode())
-    if impl == "onehot":
-        from ..kernels.corr_lookup import corr_lookup_onehot
-        return corr_lookup_onehot(pyramid, coords, radius)
-    if impl in ("pallas", "packed"):
-        supported = (_pallas_supported(pyramid) if impl == "pallas"
-                     else _fused_supported(pyramid))
-        if not supported:
-            # planes too large for any legal VMEM tile (inputs ~>5800 px on
-            # a side): the XLA one-hot twin has identical numerics and no
-            # tiling constraint
-            hl, wl = pyramid[0].shape[2:]
-            _state_corr_lookup(
-                corr_lookup_plan(),
-                fallback=f"onehot (XLA): a {hl}x{wl} level-0 plane fits no "
-                         "legal VMEM tile")
-            from ..kernels.corr_lookup import corr_lookup_onehot
-            return corr_lookup_onehot(pyramid, coords, radius)
-        from ..kernels import interpret_mode
-        if impl == "packed":
-            from ..kernels.corr_lookup import pack_pyramid
-            packed, metas = pack_pyramid(pyramid)
-            from ..kernels.corr_lookup import corr_lookup_packed
-            return corr_lookup_packed(packed, metas, coords, radius,
-                                      interpret=interpret_mode())
-        from ..kernels.corr_lookup import corr_lookup_pallas
-        return corr_lookup_pallas(pyramid, coords, radius,
-                                  interpret=interpret_mode())
-    return corr_lookup_gather(pyramid, coords, radius)
+    kernel = form.impl in ("proj", "level")
+    telemetry.event("corr_lookup", impl=form.impl,
+                    compiled=(not interpret_mode()) if kernel else None,
+                    fallback=form.fallback)
+    if form.fallback is not None:
+        print(f"corr lookup: impl={form.impl} in place of proj: "
+              f"{form.fallback}")
 
 
 def corr_lookup_gather(pyramid: Sequence[jnp.ndarray], coords: jnp.ndarray,
@@ -597,15 +444,10 @@ class RAFT(nn.Module):
     (well under the I3D flow stream's ToUInt8 quantization step of ~0.16);
     the f32 default is bit-identical to before (every cast is a no-op).
 
-    Precision/perf record: bf16 mode measured +7.5% on the I3D RGB+Flow
-    step in round 3 (3.95 -> 4.25 stacks/s, v5e) — the conv stacks go
-    MXU-native while the lookup cost is unchanged (it is selection-bound,
-    kernels/corr_lookup.py). A bf16 corr PYRAMID was measured twice and
-    rejected twice: 0.87x in round 2 (in-kernel upcast outweighed the DMA
-    saving), and moot in round 3 — the lane-dense repack proved lookup
-    DMA bytes are not the binding constraint at all, so halving them buys
-    nothing. The pyramid stays f32 in every mode, which also keeps lookup
-    values exact."""
+    The pyramid stays f32 in every mode: the lookup is bound by per-query
+    selection work, not by the bytes it reads (kernels/corr_lookup.py), so
+    a bf16 pyramid buys nothing and would cost the lookup its exact
+    values."""
     iters: int = ITERS
     dtype: Any = jnp.float32
 
@@ -625,50 +467,13 @@ class RAFT(nn.Module):
             fmap1, fmap2 = jnp.split(fmaps, 2, axis=0)
         with jax.named_scope("corr_pyramid"):
             pyramid = build_corr_pyramid(fmap1, fmap2)
-        corr_meta = None
-        fuse_meta = None
-        plan = corr_lookup_plan()
-        impl = plan["impl"]
-        fallback = None
-        with jax.named_scope("corr_pyramid"):
-            if impl == "pallas" and _pallas_supported(pyramid):
-                # tile-align the loop-invariant pyramid ONCE, outside the scan:
-                # the pallas lookup needs (8, 128)-aligned level planes, and XLA
-                # does not hoist the pads out of the while body — unhoisted they
-                # ran 20x per forward and cost ~30% of the whole RAFT step
-                # (kernels/corr_lookup.py align_level; zero pads are exactly the
-                # reference's out-of-range zeros rule). What is hoisted is all
-                # of the stage now: four small dots, a re-tiling of each
-                # true-size level and these pads; the volume is never pooled
-                from ..kernels.corr_lookup import (align_level,
-                                                   proj_lookup_supported,
-                                                   stack_aligned_pyramid)
-                if plan["fused"] and proj_lookup_supported(pyramid):
-                    # round-4 default: ONE kernel serves all four levels AND
-                    # the motion encoder's convc1 — the 324-channel lookup
-                    # intermediate (and its relayout boundary) never exists
-                    pyramid, fuse_meta = stack_aligned_pyramid(pyramid)
-                else:
-                    if plan["fused"]:
-                        hl, wl = pyramid[0].shape[2:]
-                        fallback = (f"the unfused per-level kernels: the stacked "
-                                    f"{hl}x{wl} pyramid plane fits no legal VMEM "
-                                    "tile")
-                    pyramid = tuple(align_level(c) for c in pyramid)
-                # (measured, not kept as default: a lane-DENSE packed pyramid
-                # moves 5.8x fewer bytes but lands ~10% slower end-to-end —
-                # the lookup is selection-bound, not DMA-bound. The packed
-                # kernel stays available as VFT_CORR_LOOKUP=packed; the
-                # negative-result record lives in kernels/corr_lookup.py.)
-            elif impl == "packed" and _fused_supported(pyramid):
-                # lane-dense-pack ONCE outside the scan; ONE fused kernel
-                # serves all four levels per iteration
-                from ..kernels.corr_lookup import pack_pyramid
-                pyramid, corr_meta = pack_pyramid(pyramid)
-        # (an unsupported pallas/packed pyramid stays raw; corr_lookup's own
-        # size gate states its one-hot fallback when the scan body traces)
-        _state_corr_lookup({**plan, "fused": fuse_meta is not None},
-                           fallback)
+            # the lookup's one decision, and the loop-invariant pads it
+            # brings, ONCE outside the scan (kernels/corr_lookup.py
+            # prepare_lookup). With them the stage is four small dots, a
+            # re-tiling of each true-size level and the pads; the volume is
+            # never pooled
+            pyramid, lookup = lookup_kernels.prepare_lookup(pyramid)
+        _state_corr_lookup(lookup)
 
         with jax.named_scope("encode"):
             cnet = BasicEncoder(HIDDEN_DIM + CONTEXT_DIM, "batch",
@@ -688,8 +493,7 @@ class RAFT(nn.Module):
             scanned = nn.scan(
                 UpdateIter, variable_broadcast="params",
                 split_rngs={"params": False}, in_axes=nn.broadcast,
-                length=self.iters)(corr_meta=corr_meta, fuse_meta=fuse_meta,
-                                   name="update_block")
+                length=self.iters)(lookup=lookup, name="update_block")
             (net, coords1), _ = scanned((net, coords0),
                                         (pyramid, inp, coords0))
 

@@ -156,7 +156,7 @@ def settle(out: Any) -> float:
 
     A host read of the output cannot return before the output exists, and
     the device's in-order queue makes it fence every prior dispatch. Used
-    by bench.py and scripts/bench_i3d_variants.py.
+    by bench.py.
     """
     return float(sum(np.asarray(x).sum()
                      for x in jax.tree_util.tree_leaves(out)))

@@ -245,7 +245,8 @@ def stage_raft(stage_dir: Path, platform: str,
                if ev.get("kind") == "corr_lookup"]
     check(lookups, "no corr_lookup event in _telemetry.jsonl")
     facts["corr_lookup"] = [{k: ev.get(k) for k in
-                             ("impl", "compiled", "fallback")}
+                             ("impl", "compiled", "fallback",
+                              "plane_cells", "plane_fill")}
                             for ev in lookups]
     if platform == "tpu":
         check(all(ev.get("impl") == "proj"
@@ -342,8 +343,11 @@ def _kernel_check(h8: int, w8: int, pairs: int, where: str,
             cl.corr_lookup_pallas(pyramid, coords, interpret=interpret),
             cl.corr_lookup_onehot(pyramid, coords)),
     }
-    # what a forward at this geometry runs on this backend: proj on the chip
-    rec["impl"] = cl.prepare_lookup(pyramid)[1].impl
+    # what a forward at this geometry runs on this backend: proj on the
+    # chip, over a plane of so many cells a query (the shelf rule's answer)
+    form = cl.prepare_lookup(pyramid)[1]
+    rec["impl"] = form.impl
+    rec["plane_cells"] = cl.plane_fill(form.metas)[0]
     rec["ok"] = interpret or rec["impl"] == "proj"
     for name, run in checks.items():
         try:
@@ -485,7 +489,8 @@ def parent(ap: argparse.ArgumentParser, opts: argparse.Namespace) -> int:
     print(f"result: {RESULT}", flush=True)
     for rec in records:
         for k in rec.get("kernels", []):
-            print(f"kernel {k['geometry']} x{k['pairs']} impl={k.get('impl')}: "
+            print(f"kernel {k['geometry']} x{k['pairs']} impl={k.get('impl')} "
+                  f"plane_cells={k.get('plane_cells')}: "
                   f"proj={k.get('proj_max_abs', k.get('proj_error'))} "
                   f"level={k.get('level_max_abs', k.get('level_error'))}",
                   flush=True)

@@ -243,7 +243,8 @@ def test_stacks_per_forward_geometry_budget():
     budget = _flow_pyramid_budget(jax.devices()[0])
     assert budget == 7 * 1024 ** 3  # the CPU backend reports no HBM
     assert _stacks_per_forward(64, 224, 224, budget) == 4
-    assert _stacks_per_forward(64, 256, 454, budget) == 1  # 3.8 GB/stack
+    assert _stacks_per_forward(64, 256, 454, budget) == 2  # 1.9 GB/stack
+    assert _stacks_per_forward(64, 436, 1024, budget) == 1  # 20 GB/stack
     assert _stacks_per_forward(16, 64, 64, budget) == 4    # cap wins
 
 

@@ -129,7 +129,7 @@ def _pooled_volume_pyramid(f1, f2, levels=4):
 #: 0-sized level; channels 256 and 64 have a power-of-two root (f1 is
 #: scaled), 48 has not (the result is)
 PYRAMID_CASES = [(30, 40, 256), (28, 28, 256), (16, 20, 48), (55, 128, 64),
-                 (7, 9, 256)]
+                 (7, 9, 256), (8, 8, 64)]
 
 
 @pytest.fixture(scope="module",
@@ -162,17 +162,31 @@ def test_corr_pyramid_equals_the_pooled_volume(both_pyramids):
 
 
 def test_stacked_plane_of_the_pyramid_has_exact_zero_pads(both_pyramids):
-    """The plane the fused lookup reads: equal to the pooled volume's, and
-    exactly zero in every pad cell (the reference's out-of-range rule)."""
-    from video_features_tpu.kernels.corr_lookup import stack_aligned_pyramid
+    """The plane the fused lookup reads: every level's cells where its
+    placement says, equal to the pooled volume's, and exactly zero in every
+    other cell (the reference's out-of-range rule, and what keeps a window
+    off the level beside it); as many cells as ``stacked_plane_cells``
+    states."""
+    from video_features_tpu.kernels.corr_lookup import (
+        stack_aligned_pyramid, stacked_plane_cells)
     got, want, top = both_pyramids
     plane, metas = stack_aligned_pyramid(got)
     want_plane, want_metas = stack_aligned_pyramid(want)
     assert metas == want_metas and plane.shape == want_plane.shape
     assert float(jnp.max(jnp.abs(plane - want_plane))) <= 1e-6 * top
-    cells, _ = stack_aligned_pyramid([jnp.ones_like(w) for w in want])
-    pads = np.asarray(cells) == 0
-    assert pads.any() and not np.asarray(plane)[pads].any()
+    h8, w8 = want[0].shape[2:]
+    assert plane.shape[2] * plane.shape[3] == stacked_plane_cells(h8, w8)
+    assert plane.shape[2] % 8 == 0 and plane.shape[3] % 128 == 0
+    data = np.zeros(plane.shape[2:], bool)
+    for m, level in zip(metas, got):
+        assert (m.rows, m.width) == level.shape[2:] and m.row_off % 8 == 0
+        cells = (slice(m.row_off, m.row_off + m.rows),
+                 slice(m.lane_off, m.lane_off + m.width))
+        assert not data[cells].any()  # no two levels share a cell
+        data[cells] = True
+        np.testing.assert_array_equal(np.asarray(plane)[0, :, *cells],
+                                      np.asarray(level)[0])
+    assert not data.all() and not np.asarray(plane)[:, :, ~data].any()
 
 
 def test_corr_lookup_proj_on_the_pyramid_matches_the_pooled_volume(
@@ -250,11 +264,129 @@ def test_corr_lookup_proj_degenerate_pyramid(rng):
     assert (1, 1) in shapes and (0, 0) in shapes, shapes
     wgt, bias = _proj_weight(rng)
     stacked, metas = stack_aligned_pyramid(pyramid)
-    assert metas[-1].hlp == 0
+    assert metas[-1].rows == 0 and metas[-1].width == 0
     ref = np.asarray(corr_lookup_proj_ref(pyramid, coords, wgt, bias))
     ours = np.asarray(corr_lookup_proj(stacked, metas, coords, wgt, bias,
                                        interpret=True))
     np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
+
+
+#: /8 geometry -> the (rows, lanes) of the plane the shelf rule answers:
+#: one shelf while level 0 is at most half the lane width, level 0 alone
+#: above one shelf of levels 1-3 when it fills it
+PLANES = {(30, 40): (32, 128), (28, 28): (32, 128), (8, 8): (8, 128),
+          (7, 9): (8, 128), (6, 5): (8, 128), (55, 128): (56 + 32, 128),
+          (135, 240): (136 + 72, 256), (200, 256): (200 + 104, 256),
+          (270, 480): (272 + 136, 512)}
+
+
+@pytest.mark.parametrize("h8, w8", sorted(PLANES))
+def test_stacked_plane_cells_is_the_built_plane_s(h8, w8):
+    """The one owner of the geometry (the VMEM gate and the flow stream's
+    HBM budget read it) states the cells of the plane that
+    ``stack_aligned_pyramid`` builds; every level lies inside the plane on
+    an 8-sublane shelf and no two share a cell. Shapes only."""
+    from video_features_tpu.kernels import corr_lookup as cl
+    raw = [jax.ShapeDtypeStruct((1, 4, h8 >> i, w8 >> i), jnp.float32)
+           for i in range(4)]
+    found = []
+
+    def built(*levels):
+        plane, metas = cl.stack_aligned_pyramid(levels)
+        found.append(metas)
+        return plane
+
+    plane = jax.eval_shape(built, *raw)
+    (metas,) = found
+    assert plane.shape == (1, 4) + PLANES[h8, w8]
+    assert cl.stacked_plane_cells(h8, w8) == plane.shape[2] * plane.shape[3]
+    assert metas == cl.place_levels([r.shape[2:] for r in raw])[0]
+    held = np.zeros(plane.shape[2:], int)
+    for m, r in zip(metas, raw):
+        assert (m.rows, m.width) == r.shape[2:] and m.row_off % 8 == 0
+        assert m.row_off + m.rows <= plane.shape[2]
+        assert m.lane_off + m.width <= plane.shape[3]
+        held[m.row_off:m.row_off + m.rows,
+             m.lane_off:m.lane_off + m.width] += 1
+    assert held.max() == 1
+    cells, fill = cl.plane_fill(metas)
+    assert cells == held.size and fill == held.sum() / held.size
+
+
+def test_the_shelf_rule_stacks_levels_that_fit_no_lane_width_together():
+    """Where no two levels fit side by side the rule's answer is the plain
+    sublane stack: every level at lane 0 under the one before it."""
+    from video_features_tpu.kernels.corr_lookup import ProjMeta, place_levels
+    metas, plane = place_levels([(30, 128), (15, 100), (7, 90), (3, 80)])
+    assert metas == (ProjMeta(0, 30, 0, 128), ProjMeta(32, 15, 0, 100),
+                     ProjMeta(48, 7, 0, 90), ProjMeta(56, 3, 0, 80))
+    assert plane == (64, 128)
+
+
+#: the level shapes of a /8 geometry, looked up by a handful of queries:
+#: the kernel is per query, so the edge cases need no h8*w8 of them
+EDGE_GEOMETRIES = [(30, 40), (28, 28), (8, 8), (55, 128), (7, 9), (6, 5)]
+
+
+def _edge_coords(rng, h8, w8):
+    """(1, 8, 16, 2) centres: a row along each edge of level 0 and up to
+    12 cells outside it on every side (a window is 9 taps wide at every
+    level, so each level's window hangs over its left, right and bottom
+    edge somewhere), the rest anywhere in that range."""
+    xy = rng.uniform(-12.0, [w8 + 12.0, h8 + 12.0],
+                     size=(8, 16, 2)).astype(np.float32)
+    xs = np.linspace(-12.0, w8 + 12.0, 16, dtype=np.float32)
+    ys = np.linspace(-12.0, h8 + 12.0, 16, dtype=np.float32)
+    xy[0, :, 0], xy[0, :, 1] = xs, h8 - 1.0       # along the bottom edge
+    xy[1, :, 0], xy[1, :, 1] = xs, h8 + 2.5       # under it
+    xy[2, :, 0], xy[2, :, 1] = 0.0, ys            # along the left edge
+    xy[3, :, 0], xy[3, :, 1] = w8 - 1.0, ys       # along the right edge
+    xy[4, :, 0], xy[4, :, 1] = w8 + 3.25, ys      # right of it
+    return jnp.asarray(xy[None])
+
+
+@pytest.mark.parametrize("h8, w8", EDGE_GEOMETRIES)
+def test_corr_lookup_proj_matches_its_twins_past_every_edge(rng, h8, w8):
+    """The kernel (interpreter) over the shelf plane against the XLA
+    composition and against the reference's gather, centres up to 12 cells
+    outside level 0 on every side."""
+    from video_features_tpu.kernels.corr_lookup import (
+        corr_lookup_proj, corr_lookup_proj_ref, stack_aligned_pyramid)
+    coords = _edge_coords(rng, h8, w8)
+    pyramid = [jnp.asarray(rng.normal(size=(1, 128, h8 >> i, w8 >> i))
+                           .astype(np.float32)) for i in range(4)]
+    wgt, bias = _proj_weight(rng)
+    ours = np.asarray(corr_lookup_proj(*stack_aligned_pyramid(pyramid),
+                                       coords, wgt, bias, interpret=True))
+    ref = np.asarray(corr_lookup_proj_ref(pyramid, coords, wgt, bias))
+    gathered = np.asarray(jax.nn.relu(jnp.einsum(
+        "bhwk,kc->bhwc", corr_lookup_gather(pyramid, coords), wgt) + bias))
+    np.testing.assert_allclose(ours, ref, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(ours, gathered, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("level", range(4))
+@pytest.mark.parametrize("h8, w8", EDGE_GEOMETRIES)
+def test_a_window_past_a_level_s_edge_reads_zeros_not_its_neighbour(
+        rng, h8, w8, level):
+    """Only ``level`` holds anything (7.0 in every cell) and the projection
+    ignores that level's own taps: every other level's window, wherever it
+    hangs over ``level``'s cells in the plane, must weigh them by exactly
+    0, so the kernel returns relu(bias) to the bit."""
+    from video_features_tpu.kernels.corr_lookup import (
+        corr_lookup_proj, stack_aligned_pyramid)
+    coords = _edge_coords(rng, h8, w8)
+    pyramid = [jnp.full((1, 128, h8 >> i, w8 >> i),
+                        7.0 if i == level else 0.0, jnp.float32)
+               for i in range(4)]
+    wgt, bias = _proj_weight(rng)
+    wgt = wgt.at[level * 81:(level + 1) * 81].set(0.0)
+    plane, metas = stack_aligned_pyramid(pyramid)
+    assert float(plane.sum()) == 7.0 * pyramid[level].size
+    ours = np.asarray(corr_lookup_proj(plane, metas, coords, wgt, bias,
+                                       interpret=True))
+    np.testing.assert_array_equal(
+        ours, np.broadcast_to(np.maximum(np.asarray(bias), 0.0), ours.shape))
 
 
 @pytest.mark.parametrize("backend, h8, w8, impl, fallback", [
@@ -262,8 +394,11 @@ def test_corr_lookup_proj_degenerate_pyramid(rng):
     ("tpu", 30, 40, "proj", None),
     ("tpu", 28, 28, "proj", None),
     ("tpu", 55, 128, "proj", None),
-    # 1080x1920: the stacked plane (264 x 256 cells) passes the 8-query tile
-    ("tpu", 135, 240, "level", "stacked 135x240 pyramid plane"),
+    # 1080x1920: levels 1-3 share a shelf under level 0, 208 x 256 cells,
+    # inside the 8-query tile that the stack of 264 rows was not
+    ("tpu", 135, 240, "proj", None),
+    # 1600x2048: shelves of 200 + 104 rows x 256 lanes pass the 8-query tile
+    ("tpu", 200, 256, "level", "stacked 200x256 pyramid plane"),
     # 2160x3840: no 8-query tile holds even the level-0 plane
     ("tpu", 270, 480, "onehot", "270x480 level-0 plane"),
 ])
@@ -290,11 +425,8 @@ def test_prepare_lookup_decides_from_backend_and_geometry(
         else fallback in form.fallback
     hash(form)  # static: RAFT's scan body carries it as a module field
     if impl == "proj":
-        assert out.shape == (1, h8 * w8,
-                             cl.stacked_plane_cells(h8, w8) // out.shape[3],
-                             -(-w8 // 128) * 128)
-        assert [m.off for m in form.metas] == list(np.cumsum(
-            [0] + [m.hlp for m in form.metas[:-1]]))
+        assert out.shape == (1, h8 * w8) + PLANES[h8, w8]
+        assert form.metas == cl.place_levels([r.shape[2:] for r in raw])[0]
     elif impl == "level":
         assert form.metas == ()
         assert [o.shape[2:] for o in out] == [

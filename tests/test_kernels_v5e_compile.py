@@ -1,0 +1,56 @@
+"""The fused lookup kernel compiled for a described v5e, no chip attached.
+
+Interpret mode (tests/test_kernels.py) checks values; it cannot see what the
+chip's compiler refuses: a slice off the tiling, a block or a spill area
+past VMEM. The TPU's compiler is installed here and compiles for a chip
+that is described and not attached, about two seconds a kernel, so the
+geometries at which ``place_levels`` answers differently are held here at
+their real sizes. Nothing runs: no value and no time comes from this file.
+
+The topology is described inside a fixture and nowhere while a module is
+imported: one process at a time may load the TPU's library, and under
+several workers only the one that is given this file may load it.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from video_features_tpu.kernels import corr_lookup as cl
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+#: (h8, w8, pairs): the benchmark cell's 240x320 at its batch, the flow
+#: stream's 224x224 at four stacks, Sintel's 436x1024 (level 0 alone on
+#: its shelf), 1080x1920 (two shelves of 256 lanes, an 8-query tile) and
+#: a 64x64 input whose plane is one 8-row shelf
+@pytest.mark.parametrize("h8, w8, pairs", [
+    (30, 40, 128), (28, 28, 256), (55, 128, 16), (135, 240, 1), (8, 8, 16)])
+def test_proj_kernel_compiles_for_a_v5e(one_chip, h8, w8, pairs):
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    levels = [(h8 >> i, w8 >> i) for i in range(4)]
+    metas, (rows, lanes) = cl.place_levels(levels)
+    assert cl.proj_lookup_supported(
+        [jax.ShapeDtypeStruct((1, 1) + lv, jnp.float32) for lv in levels])
+    q = pairs * h8 * w8
+    compiled = cl._corr_lookup_proj_flat.lower(
+        spec(1, q, rows, lanes), metas, spec(1, q, 2), spec(324, 256),
+        spec(256)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"f32[1,{q},256]" in text

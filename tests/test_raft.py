@@ -229,19 +229,22 @@ def test_fused_convc1_path_matches_default(rng, monkeypatch):
             out = np.asarray(model.apply({"params": params}, x1, x2))
         (event,) = [e for e in span.record["events"]
                     if e["kind"] == "corr_lookup"]
-        return out, event["impl"]
+        return out, event
 
-    want, impl = flow()
-    assert impl == "gather"
+    want, event = flow()
+    assert (event["impl"], event["plane_cells"], event["plane_fill"]) \
+        == ("gather", None, None)
     with monkeypatch.context() as m:
         _as_on_a_tpu(m)
-        got, impl = flow()
-    assert impl == "proj"
+        got, event = flow()
+    # a 64 x 72 input: levels 8x9, 4x4, 2x2 and 1x1 on one 8 x 128 shelf
+    assert (event["impl"], event["plane_cells"], event["plane_fill"]) \
+        == ("proj", 1024, (72 + 16 + 4 + 1) / 1024)
     np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-3)
     with monkeypatch.context() as m:
         _as_on_a_tpu(m, proj_fits=False)
-        unfused, impl = flow()
-    assert impl == "level"
+        unfused, event = flow()
+    assert (event["impl"], event["plane_cells"]) == ("level", None)
     np.testing.assert_allclose(unfused, want, atol=1e-3, rtol=1e-3)
 
 

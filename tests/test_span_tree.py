@@ -550,8 +550,8 @@ def test_pallas_kernels_carry_their_name(kernel, name):
         def fn():
             return k.corr_lookup_pallas(pyramid, coords, 4, interpret=True)
     else:
-        aligned = [k.align_level(c) for c in pyramid]
-        stacked, meta = k.stack_aligned_pyramid(aligned)
+        stacked, meta = k.stack_aligned_pyramid(pyramid)
+        assert stacked.shape == (b, h * w, 16, 128)  # one shelf of levels
 
         def fn():
             return k.corr_lookup_proj(stacked, meta, coords,

@@ -61,10 +61,11 @@ def _pwc_quantized_flow(model, crop: int, params, pairs_u8):
 
 
 #: Share of device memory one pair-batch forward's correlation pyramid may
-#: take — the dominant RAFT allocation, (pairs, P, Hsum, Wp) f32
+#: take — the dominant RAFT allocation, (pairs, P, rows, lanes) f32
 #: (kernels/corr_lookup stack_aligned_pyramid). 7/16 of a 16 GB v5e picks 4
-#: stacks/forward at the 224px flagship geometry (6.6 GB, measured fine
-#: incl. towers) and scales down for larger source resolutions.
+#: stacks/forward at the 224px flagship geometry (3.3 GB since the levels
+#: share one 32 x 128 shelf; 6.6 GB measured fine incl. towers before) and
+#: scales down for larger source resolutions.
 _FLOW_PYRAMID_SHARE = 7 / 16
 
 #: what the CPU backend (tests, device=cpu), which reports no memory

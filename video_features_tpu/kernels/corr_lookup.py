@@ -28,11 +28,12 @@ no config key, environment variable or caller selects a form):
     corr_lookup_gather): every backend but the TPU, and the parity
     reference of the tests;
   - ``proj`` — :func:`corr_lookup_proj`: ONE Pallas kernel for all levels
-    over a sublane-stacked plane, fused with the motion encoder's 1x1
-    ``convc1``. The TPU's form wherever the stacked plane fits a VMEM tile;
+    over one plane a query in which the levels sit side by side
+    (:func:`place_levels`), fused with the motion encoder's 1x1
+    ``convc1``. The TPU's form wherever that plane fits a VMEM tile;
   - ``level`` — :func:`corr_lookup_pallas`: one Pallas kernel a level over
-    lane-padded planes. The TPU's form once the stacked plane is too large
-    (a 1080x1920 input) while each level still fits;
+    lane-padded planes. The TPU's form once the one plane is too large
+    (a 1600x2048 input) while each level still fits;
   - ``onehot`` — :func:`corr_lookup_onehot`: the formulation above in plain
     XLA, no tiling constraint. The TPU's form past both size gates, and the
     kernels' twin in the tests and on the chip (chip_smoke.py stage 5:
@@ -41,14 +42,32 @@ no config key, environment variable or caller selects a form):
     pin; under bfloat16 the contraction drifts ~8e-3, that mode's
     contract).
 
+What bounds ``proj``, measured on a v5e at 240x320 (153,600 queries a
+call, 128 pairs; PERF.md section 6, PR 31). With every level under a
+128-lane pad of its own the plane was 64 x 128 cells, 32,768 B a query of
+which 19% held data, and the kernel took 53.7 ns a query: 610 GB/s, three
+quarters of the chip's 819, so no change to its selectors could gain more
+than a quarter. With the levels on one shelf (32 x 128 cells, 16,384 B,
+39% data) and the same contractions it took 47.8 ns, 342 GB/s: now
+bound by per-query vector work, most of it moving each level's 9 x 9 taps
+from the query's tile into the projection's rows (36 row gathers a
+query), and by spills of the unrolled tile. Sharing the contractions
+among a shelf's levels (9 row gathers, 7 selector tiles for 16) took it
+to 30.0 ns, and one coordinate block for two to 24.8 ns: 662 GB/s, at the
+memory wall of the new layout. A plane that is mostly level 0 (a
+436x1024 input: 82% data) was at that wall before and gains the bytes it
+sheds, no more.
+
 A lane-dense packing of the pyramid (several image rows a 128-lane line,
-5.8x fewer bytes an iteration) was built and lost to the padded planes:
-the lookup is bound by per-query selection work, not by bytes. Commit
-da49f76 is the last that holds it.
+5.8x fewer bytes an iteration) was built in round 3 and lost to the padded
+planes: every selector needed row-in-line arithmetic, at twice today's
+selection cost. Commit da49f76 is the last that holds it. This layout
+keeps one image row a lane line.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -299,141 +318,239 @@ def corr_lookup_pallas(pyramid: Sequence[jnp.ndarray], coords: jnp.ndarray,
 #
 # Round-4 profiling: the four per-level lookup kernels cost ~100 ms of a
 # 215 ms I3D RGB+Flow step and ALL levels cost the same ~25 ms despite
-# 4-64x different plane sizes — the binding cost is per-query work on the
-# 128-lane-padded width (selector build + blend + 9-lane-wide stores),
-# which is level-size independent. Downstream, the (B, H, W, 324) lookup output is a relayout
-# boundary XLA cannot see through (~17 ms/step of reshape passes feeding
-# the motion encoder's convc1, models/raft.py BasicMotionEncoder).
+# 4-64x different plane sizes — each level paid for a 128-lane-padded width
+# of its own (selector build + blend + 9-lane-wide stores). Downstream, the
+# (B, H, W, 324) lookup output is a relayout boundary XLA cannot see through
+# (~17 ms/step of reshape passes feeding the motion encoder's convc1,
+# models/raft.py BasicMotionEncoder).
 #
 # This kernel removes both ends at once:
 #   - the bilinear blend folds INTO the selectors (9 weighted rows instead
 #     of 10 one-hot rows + a 4-corner blend), and
 #   - the motion encoder's convc1 (a 1x1 conv, i.e. a (324, 256) matmul)
-#     folds INTO the kernel as per-level (81, 256) projections of the tap
-#     window, accumulated across levels in VMEM — so the kernel emits the
-#     post-conv (TP, 256) activation (dense, tile-aligned stores) and the
-#     324-channel intermediate never exists.
+#     folds INTO the kernel as one projection of the tap windows off a
+#     VMEM scratch — so the kernel emits the post-conv (TP, 256)
+#     activation (dense, tile-aligned stores) and the 324-channel
+#     intermediate never exists.
 #
-# All four levels ride ONE kernel over a sublane-stacked pyramid plane
-# (one contiguous block DMA per grid step; level planes are static sublane
-# slices). The projection weight is a constant-index block, so Mosaic
-# keeps it resident across grid steps.
+# All four levels ride ONE kernel over ONE pyramid plane a query (one
+# contiguous block DMA per grid step). One image row stays one lane line;
+# where a level sits in the plane is :func:`place_levels`' shelf rule. The
+# projection weight is a constant-index block, so Mosaic keeps it resident
+# across grid steps.
 
 
 class ProjMeta(NamedTuple):
-    """Static geometry of one level inside the sublane-stacked plane."""
-    hlp: int  # lane-padded sublane rows of this level
-    off: int  # sublane offset of this level in the stacked plane
+    """Where one level's (rows, width) data cells sit inside the plane."""
+    row_off: int   # sublane offset of the level's shelf (a multiple of 8)
+    rows: int      # the level's own rows, Hl
+    lane_off: int  # lane offset of the level's first column
+    width: int     # the level's own columns, Wl
+
+
+def _up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def place_levels(shapes: Sequence[Tuple[int, int]]
+                 ) -> Tuple[Tuple[ProjMeta, ...], Tuple[int, int]]:
+    """The plane's geometry, a function of the (Hl, Wl) level shapes alone:
+    ``(metas, (rows, lanes))``. The lane width is the 128-multiple that
+    holds the widest level. A level goes to the right of the one before it
+    while that width has room, else it starts a new shelf below; a shelf is
+    as tall as its tallest level, rounded up to 8 sublanes. Levels that
+    halve each other's sides fill one shelf when level 0 is at most half
+    the lane width ((30, 40), (28, 28): (32, 128), half the cells of one
+    128-lane pad a level); a level 0 that fills the width stands alone
+    above one shelf of levels 1-3 ((55, 128): 56 + 32 rows); when no two
+    levels fit side by side the rule's answer is the plain sublane stack."""
+    lanes = max(128, max(_up(wl, 128) for _, wl in shapes))
+    metas = []
+    row_off = lane = shelf_rows = 0
+    for hl, wl in shapes:
+        if lane + wl > lanes:  # no room beside the level before
+            row_off, lane, shelf_rows = row_off + shelf_rows, 0, 0
+        metas.append(ProjMeta(row_off, hl, lane, wl))
+        lane += wl
+        shelf_rows = max(shelf_rows, _up(hl, 8) if wl else 0)
+    return tuple(metas), (row_off + shelf_rows, lanes)
+
+
+def _shelves(metas: Sequence[ProjMeta]
+             ) -> List[Tuple[int, int, List[Tuple[int, ProjMeta]]]]:
+    """(row_off, rows, [(level index, meta)]) per shelf, top to bottom;
+    levels without a cell (tiny inputs pool to 0x0) are on no shelf."""
+    held = [(lvl, m) for lvl, m in enumerate(metas) if m.rows and m.width]
+    return [(row_off, max(_up(m.rows, 8) for _, m in shelf), shelf)
+            for row_off, shelf in (
+                (off, list(group)) for off, group in itertools.groupby(
+                    held, key=lambda level: level[1].row_off))]
 
 
 def stack_aligned_pyramid(pyramid: Sequence[jnp.ndarray]
                           ) -> Tuple[jnp.ndarray, Tuple[ProjMeta, ...]]:
-    """Align every (B, P, Hl, Wl) level (zero pad: Hl -> 8-multiple, Wl ->
-    128-multiple — the zeros ARE the reference's out-of-range rule, see
-    :func:`align_level`), pad all levels to the widest lane width, and
-    concatenate along sublanes into ONE (B, P, Hsum, Wp) plane. Hoist this
-    OUT of the GRU scan (loop-invariant).
+    """Lay the (B, P, Hl, Wl) levels out in ONE zero-filled (B, P, rows,
+    lanes) plane as :func:`place_levels` says (the zeros ARE the
+    reference's out-of-range rule, see :func:`align_level`, and they keep a
+    level's window from reading its neighbour: every cell between and
+    below the levels of a shelf is a zero). Hoist this OUT of the GRU scan
+    (loop-invariant).
 
-    On a v5e these pads and the concatenate are one fusion that writes the
-    plane in the layout the kernel reads, 0.12 ms a pair at 240x320 — over
-    half of what is left of RAFT's pyramid stage since the levels come from
-    pooled feature maps (models/raft.py build_corr_pyramid). Emitting the
-    plane from ONE dot against a zero-padded stack of the pooled maps was
-    measured and not kept (PR 25): the MXU's result has the query in its
-    rows, so the dot writes (B, h, P, w) and an unnamed 39 MB-a-pair copy
-    re-tiles it — 0.29 ms a pair against this form's 0.22."""
-    aligned = [align_level(c) for c in pyramid]
-    wp = max(c.shape[3] for c in aligned)
-    aligned = [c if c.shape[3] == wp else
-               jnp.pad(c, ((0, 0), (0, 0), (0, 0), (0, wp - c.shape[3])))
-               for c in aligned]
-    metas = []
-    off = 0
-    for c in aligned:
-        metas.append(ProjMeta(c.shape[2], off))
-        off += c.shape[2]
-    return jnp.concatenate(aligned, axis=2), tuple(metas)
+    Every level is padded out to the whole plane and the planes are summed
+    (x + 0 is x): on a v5e that is one fusion over the levels and one copy
+    into the layout the kernel reads — most of what is left of RAFT's
+    pyramid stage since the levels come from pooled feature maps
+    (models/raft.py build_corr_pyramid). Concatenating the levels of a
+    shelf along the lanes compiles to a 75-lane concatenate and a second
+    pass that pads it to 128: 2.24 ms a pair for the whole 240x320 forward
+    against this form's 2.17, with 2.2 GB more of temporaries at 128 pairs
+    (chip runs, PR 31). Emitting the plane from ONE dot against a
+    zero-padded stack of the pooled maps was measured and not kept
+    (PR 25): the MXU's result has the query in its rows, so the dot
+    writes (B, h, P, w) and an unnamed copy re-tiles it."""
+    metas, (rows, lanes) = place_levels([c.shape[2:] for c in pyramid])
+    plane = None
+    for level, m in zip(pyramid, metas):
+        if m.rows == 0 or m.width == 0:
+            continue
+        cells = jnp.pad(level, (
+            (0, 0), (0, 0), (m.row_off, rows - m.row_off - m.rows),
+            (m.lane_off, lanes - m.lane_off - m.width)))
+        plane = cells if plane is None else plane + cells
+    return plane, metas
 
 
 def stacked_plane_cells(h8: int, w8: int, levels: int = 4) -> int:
-    """Per-query cell count (Hsum * Wp) of the plane
+    """Per-query cell count (rows * lanes) of the plane
     :func:`stack_aligned_pyramid` builds for a /8 feature grid of
-    (h8, w8) — each level 8-sublane/128-lane aligned, floor-halved with
-    the odd-drop rule (build_corr_pyramid's torch avg_pool semantics).
-    Shared by the VMEM support gate here and the flow-stream HBM budget
-    (extractors/i3d_flow.py _stacks_per_forward) so the geometry math has
-    exactly one owner."""
-    hsum, wp = 0, 128
-    for _ in range(levels):
-        hsum += -(-h8 // 8) * 8
-        wp = max(wp, -(-w8 // 128) * 128)
-        h8, w8 = h8 // 2, w8 // 2
-    return hsum * wp
+    (h8, w8) — levels floor-halved with the odd-drop rule
+    (build_corr_pyramid's torch avg_pool semantics), placed by
+    :func:`place_levels`. Shared by the VMEM support gate here and the
+    flow-stream HBM budget (extractors/i3d_flow.py _stacks_per_forward) so
+    the geometry math has exactly one owner."""
+    _, (rows, lanes) = place_levels(
+        [(h8 >> lvl, w8 >> lvl) for lvl in range(levels)])
+    return rows * lanes
+
+
+def plane_fill(metas: Sequence[ProjMeta]
+               ) -> Tuple[Optional[int], Optional[float]]:
+    """(cells a query, share of them that hold data) of the plane these
+    placements describe: what the ``corr_lookup`` span event states.
+    (None, None) for the forms that have no plane (no placements)."""
+    if not metas:
+        return None, None
+    _, (rows, lanes) = place_levels([(m.rows, m.width) for m in metas])
+    cells = rows * lanes
+    return cells, sum(m.rows * m.width for m in metas) / cells
 
 
 def proj_lookup_supported(pyramid: Sequence[jnp.ndarray]) -> bool:
     """Whether the fused projection kernel can tile these planes: one
-    stacked-plane block at the 8-query tile floor must fit the probed VMEM
-    budget (same envelope as the per-level kernel)."""
+    plane block at the 8-query tile floor must fit the probed VMEM budget
+    (same envelope as the per-level kernel)."""
     h0, w0 = pyramid[0].shape[2], pyramid[0].shape[3]
     cells = stacked_plane_cells(h0, w0, levels=len(pyramid))
     return 8 * cells * 4 <= _VMEM_BLOCK_BYTES
 
 
-def _proj_kernel(cx_ref, cy_ref, corr_ref, w_ref, b_ref, out_ref, taps_ref,
+def _proj_kernel(c_ref, corr_ref, w_ref, b_ref, out_ref, taps_ref,
                  *, radius: int, metas: Tuple[ProjMeta, ...]):
     """One grid step: TP queries x ALL levels -> relu(lookup @ W + b).
 
-    Block shapes: cx/cy (1, TP, 1, 1) pre-expanded on the host; corr
-    (1, TP, Hsum, Wp) — the stacked plane; w (L*n*n, C) with row order
-    matching the lookup channel order (per level, tap k = xx*n + yy,
-    x-offset slowest — the reference's quirk); b (1, C); out (1, TP, C);
-    taps_ref a (TP, L*n*n) VMEM scratch. The blended windows land in
-    scratch via lane-sliced stores (never HBM), then ONE rank-2
-    (TP, L*n*n) @ (L*n*n, C) matmul projects them — Mosaic's tpu.matmul
-    takes exactly one contracting dim and position-matched batch dims
-    only, so the multi-dim-contraction and batched forms of this
-    projection are unavailable (both probed on hardware)."""
+    Block shapes: c (1, TP, 1, 2), a query's level-0 (x, y) centre in the
+    two lanes of a tile of its own — pre-expanded on the host so no
+    rank-changing relayout happens in-kernel (Mosaic rejects 1D->3D
+    reshapes), and ONE block where cx and cy as (1, TP, 1, 1) blocks each
+    cost a relayout fusion and a strided DMA an iteration (4.8% of the
+    240x320 forward on a v5e); corr
+    (1, TP, rows, lanes) — the plane; w (n*L*n, C) with rows in the
+    scratch's order (x-offset xx slowest, then level, then yy); b (1, C);
+    out (1, TP, C); taps_ref a (TP, n*L*n) VMEM scratch. The blended
+    windows land in scratch via lane-sliced stores (never HBM), then ONE
+    rank-2 (TP, n*L*n) @ (n*L*n, C) matmul projects them — Mosaic's
+    tpu.matmul takes exactly one contracting dim and position-matched
+    batch dims only, so the multi-dim-contraction and batched forms of this
+    projection are unavailable (both probed on hardware).
+
+    A shelf's levels share both contractions. The bilinear selectors are
+    triangular hats: the weight of level column w for tap xx is
+    relu(1 - |w - (px0 + xx)|) — exactly (1-fx) at the left corner, fx at
+    the right, 0 elsewhere and 0 for every out-of-level tap, which is the
+    reference's zeros-padding rule. In level-0 units that hat is
+    2^-l * relu(2^l - |2^l (w + r - xx) - cx|): the distances and the
+    heights are constants of the placement, the per-query work is
+    subtract, abs, subtract, max on a splat of cx, and the two 2^-l wait
+    in the level mask. y first: the 9 hats of every level on the shelf,
+    stacked 9 rows a level, contract the shelf's rows in one dot; its
+    result still mixes levels lane by lane, so a constant mask keeps for
+    level l's rows only level l's own lanes (times 4^-l) — this is what
+    makes a window hanging past a level's edge read zeros and never the
+    neighbouring level; then ONE (16, lanes) block of x hats — each lane
+    belongs to one level — contracts the lanes. The (16, 9L) result has a
+    query's taps for x-offset xx in row xx: 9 row gathers a query move them
+    to the scratch, where the stack of one 128-lane pad a level needed 36
+    (and 16 selector tiles a query where this builds 7)."""
+    centre = c_ref[0]
+    cx, cy = centre[:, :, 0:1], centre[:, :, 1:2]  # (TP, 1, 1)
     n = 2 * radius + 1
-    tp, hsum, wp = corr_ref.shape[1:]
-    cx = cx_ref[0]  # (TP, 1, 1)
-    cy = cy_ref[0]
-    corr_all = corr_ref[0].astype(jnp.float32)  # (TP, Hsum, Wp)
-    d9 = jax.lax.broadcasted_iota(
-        jnp.int32, (1, n, 1), 1).astype(jnp.float32)
+    nsel = _up(n, 8)  # rows of the x hats: whole sublane tiles
+    tp, _, lanes = corr_ref.shape[1:]
+    slab = n * len(metas)  # scratch lanes an x-offset owns: (level, yy)
+    corr_all = corr_ref[0].astype(jnp.float32)  # (TP, rows, lanes)
+    # levels without a cell (tiny inputs pool to 0x0) read the
+    # zeros-padding region in every tap and contribute nothing to the
+    # projection; zero the scratch lanes they own
     for lvl, m in enumerate(metas):
-        if m.hlp == 0:
-            # degenerate level (tiny inputs pool to 0x0): every tap reads
-            # the zeros-padding region and contributes nothing to the
-            # projection; zero the scratch lanes it owns
-            base = lvl * n * n
-            taps_ref[:, base:base + n * n] = jnp.zeros((tp, n * n),
-                                                       jnp.float32)
-            continue
-        px0 = cx * (1.0 / (1 << lvl)) - radius
-        py0 = cy * (1.0 / (1 << lvl)) - radius
-        # bilinear selectors DIRECTLY as triangular hats: the weight of
-        # plane column w for tap xx is relu(1 - |w - (px0 + xx)|) — exactly
-        # (1-fx) at the left corner, fx at the right, 0 elsewhere, and 0
-        # for every out-of-plane tap (no lane in range), which is the
-        # reference's zeros-padding rule. Half the VPU work of building
-        # (n+1)-row corner one-hots and blending 4 corners.
-        yl = jax.lax.broadcasted_iota(
-            jnp.int32, (tp, n, m.hlp), 2).astype(jnp.float32)
-        xl = jax.lax.broadcasted_iota(
-            jnp.int32, (tp, n, wp), 2).astype(jnp.float32)
-        yw = jnp.maximum(1.0 - jnp.abs(yl - py0 - d9), 0.0)  # (TP, 9, Hlp)
-        xw = jnp.maximum(1.0 - jnp.abs(xl - px0 - d9), 0.0)  # (TP, 9, Wp)
-        level = jax.lax.slice_in_dim(corr_all, m.off, m.off + m.hlp, axis=1)
-        u = jax.lax.dot_general(       # (TP, 9x, Hlp)
-            xw, level, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        if m.rows == 0 or m.width == 0:
+            for k in range(n):
+                taps_ref[:, k * slab + lvl * n:k * slab + (lvl + 1) * n] = (
+                    jnp.zeros((tp, n), jnp.float32))
+    for row_off, rows, held in _shelves(metas):
+        g = _up(n * len(held), 8)  # y-hat rows: 9 a level, to a whole tile
+        yrow = jax.lax.broadcasted_iota(jnp.int32, (1, g, rows), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, g, rows), 2)
+        trow = jax.lax.broadcasted_iota(jnp.int32, (1, g, lanes), 1)
+        tlane = jax.lax.broadcasted_iota(jnp.int32, (1, g, lanes), 2)
+        xx = jax.lax.broadcasted_iota(jnp.int32, (1, nsel, lanes), 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, nsel, lanes), 2)
+        # constants of the placement; a distance of +inf is a hat of 0
+        ydist = jnp.full((1, g, rows), jnp.inf, jnp.float32)
+        ytop = jnp.zeros((1, g, rows), jnp.float32)
+        own = jnp.zeros((1, g, lanes), jnp.float32)
+        xdist = jnp.full((1, nsel, lanes), jnp.inf, jnp.float32)
+        xtop = jnp.zeros((1, nsel, lanes), jnp.float32)
+        for i, (lvl, m) in enumerate(held):
+            up = float(1 << lvl)
+            mine = (yrow >= i * n) & (yrow < (i + 1) * n)
+            ydist = jnp.where(
+                mine, (row + (radius + i * n) - yrow).astype(jnp.float32) * up,
+                ydist)
+            ytop = jnp.where(mine, up, ytop)
+            own = jnp.where(
+                (trow >= i * n) & (trow < (i + 1) * n)
+                & (tlane >= m.lane_off) & (tlane < m.lane_off + m.width),
+                1.0 / (up * up), own)
+            mine = ((lane >= m.lane_off) & (lane < m.lane_off + m.width)
+                    & (xx < n))
+            xdist = jnp.where(
+                mine, (lane - (m.lane_off - radius) - xx).astype(jnp.float32)
+                * up, xdist)
+            xtop = jnp.where(mine, up, xtop)
+        shelf = jax.lax.slice_in_dim(corr_all, row_off, row_off + rows,
+                                     axis=1)
+        yw = jnp.maximum(ytop - jnp.abs(ydist - cy), 0.0)  # (TP, g, rows)
+        t = jax.lax.dot_general(       # (TP, g, lanes)
+            yw, shelf, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        taps = jax.lax.dot_general(    # (TP, 9x, 9y) — blended tap window
-            u, yw, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        xw = jnp.maximum(xtop - jnp.abs(xdist - cx), 0.0)  # (TP, 16, lanes)
+        taps = jax.lax.dot_general(    # (TP, 16x, g) — blended tap windows
+            xw, t * own, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        base = lvl * n * n
-        for i in range(n):  # lane-sliced stores into VMEM scratch
-            taps_ref[:, base + i * n:base + (i + 1) * n] = taps[:, i, :]
+        first, width = held[0][0] * n, len(held) * n
+        for k in range(n):  # lane-sliced stores into VMEM scratch
+            taps_ref[:, k * slab + first:k * slab + first + width] = (
+                taps[:, k, :width])
     acc = jax.lax.dot_general(  # ONE rank-2 projection matmul off scratch
         taps_ref[...], w_ref[...].astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -444,37 +561,40 @@ def _proj_kernel(cx_ref, cy_ref, corr_ref, w_ref, b_ref, out_ref, taps_ref,
 @functools.partial(jax.jit, static_argnames=("metas", "radius", "interpret"))
 def _corr_lookup_proj_flat(stacked: jnp.ndarray,
                            metas: Tuple[ProjMeta, ...],
-                           cx: jnp.ndarray, cy: jnp.ndarray,
+                           centres: jnp.ndarray,
                            weight: jnp.ndarray, bias: jnp.ndarray,
                            radius: int = 4, interpret: bool = False
                            ) -> jnp.ndarray:
-    """Flat-query fused lookup+projection: stacked (1, Q, Hsum, Wp) plane,
-    cx/cy (1, Q) level-0 centers, weight (L*(2r+1)^2, C), bias (C,).
-    Returns (1, Q, C) = relu(lookup @ weight + bias)."""
-    _, q, hsum, wp = stacked.shape
+    """Flat-query fused lookup+projection: stacked (1, Q, rows, lanes)
+    plane, centres (1, Q, 2) level-0 (x, y), weight (L*(2r+1)^2, C),
+    bias (C,). Returns (1, Q, C) = relu(lookup @ weight + bias)."""
+    _, q, rows, lanes = stacked.shape
     n = 2 * radius + 1
     c_out = weight.shape[1]
-    plane = hsum * wp * 4
+    plane = rows * lanes * 4
     if 8 * plane > _VMEM_BLOCK_BYTES:
         raise ValueError(
-            f"stacked corr plane ({hsum}x{wp}) too large for any legal "
+            f"corr plane ({rows}x{lanes}) too large for any legal "
             "VMEM tile; use the unfused path (proj_lookup_supported "
             "gates this dispatch)")
     tp = _best_tile(q, min(_MAX_TILE_P, max(8, _VMEM_BLOCK_BYTES // plane)))
     qq = -(-q // tp) * tp
     if qq != q:
         stacked = jnp.pad(stacked, ((0, 0), (0, qq - q), (0, 0), (0, 0)))
-        cx = jnp.pad(cx, ((0, 0), (0, qq - q)))
-        cy = jnp.pad(cy, ((0, 0), (0, qq - q)))
-    coord_spec = pl.BlockSpec((1, tp, 1, 1), lambda qi: (0, qi, 0, 0),
-                              memory_space=pltpu.VMEM)
+        centres = jnp.pad(centres, ((0, 0), (0, qq - q), (0, 0)))
+    # the scratch holds a query's taps x-offset slowest, then level, then
+    # yy (one row gather an x-offset fills all levels): the weight's rows,
+    # (level, xx, yy) in the lookup's channel order, follow it
+    weight = weight.reshape(len(metas), n, n, c_out).transpose(
+        1, 0, 2, 3).reshape(len(metas) * n * n, c_out)
     out = pl.pallas_call(
         functools.partial(_proj_kernel, radius=radius, metas=metas),
         name="corr_lookup_proj",  # the kernel's own name in a device trace
         grid=(qq // tp,),
         in_specs=[
-            coord_spec, coord_spec,
-            pl.BlockSpec((1, tp, hsum, wp), lambda qi: (0, qi, 0, 0),
+            pl.BlockSpec((1, tp, 1, 2), lambda qi: (0, qi, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tp, rows, lanes), lambda qi: (0, qi, 0, 0),
                          memory_space=pltpu.VMEM),
             # constant index maps: Mosaic keeps these blocks resident
             # across grid steps (no per-program re-DMA)
@@ -490,8 +610,7 @@ def _corr_lookup_proj_flat(stacked: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(cx.astype(jnp.float32)[..., None, None],
-      cy.astype(jnp.float32)[..., None, None], stacked,
+    )(centres.astype(jnp.float32)[:, :, None, :], stacked,
       weight, bias.reshape(1, c_out))
     return out[:, :q]
 
@@ -508,11 +627,9 @@ def corr_lookup_proj(stacked: jnp.ndarray, metas: Tuple[ProjMeta, ...],
     ``relu(corr_lookup(pyramid, coords) @ weight + bias)`` with the pair
     batch folded into the query dim (the lookup is purely per-query)."""
     b, h, w, _ = coords.shape
-    cx = coords[..., 0].reshape(1, b * h * w)
-    cy = coords[..., 1].reshape(1, b * h * w)
     flat = stacked.reshape(1, b * h * w, *stacked.shape[2:])
-    out = _corr_lookup_proj_flat(flat, metas, cx, cy, weight, bias,
-                                 radius, interpret)
+    out = _corr_lookup_proj_flat(flat, metas, coords.reshape(1, b * h * w, 2),
+                                 weight, bias, radius, interpret)
     return out.reshape(b, h, w, -1)
 
 
@@ -540,10 +657,10 @@ def prepare_lookup(pyramid: Sequence[jnp.ndarray]
     """The lookup's one decision: which form runs on these raw
     (B, P, Hl, Wl) levels, and the pyramid in that form. Reads the backend
     and the level-0 plane's shape, nothing else. Off a TPU ``gather`` (raw
-    levels). On a TPU ``proj`` where the stacked plane fits a VMEM tile
-    (the plane; its metas ride the form), else ``level`` where every level
-    does (aligned levels), else ``onehot`` (raw levels), with the reason
-    the gate gave in ``fallback``.
+    levels). On a TPU ``proj`` where the one plane of all levels fits a
+    VMEM tile (the plane; its metas ride the form), else ``level`` where
+    every level does (aligned levels), else ``onehot`` (raw levels), with
+    the reason the gate gave in ``fallback``.
 
     Call it ONCE, outside the GRU scan: the pads are loop-invariant and XLA
     does not hoist them out of the while body (unhoisted they cost ~30% of

@@ -143,7 +143,7 @@ class BasicMotionEncoder(nn.Module):
     """update.py:86-104.
 
     ``lookup`` (static) says what ``corr`` is. Under ``proj`` it is the
-    sublane-stacked pyramid plane (kernels/corr_lookup.py
+    one pyramid plane a query (kernels/corr_lookup.py
     stack_aligned_pyramid) and ``coords`` the level-0 query centers:
     convc1 runs inside the fused Pallas lookup+projection kernel and the
     (B, H, W, 324) lookup intermediate never materializes (round-4
@@ -327,14 +327,18 @@ def _state_corr_lookup(form: LookupForm) -> None:
     output check can tell. A ``corr_lookup`` event on the current video
     span (telemetry=true) with ``impl``, ``compiled`` (True when
     ``pallas_call`` goes to Mosaic, False when it runs in the Pallas
-    interpreter off a TPU, None for the XLA forms) and ``fallback``; and a
-    printed line whenever a size gate replaced ``proj``. Runs once per
-    traced shape, not per call."""
+    interpreter off a TPU, None for the XLA forms), ``fallback`` and, under
+    ``proj``, ``plane_cells`` (cells of the plane the kernel reads a query
+    and iteration) and ``plane_fill`` (the share of them that hold data;
+    None under the other forms); and a printed line whenever a size gate
+    replaced ``proj``. Runs once per traced shape, not per call."""
     from .. import telemetry
     kernel = form.impl in ("proj", "level")
+    cells, fill = lookup_kernels.plane_fill(form.metas)
     telemetry.event("corr_lookup", impl=form.impl,
                     compiled=(not interpret_mode()) if kernel else None,
-                    fallback=form.fallback)
+                    fallback=form.fallback, plane_cells=cells,
+                    plane_fill=fill)
     if form.fallback is not None:
         print(f"corr lookup: impl={form.impl} in place of proj: "
               f"{form.fallback}")
@@ -444,10 +448,12 @@ class RAFT(nn.Module):
     (well under the I3D flow stream's ToUInt8 quantization step of ~0.16);
     the f32 default is bit-identical to before (every cast is a no-op).
 
-    The pyramid stays f32 in every mode: the lookup is bound by per-query
-    selection work, not by the bytes it reads (kernels/corr_lookup.py), so
-    a bf16 pyramid buys nothing and would cost the lookup its exact
-    values."""
+    The pyramid stays f32 in every mode. Since its levels share one
+    lane-padded plane the fused lookup runs at the memory wall of that
+    plane (16,384 B a query and iteration at 240x320, 662 GB/s of a v5e's
+    819; kernels/corr_lookup.py), so a bf16 pyramid would now buy time,
+    and would cost the lookup its exact values: a different result, not
+    a faster one."""
     iters: int = ITERS
     dtype: Any = jnp.float32
 

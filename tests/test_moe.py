@@ -187,3 +187,69 @@ def test_with_every_expert_held_the_step_is_the_parents():
     parent = jax.make_jaxpr(lambda *a: full_length(*a, 0, args[6])
                             )(*args[:5])
     assert shaped_equations(today.jaxpr) == shaped_equations(parent.jaxpr)
+
+
+# -- the gate's rule ------------------------------------------------------------------
+
+def plain_gate(logits, k, over_all, renormalise, scaling):
+    """The two published rules in numpy, token by token."""
+    gates, experts = [], []
+    for row in np.asarray(logits, np.float64):
+        if over_all:
+            p = np.exp(row - row.max())
+            p /= p.sum()
+            top = np.argsort(-p, kind="stable")[:k]
+            g = p[top] / (p[top].sum() + 1e-20) if renormalise else p[top]
+        else:
+            top = np.argsort(-row, kind="stable")[:k]
+            g = np.exp(row[top] - row[top].max())
+            g /= g.sum()
+        gates.append(g * scaling)
+        experts.append(top)
+    return np.asarray(gates), np.asarray(experts)
+
+
+@pytest.mark.parametrize("rule", [
+    dict(),                                                 # granite's
+    dict(over_all=True, renormalise=False),                 # deepseek_v2's
+    dict(over_all=True, renormalise=True),
+    dict(over_all=True, renormalise=False, scaling=2.5),
+], ids=["top_k_then_softmax", "softmax_then_top_k", "renormalised",
+        "scaled"])
+def test_route_under_each_rule_is_the_plain_computation(rule):
+    rng = np.random.default_rng(11)
+    u = jnp.asarray(rng.standard_normal((64, D)), jnp.float32)
+    router = jnp.asarray(rng.standard_normal((D, WIDE)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        gates, experts = moe.route(u, router, K, **rule)
+        logits = jnp.dot(u, router)
+    want_gates, want_experts = plain_gate(
+        logits, K, rule.get("over_all", False),
+        rule.get("renormalise", True), rule.get("scaling", 1.0))
+    assert np.array_equal(np.asarray(experts), want_experts)
+    np.testing.assert_allclose(np.asarray(gates), want_gates, rtol=1e-5)
+    total = np.asarray(gates).sum(axis=1)
+    if not rule.get("over_all") or rule.get("renormalise"):
+        np.testing.assert_allclose(total, rule.get("scaling", 1.0),
+                                   rtol=1e-5)
+    else:       # the chosen probabilities of a softmax over all: under 1
+        assert (total <= rule.get("scaling", 1.0) * (1 + 1e-6)).all()
+        assert total.min() < 0.99 * rule.get("scaling", 1.0)
+
+
+def test_granites_rule_is_the_default_and_its_program_is_the_parents():
+    """``route`` with no rule named lowers to what PR 31's ``route`` lowered
+    to: the top-k over the logits and one softmax, no multiply behind it."""
+    u, router = jnp.ones((8, D), jnp.bfloat16), jnp.ones((D, WIDE))
+
+    def parent(u, router):
+        logits = jnp.dot(u, router.astype(u.dtype),
+                         preferred_element_type=jnp.float32)
+        top, experts = jax.lax.top_k(logits, K)
+        return jax.nn.softmax(top.astype(jnp.float32), axis=-1), experts
+
+    def now(u, router):
+        return moe.route(u, router, K)
+
+    assert str(jax.make_jaxpr(now)(u, router)) == \
+        str(jax.make_jaxpr(parent)(u, router))

@@ -64,3 +64,35 @@ def test_blockwise_attention_matches_dense(rng):
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=2e-5, atol=2e-5,
                                        err_msg=f"t={t} bs={bs} causal={causal}")
+
+
+def _dense_segments(q, k, v, seg, scale):
+    """Dense causal attention within segments, values as wide as they are."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    t = q.shape[1]
+    mask = jnp.tril(jnp.ones((t, t), bool))[None, None] \
+        & (seg[:, None, :, None] == seg[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("t, bs", [(32, 8), (37, 8), (16, 64)])
+@pytest.mark.parametrize("segments", [False, True])
+def test_blockwise_attention_with_values_narrower_than_keys(rng, t, bs,
+                                                            segments):
+    """deepseek_v2's latent attention: 24-wide query/key heads (a width
+    that is no multiple of 16), 16-wide value heads; causal, with and without
+    packed segments, T not a multiple of the block, a scale of its own."""
+    from video_features_tpu.parallel.sequence import blockwise_attention
+    q, k = (jnp.asarray(rng.normal(size=(2, t, 3, 24)).astype(np.float32))
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(2, t, 3, 16)).astype(np.float32))
+    seg = jnp.asarray(np.sort(rng.integers(1, 4, (2, t)), axis=1), jnp.int32) \
+        if segments else jnp.ones((2, t), jnp.int32)
+    got = blockwise_attention(q, k, v, block_size=bs, causal=True,
+                              scale=0.11,
+                              segment_ids=seg if segments else None)
+    assert got.shape == (2, t, 3, 16)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_dense_segments(q, k, v, seg, 0.11)),
+        rtol=2e-5, atol=2e-5)

@@ -1,143 +1,24 @@
-"""granite-4.0-h-small as a token-sequence extractor.
-
-The item is a file of token ids (``.tokens``: raw little-endian int32, what
-a tokenizer run over a caption or a transcript leaves), not a video. A
-document is cut into windows of ``stack_size`` tokens every ``step_size``, as
-a video is cut into clip stacks, but the last, shorter window is kept: text
-has no frame to drop. Every window is one segment of a packed row
-(``parallel/packer.py SegmentPacker``: documents of all the workers share
-rows and groups), and comes back as one line: the mean of the final hidden
-states over its tokens (``{stem}_granite_hybrid.npy``, ``(windows, hidden)``
-float32) and, beside it as ``fps`` rides beside other families' features, how
-many of its tokens each layer's router sent to each expert
-(``{stem}_expert_tokens.npy``, ``(windows, layers, experts)`` int32).
-
-No checkpoint converter exists yet: ``allow_random_weights=true`` draws the
-seeded weights of ``models/granite_hybrid.py`` on the device, in the serving
-type, layer by layer (``weights/store.py``'s random path builds a float32
-tree on the host, 19 GB for the benchmark's configuration).
-"""
+"""granite-4.0-h-small as a token-sequence extractor: the item, the windows,
+the packed rows and the two outputs are ``extractors/token_sequence.py``'s;
+this file names the model and, for ``show_pred``, reads the tied head."""
 from __future__ import annotations
-
-from functools import partial
-from typing import Dict
 
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..config import Config
 from ..models import granite_hybrid as gh
-from ..parallel.mesh import DataParallelApply
-from ..parallel.packer import SegmentPacker
-from ..telemetry import trace
-from ..utils.profiling import profiler
-from .base import BaseExtractor
-
-#: a token item's suffix; the sink's stem rule takes any
-TOKEN_SUFFIX = ".tokens"
-#: the seed of ``allow_random_weights``
-WEIGHTS_SEED = 0
+from .token_sequence import (TOKEN_SUFFIX, WEIGHTS_SEED,  # noqa: F401
+                             TokenSequenceExtractor, read_tokens, windows_of)
 
 
 def _device_forward(arch: gh.Arch, max_segments: int, dtype, params, rows):
-    """(B, 2, T) int32 ids and segment ids -> (B, max_segments, hidden +
-    layers * experts) float32."""
     return gh.segment_features(arch, max_segments, dtype, params, rows)
 
 
-def read_tokens(path: str, vocab_held: int) -> np.ndarray:
-    """The ids of a ``.tokens`` file. An id outside the rows of the
-    vocabulary this chip holds is refused here, where the item is read: on
-    the device it would be clamped to another token's row in silence."""
-    if not str(path).endswith(TOKEN_SUFFIX):
-        raise NotImplementedError(
-            f"granite_hybrid reads {TOKEN_SUFFIX} files (raw int32 token "
-            f"ids), got {path!r}")
-    ids = np.fromfile(path, dtype="<i4")
-    if ids.size and not (0 <= int(ids.min()) and int(ids.max()) < vocab_held):
-        raise ValueError(
-            f"{path}: token ids {int(ids.min())}..{int(ids.max())} outside "
-            f"the {vocab_held} vocabulary rows held here")
-    return ids
-
-
-def windows_of(n: int, window: int, step: int):
-    """``[(start, end)]`` of a document of ``n`` tokens: the last window is
-    as short as the document leaves it (``ceil(n / window)`` windows where
-    ``step == window``); an empty document has none."""
-    if n <= 0:
-        return []
-    count = 1 if n <= window else -(-(n - window) // step) + 1
-    return [(i * step, min(i * step + window, n)) for i in range(count)]
-
-
-class ExtractGraniteHybrid(BaseExtractor):
-
-    def __init__(self, args: Config) -> None:
-        super().__init__(args)
-        self.model_name = args.get("model_name")
-        self.arch = gh.arch_from_config(
-            dict(args.architecture), args.get("layer_shards") or 1,
-            args.get("layer_shard_rank") or 0)
-        self.stack_size = int(args.get("stack_size") or 4096)
-        self.step_size = int(args.get("step_size") or self.stack_size)
-        self.batch_size = int(args.get("batch_size") or 4)
-        self.max_segments = int(args.get("max_segments") or 64)
-        self.output_feat_keys = [self.feature_type, "expert_tokens"]
-        if args.get("weights_path") or not args.get("allow_random_weights"):
-            raise NotImplementedError(
-                "granite_hybrid has no checkpoint converter yet: pass "
-                "allow_random_weights=true (seeded weights, made on the "
-                "device) and no weights_path")
-        self.dtype = jnp.bfloat16 if self.precision == "bfloat16" \
-            else jnp.float32
-        mesh = self._data_mesh()
-        # drawn where they will live: a second copy would not fit
-        params = gh.init_params(self.arch, WEIGHTS_SEED, self.dtype,
-                                sharding=NamedSharding(mesh, P()))
-        self.runner = DataParallelApply(
-            partial(_device_forward, self.arch, self.max_segments,
-                    self.dtype),
-            params, mesh=mesh, fixed_batch=self.batch_size)
-        self._packer = SegmentPacker(self.runner, batch=self.batch_size,
-                                     row_len=self.stack_size,
-                                     max_segments=self.max_segments)
-
-    def extract(self, video_path: str) -> Dict[str, np.ndarray]:
-        with profiler.stage("decode"), \
-                trace.span("decode.read", item=str(video_path)):
-            ids = read_tokens(video_path, self.arch.vocab_held)
-        windows = windows_of(len(ids), self.stack_size, self.step_size)
-        handle = self._packer.open_video()
-        try:
-            for start, end in windows:
-                self._packer.add(handle, ids[start:end])
-        except BaseException:
-            self._packer.abort_video(handle)
-            raise
-        lines = self._packer.close_video(handle).reshape(
-            len(windows), self.arch.feature_dim + self.arch.counter_dim)
-        counts = np.rint(lines[:, self.arch.feature_dim:]).astype(
-            np.int32).reshape(len(windows), len(self.arch.layer_types),
-                              self.arch.num_local_experts)
-        if len(windows):
-            # how near this document's routing runs to the routed layer's
-            # compact buffer (``ops/moe.py held_rows``): the assignments of
-            # its fullest layer to the experts held here, beside all of one
-            # layer's
-            a_layer = counts.sum(axis=0)                # (layers, experts)
-            first = self.arch.first_expert
-            trace.counter("moe.assignments", int(
-                a_layer[:, first:first + self.arch.experts_held]
-                .sum(axis=1).max()), series="held")
-            trace.counter("moe.assignments", int(a_layer[0].sum()),
-                          series="all")
-        if self.show_pred:
-            self.maybe_show_pred(ids, windows)
-        return {self.feature_type: np.ascontiguousarray(
-                    lines[:, :self.arch.feature_dim], np.float32),
-                "expert_tokens": counts}
+class ExtractGraniteHybrid(TokenSequenceExtractor):
+    model = gh
+    device_forward = staticmethod(_device_forward)
+    default_stack_size = 4096
 
     def maybe_show_pred(self, ids: np.ndarray, windows) -> None:
         """The five likeliest next tokens after each window's last position,

@@ -36,7 +36,10 @@ import jax.numpy as jnp
 
 from ..ops import moe, ssd
 from ..parallel.sequence import blockwise_attention
+from . import token_rows
 from .common import scope
+from .token_rows import INIT_STD, rms_norm
+from .token_rows import part_key as _part_key
 
 
 @dataclass(frozen=True)
@@ -86,8 +89,13 @@ class Arch:
         return self.hidden_size
 
     @property
+    def counter_shape(self) -> Tuple[int, int]:
+        """(routed layers, the router's width) of a line's counts."""
+        return len(self.layer_types), self.num_local_experts
+
+    @property
     def counter_dim(self) -> int:
-        return len(self.layer_types) * self.num_local_experts
+        return math.prod(self.counter_shape)
 
 
 def arch_from_config(published: Mapping[str, Any], layer_shards: int = 1,
@@ -121,10 +129,6 @@ def arch_from_config(published: Mapping[str, Any], layer_shards: int = 1,
 
 
 # -- weights -------------------------------------------------------------------
-
-#: matrices are normal(0, INIT_STD): no checkpoint exists in a sealed machine
-INIT_STD = 0.02
-
 
 def _mamba_weights(arch: Arch, key) -> Dict[str, jnp.ndarray]:
     """``A_log``, ``dt_bias`` and ``D`` by the Mamba-2 release's
@@ -191,19 +195,8 @@ def _draw_layer(arch: Arch, kind: str, key) -> Dict[str, Any]:
     }
 
 
-def _draw_outer(arch: Arch, key) -> Dict[str, jnp.ndarray]:
-    rows = jax.vmap(lambda r: INIT_STD * jax.random.normal(
-        jax.random.fold_in(key, r), (arch.hidden_size,), jnp.float32))
-    return {"embed": rows(jnp.arange(arch.vocab_held)),
-            "final_norm": jnp.ones((arch.hidden_size,), jnp.float32)}
-
-
-def _part_key(seed: int, index: int):
-    return jax.random.fold_in(jax.random.PRNGKey(seed), index)
-
-
 def _draw(arch: Arch, kind: str, key) -> Dict[str, Any]:
-    return _draw_outer(arch, key) if kind == "outer" \
+    return token_rows.draw_outer(arch, key) if kind == "outer" \
         else _draw_layer(arch, kind, key)
 
 
@@ -226,37 +219,14 @@ def outer_weights(arch: Arch, seed: int) -> Dict[str, jnp.ndarray]:
                          _part_key(seed, len(arch.layer_types)))
 
 
-def serving_tree(weights: Any, dtype) -> Any:
-    """Matrices rounded once to ``dtype``; the per-channel vectors (norms,
-    ``A_log``, ``dt_bias``, ``D``, the convolution's bias) stay float32, as
-    a checkpoint keeps them: ``dt_bias`` near -7 in bfloat16 would move a
-    head's step by 3%."""
-    return jax.tree_util.tree_map(
-        lambda x: x.astype(dtype if x.ndim >= 2 else jnp.float32), weights)
-
-
 def init_params(arch: Arch, seed: int, dtype, sharding=None) -> Dict[str, Any]:
-    """The whole tree in ``dtype`` on the device: each part is drawn in
-    float32 and rounded once inside one program, so no float32 copy of a
-    layer is ever held. One program per kind of layer: the key is traced."""
-    @functools.partial(jax.jit, static_argnums=0, out_shardings=sharding)
-    def rounded(kind, key):
-        return serving_tree(_draw(arch, kind, key), dtype)
-
-    depth = len(arch.layer_types)
-    return {**rounded("outer", _part_key(seed, depth)),
-            "layers": [rounded(arch.layer_types[i], _part_key(seed, i))
-                       for i in range(depth)]}
+    """The whole tree in ``dtype`` on the device, drawn layer by layer
+    (``token_rows.init_params``)."""
+    return token_rows.init_params(functools.partial(_draw, arch),
+                                  arch.layer_types, seed, dtype, sharding)
 
 
 # -- the forward pass ------------------------------------------------------------
-
-def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
-    """float32 inside, ``x.dtype`` out."""
-    x32 = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * scale * weight.astype(jnp.float32)).astype(x.dtype)
-
 
 def mamba_mixer(arch: Arch, w: Mapping[str, jnp.ndarray], u: jnp.ndarray,
                 seg: jnp.ndarray, state_dtype=jnp.float32) -> jnp.ndarray:
@@ -346,25 +316,9 @@ def token_states(arch: Arch, params: Mapping[str, Any], rows: jnp.ndarray,
 def pool_segments(arch: Arch, max_segments: int, seg: jnp.ndarray,
                   f: jnp.ndarray, chosen: jnp.ndarray) -> jnp.ndarray:
     """``f`` (B, T, D) and ``chosen`` (layers, B, T, K) -> (B, max_segments,
-    feature_dim + counter_dim) float32. Line ``s - 1`` of a row is segment
-    ``s``: the mean of ``f`` over its tokens, then for every layer the number
-    of its tokens routed to each of the ``num_local_experts`` experts.
-    Padding (segment 0) is in no line; a segment id the row does not hold
-    gives a line of zeros."""
-    with scope("GraniteHybrid", "pool"):
-        member = (seg[:, None, :] == jnp.arange(
-            1, max_segments + 1)[None, :, None]).astype(jnp.float32)
-        tokens = member.sum(axis=-1, keepdims=True)            # (B, S, 1)
-        pooled = jnp.einsum("bst,btd->bsd", member, f,
-                            precision=jax.lax.Precision.HIGHEST) \
-            / jnp.maximum(tokens, 1.0)
-        # (layers, B, T, K) -> how often each expert was chosen per token
-        picked = jax.nn.one_hot(chosen, arch.num_local_experts,
-                                dtype=jnp.float32).sum(axis=3)
-        counts = jnp.einsum("bst,lbte->bsle", member, picked,
-                            precision=jax.lax.Precision.HIGHEST)
-        return jnp.concatenate(
-            [pooled, counts.reshape(*counts.shape[:2], -1)], axis=-1)
+    feature_dim + counter_dim) float32 (``token_rows.pool_segments``)."""
+    return token_rows.pool_segments("GraniteHybrid", arch.num_local_experts,
+                                    max_segments, seg, f, chosen)
 
 
 def segment_features(arch: Arch, max_segments: int, dtype,

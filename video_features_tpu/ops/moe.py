@@ -1,7 +1,9 @@
 """A routed expert layer that is told which experts it holds.
 
 The router keeps its published width: every token's logits over ALL experts,
-the ``top_k`` largest, gates = softmax over those ``top_k`` logits. Of the
+the ``top_k`` largest, gates = softmax over those ``top_k`` logits (or, by
+:func:`route`'s static rule, the ``top_k`` largest of the softmax over all).
+Of the
 selected experts only those in ``[first, first + held)`` live here (expert
 parallelism: the others are on the chips that share the layer), and the
 layer returns ``sum_e gate_e * expert_e(u)`` over the selected experts that
@@ -18,7 +20,8 @@ from the routing. Where it fits, the row gather, both products and the
 fusion between them are that buffer long and the return trip reads from it
 (device scope ``moe/held``); where it does not, the same steps run over a
 buffer with room for every assignment (``moe/all``) and give the same bits.
-A layer that holds every expert has the one length and no condition.
+A layer that holds every expert has the one length and no condition
+(deepseek_v2 on one chip a layer).
 
 An expert is a gated unit: ``(silu(u W[:, :I]) * (u W[:, I:])) V``.
 """
@@ -32,16 +35,34 @@ import jax.numpy as jnp
 
 
 def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
-          router_dtype=jnp.float32) -> Tuple[jnp.ndarray, jnp.ndarray]:
+          router_dtype=jnp.float32, over_all: bool = False,
+          renormalise: bool = True, scaling: float = 1.0
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(gates, experts)``, each (T, top_k): the router's choice for every
     token of ``u`` (T, D) over all ``router.shape[1]`` experts. Logits and
     gates are float32 (``router_dtype``: the tests lower it to show that the
     comparison notices): a logit rounded to bfloat16 swaps near-tied
-    experts."""
+    experts.
+
+    The gate's rule is static. By default (granite's) the ``top_k`` largest
+    logits are chosen and the gates are the softmax over those. With
+    ``over_all`` (deepseek_v2's ``scoring_func`` softmax) the softmax runs
+    over every expert's logit and the ``top_k`` largest probabilities are
+    chosen; they are divided by their sum only where ``renormalise``
+    (``norm_topk_prob``), and multiplied by ``scaling``
+    (``routed_scaling_factor``; the published deepseek_v2 code leaves the
+    factor out where it renormalises, deepseek_v3's applies both, as here)."""
     logits = jnp.dot(u, router.astype(u.dtype),
                      preferred_element_type=jnp.float32).astype(router_dtype)
-    top, experts = jax.lax.top_k(logits, top_k)
-    return jax.nn.softmax(top.astype(jnp.float32), axis=-1), experts
+    if not over_all:
+        top, experts = jax.lax.top_k(logits, top_k)
+        gates = jax.nn.softmax(top.astype(jnp.float32), axis=-1)
+    else:
+        gates, experts = jax.lax.top_k(
+            jax.nn.softmax(logits.astype(jnp.float32), axis=-1), top_k)
+        if renormalise:
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
+    return (gates if scaling == 1.0 else gates * scaling), experts
 
 
 def gated_unit(u: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray

@@ -360,14 +360,20 @@ class SegmentPacker(ClipPacker):
         if not self._row:
             return
         row = np.zeros((2, self.row_len), np.int32)
-        at, members = 0, []
+        at, pairs, members = 0, 0, []
         for slot, (handle, idx, tokens) in enumerate(self._row):
             row[0, at:at + len(tokens)] = tokens
             row[1, at:at + len(tokens)] = slot + 1
             at += len(tokens)
+            pairs += len(tokens) * (len(tokens) + 1) // 2
             members.append((handle, idx, slot))
         trace.counter("packer.row_fill", at, series="tokens")
         trace.counter("packer.row_fill", self.row_len, series="capacity")
+        # the causal same-document (query, key) pairs of the row over all an
+        # attention that skips nothing computes
+        trace.counter("packer.pair_fill", pairs, series="pairs")
+        trace.counter("packer.pair_fill", self.row_len ** 2,
+                      series="capacity")
         self._buf.append((members, row))
         self._row, self._row_fill = [], 0
 

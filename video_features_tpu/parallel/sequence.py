@@ -58,7 +58,8 @@ def dense_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def _fold_init(b, h, t, d):
-    """Fresh streaming-softmax accumulator (o, m, l), f32."""
+    """Fresh streaming-softmax accumulator (o, m, l), f32; ``d`` is the
+    value head's width."""
     return (jnp.zeros((b, h, t, d), jnp.float32),
             jnp.full((b, h, t, 1), -jnp.inf, jnp.float32),
             jnp.zeros((b, h, t, 1), jnp.float32))
@@ -98,7 +99,10 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         scale: Optional[float] = None,
                         segment_ids: Optional[jnp.ndarray] = None
                         ) -> jnp.ndarray:
-    """Single-device memory-efficient attention (B, T, H, D) -> same.
+    """Single-device memory-efficient attention: ``q`` / ``k`` (B, T, H, D)
+    and ``v`` (B, T, H, Dv) -> (B, T, H, Dv). The value head may be narrower
+    than the query/key head (deepseek_v2's latent attention: 192 and 128);
+    the accumulator is as wide as ``v``.
 
     The intra-device complement of :func:`ring_attention`: a ``lax.scan``
     over K/V blocks with the same streaming log-sum-exp softmax, so peak
@@ -110,6 +114,7 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     documents into a row: a query attends only to keys of its own segment.
     """
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     scale = (d ** -0.5) if scale is None else scale
     bs = min(block_size, t)
     n_blocks = -(-t // bs)
@@ -118,7 +123,7 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     # (n_blocks, B, bs, H, D) scan sequence
     kb = jnp.moveaxis(kp.reshape(b, n_blocks, bs, h, d), 1, 0)
-    vb = jnp.moveaxis(vp.reshape(b, n_blocks, bs, h, d), 1, 0)
+    vb = jnp.moveaxis(vp.reshape(b, n_blocks, bs, h, dv), 1, 0)
     q_pos = jnp.arange(t)
     blocks = (kb, vb)
     if segment_ids is not None:
@@ -140,7 +145,7 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         o, m, l = _softmax_fold(q, (o, m, l), ck, cv, scale, valid)
         return (o, m, l, i + 1), None
 
-    o0, m0, l0 = _fold_init(b, h, t, d)
+    o0, m0, l0 = _fold_init(b, h, t, dv)
     (o, _, l, _), _ = jax.lax.scan(step, (o0, m0, l0, 0), blocks)
     return _fold_finalize(o, l, q.dtype)
 

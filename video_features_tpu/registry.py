@@ -19,6 +19,7 @@ _DISPATCH = {
     "raft": ("raft", "ExtractRAFT"),
     "pwc": ("pwc", "ExtractPWC"),
     "granite_hybrid": ("granite_hybrid", "ExtractGraniteHybrid"),
+    "deepseek_v2": ("deepseek_v2", "ExtractDeepSeekV2"),
 }
 
 #: families that consume the AUDIO track: in a multi-family run they

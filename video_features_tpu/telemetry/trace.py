@@ -162,6 +162,7 @@ KNOWN_COUNTER_NAMES = (
     "packer.buffered",      # clips in the shared buffer after an add/flush
     "packer.ragged_flush",  # rows of the one ragged dispatch, when it fires
     "packer.row_fill",      # a sealed token row: series tokens / capacity
+    "packer.pair_fill",     # ... its causal same-document pairs / T squared
     "stream.inflight",      # un-materialized outputs of a FeatureStream
 )
 

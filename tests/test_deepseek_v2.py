@@ -338,8 +338,9 @@ def test_the_extractor_agrees_with_the_reference_window_by_window(
 
 def test_a_document_states_mla_once_and_counts_its_assignments(
         extractor, tmp_path):
-    """The ``mla`` event on the first item's span, ``moe.assignments`` and
-    ``packer.pair_fill`` on the program's own timeline."""
+    """The ``mla`` event on the first item's span, ``moe.assignments``,
+    ``packer.pair_fill`` and ``attention.blocks`` on the program's own
+    timeline."""
     from video_features_tpu.telemetry import trace
     from video_features_tpu.telemetry.spans import VideoSpan
     from video_features_tpu.utils.profiling import profiler
@@ -371,6 +372,10 @@ def test_a_document_states_mla_once_and_counts_its_assignments(
     pairs = [e["args"] for e in events if e["name"] == "packer.pair_fill"]
     assert pairs[:4] == [{"pairs": 96 * 97 // 2}, {"capacity": 96 * 96},
                          {"pairs": 34 * 35 // 2}, {"capacity": 96 * 96}]
+    # ... and beside each, the block pairs its attention folds: a row of
+    # 96 tokens is one tile against one block
+    blocks = [e["args"] for e in events if e["name"] == "attention.blocks"]
+    assert blocks[:4] == [{"kept": 1}, {"total": 1}] * 2
 
 
 def test_serve_loop_turns_token_files_into_feature_files(tmp_path):

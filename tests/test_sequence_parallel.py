@@ -66,11 +66,13 @@ def test_blockwise_attention_matches_dense(rng):
                                        err_msg=f"t={t} bs={bs} causal={causal}")
 
 
-def _dense_segments(q, k, v, seg, scale):
-    """Dense causal attention within segments, values as wide as they are."""
+def _dense_segments(q, k, v, seg, scale, causal=True):
+    """Dense (causal) attention within segments, values as wide as they
+    are."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     t = q.shape[1]
-    mask = jnp.tril(jnp.ones((t, t), bool))[None, None] \
+    mask = (jnp.tril(jnp.ones((t, t), bool)) if causal
+            else jnp.ones((t, t), bool))[None, None] \
         & (seg[:, None, :, None] == seg[:, None, None, :])
     p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
@@ -96,3 +98,238 @@ def test_blockwise_attention_with_values_narrower_than_keys(rng, t, bs,
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(_dense_segments(q, k, v, seg, 0.11)),
         rtol=2e-5, atol=2e-5)
+
+
+# -- blockwise_attention computes only the (tile, block) pairs the mask can keep --
+
+def _packed_qkv(rng, b, t, h=3, d=24, dv=16):
+    q, k = (jnp.asarray(rng.normal(size=(b, t, h, d)).astype(np.float32))
+            for _ in range(2))
+    return q, k, jnp.asarray(rng.normal(size=(b, t, h, dv))
+                             .astype(np.float32))
+
+
+def _segment_ids(rng, kind, b, t):
+    """Rows whose documents differ: ``sorted`` as the packer lays a row out
+    (1, 2, ... then padding, 0, at its end), ``shuffled`` any ids at all."""
+    if kind is None:
+        return None
+    seg = rng.integers(1, 6, (b, t))
+    if kind == "sorted":
+        seg = np.sort(seg, axis=1)
+        for row, pad in zip(seg, rng.integers(0, t // 3, b)):
+            row[t - pad:] = 0
+    return seg.astype(np.int32)
+
+
+@pytest.mark.parametrize("b", [1, 3])
+@pytest.mark.parametrize("q_tile, bs", [(4, 8), (8, 8), (16, 8)])
+@pytest.mark.parametrize("segments", [None, "sorted", "shuffled"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_tiled_blockwise_attention_matches_dense(rng, monkeypatch, causal,
+                                                 segments, q_tile, bs, b):
+    """Query tiles below, equal to and above the key block, T (75) a multiple
+    of neither, values narrower than keys, one row and three whose documents
+    differ; unmasked, it is the scan over every block."""
+    from video_features_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "Q_TILE", q_tile)
+    t = 75
+    q, k, v = _packed_qkv(rng, b, t)
+    seg = _segment_ids(rng, segments, b, t)
+    got = sequence.blockwise_attention(
+        q, k, v, block_size=bs, causal=causal, scale=0.11,
+        segment_ids=None if seg is None else jnp.asarray(seg))
+    assert got.shape == (b, t, 3, 16)
+    want = _dense_segments(
+        q, k, v, jnp.ones((b, t), jnp.int32) if seg is None
+        else jnp.asarray(seg), 0.11, causal)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("b", [1, 3])
+@pytest.mark.parametrize("segments", [None, "sorted", "shuffled"])
+def test_skipping_a_block_is_exact(rng, monkeypatch, segments, b):
+    """With its bounds forced open the function folds every block, as it did
+    before it had bounds: the same bits, the skipped folds were identities."""
+    from video_features_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "Q_TILE", 16)
+    t, bs = 75, 8
+    q, k, v = _packed_qkv(rng, b, t)
+    seg = _segment_ids(rng, segments, b, t)
+
+    def run():
+        return np.asarray(sequence.blockwise_attention(
+            q, k, v, block_size=bs, causal=True, scale=0.11,
+            segment_ids=None if seg is None else jnp.asarray(seg)))
+
+    lo, hi = sequence.block_bounds(seg, t, 16, bs, True)
+    assert int((hi - lo).sum()) < lo.size * 10      # something is skipped
+    skipping = run()
+    monkeypatch.setattr(
+        sequence, "block_bounds", lambda ids, t, q_tile, block_size, causal:
+        (np.zeros((1, 5), np.int32), np.full((1, 5), 10, np.int32)))
+    assert np.array_equal(run(), skipping)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("segments", ["sorted", "shuffled"])
+@pytest.mark.parametrize("q_tile, bs", [(8, 16), (16, 16), (32, 16)])
+def test_no_block_outside_the_bounds_holds_a_valid_pair(rng, segments, causal,
+                                                        q_tile, bs):
+    from video_features_tpu.parallel.sequence import block_bounds
+    b, t = 4, 150
+    seg = _segment_ids(rng, segments, b, t)
+    lo, hi = block_bounds(seg, t, q_tile, bs, causal)
+    n_tiles, n_blocks = -(-t // q_tile), -(-t // bs)
+    assert lo.shape == hi.shape == (b, n_tiles)
+    pos = np.arange(t)
+    mask = seg[:, :, None] == seg[:, None, :]
+    if causal:
+        mask = mask & (pos[:, None] >= pos[None, :])
+    inside = 0
+    for r in range(b):
+        for j in range(n_tiles):
+            for i in range(n_blocks):
+                held = mask[r, j * q_tile:(j + 1) * q_tile,
+                            i * bs:(i + 1) * bs].any()
+                if not lo[r, j] <= i < hi[r, j]:
+                    assert not held, (r, j, i)
+                inside += int(held)
+    assert inside <= int((hi - lo).sum()) <= b * n_tiles * n_blocks
+    # the device's table is the host's
+    on_device = block_bounds(jnp.asarray(seg), t, q_tile, bs, causal)
+    assert all(isinstance(x, jax.Array) for x in on_device)
+    assert np.array_equal(on_device[0], lo) and np.array_equal(on_device[1], hi)
+
+
+def test_a_causal_document_of_16384_tokens_keeps_528_of_1024_blocks():
+    from video_features_tpu.parallel.sequence import (BLOCK_SIZE, Q_TILE,
+                                                      block_bounds)
+    assert (Q_TILE, BLOCK_SIZE) == (512, 512)
+    for ids in (None, np.ones((1, 16384), np.int32)):
+        lo, hi = block_bounds(ids, 16384, Q_TILE, BLOCK_SIZE, True)
+        assert lo.shape == (1, 32) and not lo.any()
+        assert hi[0].tolist() == list(range(1, 33))       # sum: 528
+    # two documents in the row: the second one's tiles start at its blocks
+    ids = np.repeat([1, 2], [6144, 10240])[None].astype(np.int32)
+    lo, hi = block_bounds(ids, 16384, Q_TILE, BLOCK_SIZE, True)
+    assert int((hi - lo).sum()) == 12 * 13 // 2 + 20 * 21 // 2
+    # nothing to skip without a mask
+    lo, hi = block_bounds(None, 16384, Q_TILE, BLOCK_SIZE, False)
+    assert int((hi - lo).sum()) == 1024
+
+
+def _scan_over_every_block(q, k, v, block_size, scale):
+    """``blockwise_attention`` as it was before it had tiles (PR 32), without
+    a mask: what the unmasked call still has to be."""
+    from video_features_tpu.parallel.sequence import (_fold_finalize,
+                                                      _fold_init,
+                                                      _softmax_fold)
+    b, t, h, d = q.shape
+    bs = min(block_size, t)
+    n_blocks = -(-t // bs)
+    pad = n_blocks * bs - t
+    kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    kb = jnp.moveaxis(kp.reshape(b, n_blocks, bs, h, d), 1, 0)
+    vb = jnp.moveaxis(vp.reshape(b, n_blocks, bs, h, d), 1, 0)
+
+    def step(acc, blk):
+        o, m, l, i = acc
+        k_pos = i * bs + jnp.arange(bs)
+        o, m, l = _softmax_fold(q, (o, m, l), blk[0], blk[1], scale,
+                                k_pos[None, :] < t)
+        return (o, m, l, i + 1), None
+
+    o0, m0, l0 = _fold_init(b, h, t, d)
+    (o, _, l, _), _ = jax.lax.scan(step, (o0, m0, l0, 0), (kb, vb))
+    return _fold_finalize(o, l, q.dtype)
+
+
+@pytest.mark.parametrize("t, bs", [(300, 256), (37, 8), (16, 64)])
+def test_unmasked_it_is_the_one_scan_it_was(rng, t, bs):
+    """CLIP's ``vision_attn: blockwise``: no ``while`` in the program, the
+    old scan's operations, the old scan's bits."""
+    from video_features_tpu.parallel.sequence import blockwise_attention
+    q, k, v = _qkv(rng, b=2, t=t, h=3, d=8)
+    new = jax.make_jaxpr(lambda *a: blockwise_attention(
+        *a, block_size=bs, scale=1.0))(q, k, v)
+    old = jax.make_jaxpr(lambda *a: _scan_over_every_block(
+        *a, block_size=bs, scale=1.0))(q, k, v)
+    assert "while" not in str(new) and "scan" in str(new)
+    assert str(new) == str(old)
+    assert "while" in str(jax.make_jaxpr(lambda *a: blockwise_attention(
+        *a, block_size=bs, causal=True))(q, k, v))
+    assert np.array_equal(
+        np.asarray(blockwise_attention(q, k, v, block_size=bs, scale=1.0)),
+        np.asarray(_scan_over_every_block(q, k, v, bs, 1.0)))
+
+
+@pytest.mark.parametrize("segments", [False, True])
+def test_a_fully_masked_tile_stays_finite(rng, monkeypatch, segments):
+    """T = 65 under tiles of 16: the last tile is one query and fifteen
+    padded ones, which match no key where there are segments; a row that is
+    all padding (segment 0 throughout) beside a packed one. No NaN anywhere
+    in the program, the padded tile's lines included."""
+    from video_features_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "Q_TILE", 16)
+    t = 65
+    q, k, v = _packed_qkv(rng, 2, t)
+    seg = np.stack([np.zeros(t, np.int32),
+                    np.repeat([1, 2, 0], [30, 25, 10]).astype(np.int32)])
+    with jax.debug_nans(True):
+        got = np.asarray(sequence.blockwise_attention(
+            q, k, v, block_size=8, causal=True, scale=0.11,
+            segment_ids=jnp.asarray(seg) if segments else None))
+    assert np.isfinite(got).all()
+    want = _dense_segments(q, k, v, jnp.asarray(seg) if segments
+                           else jnp.ones((2, t), jnp.int32), 0.11)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("row_len, lengths, want", [
+    # one tile, one block: nothing to skip
+    (96, (96, 34), [(1, 1), (1, 1)]),
+    # three tiles of 512 over three blocks; a document a row, then a row of
+    # two whose second starts in the second block, then one with padding
+    (1536, (1536, 600, 936, 1100), [(6, 9), (5, 9), (6, 9)]),
+])
+def test_the_packer_counts_the_blocks_attention_will_run(row_len, lengths,
+                                                         want):
+    """``attention.blocks`` (``kept``, ``total``) beside ``packer.pair_fill``
+    on the program's own timeline, a pair of samples a sealed row, from the
+    table the device computes its loop bounds from."""
+    from video_features_tpu.parallel.packer import SegmentPacker
+    from video_features_tpu.parallel.sequence import blocks_run
+    from video_features_tpu.telemetry import trace
+
+    class Runner:
+        fixed_batch = 1
+
+        def dispatch(self, group):
+            return np.zeros((len(group), 4, 1), np.float32)
+
+    recorder = trace.TraceRecorder(None).start()
+    try:
+        packer = SegmentPacker(Runner(), batch=1, row_len=row_len,
+                               max_segments=4)
+        handle = packer.open_video()
+        for n in lengths:
+            packer.add(handle, np.ones(n, np.int32))
+        packer.close_video(handle)
+    finally:
+        recorder.close()
+    events = [e for e in trace.last_recording().events()
+              if e.get("ph") == "C"]
+    names = [e["name"] for e in events if e["name"].startswith(
+        ("packer.pair_fill", "attention.blocks"))]
+    assert names == (["packer.pair_fill"] * 2
+                     + ["attention.blocks"] * 2) * len(want)
+    blocks = [e["args"] for e in events if e["name"] == "attention.blocks"]
+    assert blocks == [s for kept, total in want
+                      for s in ({"kept": kept}, {"total": total})]
+    assert all(kept <= total for kept, total in want)
+    # the same count from jax arrays, as the device has them
+    assert blocks_run(jnp.ones((2, row_len), jnp.int32)) == (
+        2 * want[0][0], 2 * want[0][1])

@@ -50,6 +50,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ..telemetry import trace
+from .sequence import blocks_run
 
 
 class ClipPacker:
@@ -374,6 +375,12 @@ class SegmentPacker(ClipPacker):
         trace.counter("packer.pair_fill", pairs, series="pairs")
         trace.counter("packer.pair_fill", self.row_len ** 2,
                       series="capacity")
+        if trace.active() is not None:
+            # ... and the (query tile, key block) pairs the token families'
+            # attention runs on this row, of all the row has
+            kept, total = blocks_run(row[1:])
+            trace.counter("attention.blocks", kept, series="kept")
+            trace.counter("attention.blocks", total, series="total")
         self._buf.append((members, row))
         self._row, self._row_fill = [], 0
 

@@ -19,9 +19,13 @@ single-chip memory wall:
     n_devices <= n_heads and T*T/n scores fit.
   - :func:`blockwise_attention` — the INTRA-device path: the same streaming
     log-sum-exp recurrence over K/V blocks on one device (FlashAttention at
-    the XLA level), O(T * block_size) score memory. Compose with
-    ring/Ulysses when a single shard's sequence is itself too long to score
-    densely.
+    the XLA level). Under a causal or a segment mask the queries are tiled
+    and each tile's loop runs only the key blocks the mask can keep a pair
+    of (:func:`block_bounds`, computed on the device from the ids it is
+    given; the host counts the same table as ``attention.blocks``):
+    O(H * Q_TILE * block_size) score memory. Unmasked it is one scan over
+    the blocks, O(T * block_size). Compose with ring/Ulysses when a single
+    shard's sequence is itself too long to score densely.
 
 The sharded pair are written as shard_map bodies (take ``axis_name``) plus
 convenience wrappers that build the shard_map over a 1-D ``seq`` mesh. All
@@ -94,8 +98,61 @@ def _softmax_fold(q, acc, ck, cv, scale, valid):
     return o, m_new, l
 
 
+#: keys a block (``block_size``'s default) and queries a tile of
+#: :func:`blockwise_attention`; the host counts with the same two
+#: (``parallel/packer.py``: ``attention.blocks``)
+BLOCK_SIZE = 512
+Q_TILE = 512
+
+
+def block_bounds(segment_ids, t: int, q_tile: int, block_size: int,
+                 causal: bool):
+    """``(lo, hi)``, each (B, tiles) (B = 1 without ``segment_ids``): the key
+    blocks ``lo <= i < hi`` are all that the tile's queries can keep a key
+    of. A pair (tile, block) MAY hold a valid (query, key) only if, where
+    ``causal``, the block's first key is not after the tile's last query,
+    and, where ``segment_ids`` (B, T) is given, the ranges [min, max] of the
+    ids of the tile's queries and of the block's keys overlap. ``lo`` is the
+    first such block and ``hi`` one past the last (0, 0 where there is none).
+    The test is conservative for any ids, sorted or not: a block inside the
+    bounds may still hold nothing, a block outside them holds nothing.
+    ``numpy`` in, ``numpy`` out (the host counts what the device will run);
+    ``jax`` arrays in, ``jax.numpy`` out."""
+    xp = jnp if isinstance(segment_ids, jax.Array) else np
+    qt, bs = min(q_tile, t), min(block_size, t)
+    n_tiles, n_blocks = -(-t // qt), -(-t // bs)
+    may = xp.ones((1, n_tiles, n_blocks), bool)
+    if causal:
+        last_query = xp.minimum((xp.arange(n_tiles) + 1) * qt, t) - 1
+        may = may & (xp.arange(n_blocks) * bs <= last_query[:, None])
+    if segment_ids is not None:
+        def ranges(size, n):
+            # the last real id stands in the pad: no range moves
+            ids = xp.pad(segment_ids, ((0, 0), (0, n * size - t)),
+                         mode="edge").reshape(-1, n, size)
+            return ids.min(-1), ids.max(-1)
+
+        q_min, q_max = ranges(qt, n_tiles)
+        k_min, k_max = ranges(bs, n_blocks)
+        may = (may & (q_min[:, :, None] <= k_max[:, None, :])
+               & (k_min[:, None, :] <= q_max[:, :, None]))
+    some = may.any(-1)
+    return (xp.where(some, may.argmax(-1), 0),
+            xp.where(some, n_blocks - may[..., ::-1].argmax(-1), 0))
+
+
+def blocks_run(segment_ids, causal: bool = True):
+    """``(kept, total)``: the (query tile, key block) pairs a call with these
+    ``segment_ids`` (B, T), the module's tile and the default block folds,
+    and all the rows have. On the host (``numpy`` ids) it is the counter
+    ``attention.blocks``."""
+    t = segment_ids.shape[-1]
+    lo, hi = block_bounds(segment_ids, t, Q_TILE, BLOCK_SIZE, causal)
+    return int((hi - lo).sum()), lo.size * -(-t // min(BLOCK_SIZE, t))
+
+
 def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                        block_size: int = 512, causal: bool = False,
+                        block_size: int = BLOCK_SIZE, causal: bool = False,
                         scale: Optional[float] = None,
                         segment_ids: Optional[jnp.ndarray] = None
                         ) -> jnp.ndarray:
@@ -104,14 +161,26 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     than the query/key head (deepseek_v2's latent attention: 192 and 128);
     the accumulator is as wide as ``v``.
 
-    The intra-device complement of :func:`ring_attention`: a ``lax.scan``
-    over K/V blocks with the same streaming log-sum-exp softmax, so peak
-    score memory is O(T * block_size) instead of O(T^2) — the
-    FlashAttention recurrence expressed at the XLA level. Use it when one
-    device's sequence shard is itself too long to score densely; compose
-    with ring/Ulysses for the cross-device axis. T need not divide
-    block_size (keys pad with a mask). ``segment_ids`` (B, T) packs several
-    documents into a row: a query attends only to keys of its own segment.
+    The intra-device complement of :func:`ring_attention`: the same
+    streaming log-sum-exp softmax over K/V blocks of ``block_size`` keys,
+    folded in ascending order: the FlashAttention recurrence expressed at
+    the XLA level. T need not divide ``block_size`` (keys pad with a mask).
+    ``segment_ids`` (B, T) packs several documents into a row: a query
+    attends only to keys of its own segment.
+
+    What the mask can throw away whole is not computed. Where ``causal`` or
+    ``segment_ids`` is given, a ``lax.scan`` runs over (row, tile of
+    ``Q_TILE`` queries) and, inside, a ``lax.fori_loop`` over the key blocks
+    ``lo <= i < hi`` of :func:`block_bounds`, computed on the device from
+    the ids the call was given: a 16,384-token causal document folds 528 of
+    its 1,024 (512 x 512) pairs. Every element is still masked as before,
+    so the bounds decide cost and never the result: a block outside them is
+    one whose fold is the identity (``s = -inf``, ``p = 0``, ``alpha = 1``).
+    A tile carries its own float32 ``(o, m, l)``; peak score memory is
+    O(H * Q_TILE * block_size). The loop is a ``while`` on the device:
+    nothing differentiates through it. With no mask at all nothing can be
+    skipped: one ``lax.scan`` over the K/V blocks scores every query
+    against each, O(T * block_size) scores.
     """
     b, t, h, d = q.shape
     dv = v.shape[-1]
@@ -121,32 +190,77 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     pad = n_blocks * bs - t
     kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    if not causal and segment_ids is None:
+        return _every_block(q, kp, vp, bs, scale)
+
+    qt = min(Q_TILE, t)
+    n_tiles = -(-t // qt)
+    q_pad = n_tiles * qt - t
+    lo, hi = (jnp.broadcast_to(x, (b, n_tiles)).reshape(-1).astype(jnp.int32)
+              for x in block_bounds(segment_ids, t, Q_TILE, block_size,
+                                    causal))
+    # rows and tiles along one scan axis: a step is one row's tile, so its
+    # bounds are that row's own; a row's key blocks are indexed beside it
+    tiles = (jnp.pad(q, ((0, 0), (0, q_pad), (0, 0), (0, 0))
+                     ).reshape(b * n_tiles, 1, qt, h, d),
+             jnp.repeat(jnp.arange(b), n_tiles),
+             jnp.tile(jnp.arange(n_tiles), b), lo, hi)
+    kb = kp.reshape(b * n_blocks, 1, bs, h, d)
+    vb = vp.reshape(b * n_blocks, 1, bs, h, dv)
+    if segment_ids is not None:
+        # a padded query or key is in no segment
+        tiles += (jnp.pad(segment_ids, ((0, 0), (0, q_pad)),
+                          constant_values=-1).reshape(b * n_tiles, 1, qt),)
+        sb = jnp.pad(segment_ids, ((0, 0), (0, pad)),
+                     constant_values=-1).reshape(b * n_blocks, 1, bs)
+
+    def tile_step(_, tile):
+        cq, row, j, lo, hi = tile[:5]
+        q_pos = j * qt + jnp.arange(qt)
+
+        def fold(i, acc):
+            at = row * n_blocks + i
+            ck, cv = (jax.lax.dynamic_index_in_dim(x, at, keepdims=False)
+                      for x in (kb, vb))
+            k_pos = i * bs + jnp.arange(bs)
+            valid = k_pos[None, :] < t
+            if causal:
+                valid = valid & (q_pos[:, None] >= k_pos[None, :])
+            if segment_ids is not None:   # (1, 1, qt, bs) over (1, H, qt, bs)
+                valid = valid & (
+                    tile[5][:, None, :, None]
+                    == jax.lax.dynamic_index_in_dim(
+                        sb, at, keepdims=False)[:, None, None, :])
+            return _softmax_fold(cq, acc, ck, cv, scale, valid)
+
+        o, _, l = jax.lax.fori_loop(lo, hi, fold, _fold_init(1, h, qt, dv))
+        return None, _fold_finalize(o, l, q.dtype)
+
+    _, out = jax.lax.scan(tile_step, None, tiles)
+    return out.reshape(b, n_tiles * qt, h, dv)[:, :t]
+
+
+def _every_block(q, kp, vp, bs, scale):
+    """:func:`blockwise_attention` with nothing to skip: one scan over the
+    K/V blocks of ``bs`` keys, all T queries against each; ``kp`` / ``vp``
+    are padded to whole blocks."""
+    b, t, h, d = q.shape
+    dv = vp.shape[-1]
+    n_blocks = kp.shape[1] // bs
     # (n_blocks, B, bs, H, D) scan sequence
     kb = jnp.moveaxis(kp.reshape(b, n_blocks, bs, h, d), 1, 0)
     vb = jnp.moveaxis(vp.reshape(b, n_blocks, bs, h, dv), 1, 0)
-    q_pos = jnp.arange(t)
-    blocks = (kb, vb)
-    if segment_ids is not None:
-        # (n_blocks, B, bs) beside the keys; the pad is in no query's segment
-        blocks += (jnp.moveaxis(jnp.pad(
-            segment_ids, ((0, 0), (0, pad)), constant_values=-1
-        ).reshape(b, n_blocks, bs), 1, 0),)
 
     def step(acc, blk):
         o, m, l, i = acc
-        ck, cv = blk[:2]
+        ck, cv = blk
         k_pos = i * bs + jnp.arange(bs)
         valid = k_pos[None, :] < t
-        if causal:
-            valid = valid & (q_pos[:, None] >= k_pos[None, :])
-        if segment_ids is not None:   # (B, 1, T, bs) over (B, H, T, bs)
-            valid = valid & (segment_ids[:, None, :, None]
-                             == blk[2][:, None, None, :])
         o, m, l = _softmax_fold(q, (o, m, l), ck, cv, scale, valid)
         return (o, m, l, i + 1), None
 
     o0, m0, l0 = _fold_init(b, h, t, dv)
-    (o, _, l, _), _ = jax.lax.scan(step, (o0, m0, l0, 0), blocks)
+    (o, _, l, _), _ = jax.lax.scan(step, (o0, m0, l0, 0), (kb, vb))
     return _fold_finalize(o, l, q.dtype)
 
 

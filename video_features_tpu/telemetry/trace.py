@@ -163,6 +163,7 @@ KNOWN_COUNTER_NAMES = (
     "packer.ragged_flush",  # rows of the one ragged dispatch, when it fires
     "packer.row_fill",      # a sealed token row: series tokens / capacity
     "packer.pair_fill",     # ... its causal same-document pairs / T squared
+    "attention.blocks",     # ... the block pairs attention folds: kept / total
     "stream.inflight",      # un-materialized outputs of a FeatureStream
 )
 

@@ -5,7 +5,9 @@ env vars are set at conftest import time. Multi-chip sharding is validated on
 this virtual mesh (no multi-chip TPU hardware in CI); the real chip is
 exercised by chip_smoke.py instead.
 """
+import faulthandler
 import os
+import signal
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -21,6 +23,62 @@ jax.config.update("jax_platforms", "cpu")
 
 # full-fp32 conv/matmul accumulation: parity tests compare against torch CPU
 jax.config.update("jax_default_matmul_precision", "highest")
+
+#: seconds one test may take: thirteen times the slowest alone at PR 34
+#: (22 s), two and a half times the slowest beside the driver's five other
+#: workers (84-124 s in six whole runs). Past it the test fails with every
+#: thread's stack on stderr and the run goes on
+TEST_TIME_LIMIT_S = 300
+
+_stderr = None
+
+
+def pytest_configure(config):
+    # fd 2 as it is before the per-test capture swaps it: the stacks of a
+    # test that is cut must reach the terminal, not a capture file that
+    # dies with the process
+    global _stderr
+    _stderr = os.fdopen(os.dup(2), "w")
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_handlecrashitem(crashitem, report, sched):
+    # xdist 3.8 under --dist loadfile puts a dead worker's file back in the
+    # queue with the test it died in still to run, so one crash repeats
+    # until the run gives up (--max-worker-restart): count the test as
+    # run (xdist reports it failed) and let the rest of its file go on
+    unit = getattr(sched, "workqueue", {}).get(crashitem.rsplit("::", 1)[0])
+    if unit is not None:
+        unit[crashitem] = True
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """Every test's own clock (this machine has no pytest-timeout)."""
+    limit = TEST_TIME_LIMIT_S
+
+    def expired(signum, frame):
+        faulthandler.cancel_dump_traceback_later()
+        print(f"\nTIME LIMIT: {request.node.nodeid} outlasted {limit}s; "
+              "every thread's stack:", file=_stderr, flush=True)
+        faulthandler.dump_traceback(file=_stderr, all_threads=True)
+        pytest.fail(f"outlasted the {limit}s time limit (stacks on stderr)",
+                    pytrace=False)
+
+    # a main thread stuck inside a C call (cv2's read() under a released
+    # capture was one) never runs `expired`: a tenth later faulthandler's
+    # own thread dumps the stacks and ends the process, and xdist reports
+    # the test as crashed and replaces the worker
+    faulthandler.dump_traceback_later(limit * 1.1, exit=True, file=_stderr)
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+        faulthandler.cancel_dump_traceback_later()
+
 
 REFERENCE_ROOT = "/root/reference"
 SAMPLE_VIDEO = os.path.join(REFERENCE_ROOT, "sample", "v_GGSY1Qvo990.mp4")

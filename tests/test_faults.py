@@ -6,8 +6,12 @@ Tier-1 discipline: the retry tests inject ``sleep``/``clock`` so no real
 backoff is ever slept; the watchdog tests use sub-second deadlines.
 """
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -17,6 +21,8 @@ from video_features_tpu.utils.faults import (FailureJournal, FaultContext,
                                              DeadlineExceeded, RetryPolicy)
 
 pytestmark = pytest.mark.quick
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------- taxonomy
@@ -316,6 +322,86 @@ def test_deadline_cancels_real_videosource(sample_video):
     assert 0 < n < 355  # genuinely interrupted mid-video
 
 
+# run in a child: what a cancel under a running read did at PR 33 (abort in
+# libavcodec, or a deadlock inside cv2) must not take the test worker along
+_CANCEL_ROUNDS = r"""
+import random, sys, threading, time
+from video_features_tpu.parallel.fanout import FrameBus
+from video_features_tpu.utils.faults import DeadlineExceeded
+from video_features_tpu.utils.io import VideoSource
+
+what, path, rounds = sys.argv[1], sys.argv[2], int(sys.argv[3])
+rng = random.Random(0)
+
+
+def run_round():
+    ended = []
+
+    def drain(source, again):
+        try:
+            frames = source().frames
+            while True:
+                for _ in frames():
+                    pass
+                if not again:
+                    break
+            ended.append("end")
+        except DeadlineExceeded:
+            ended.append("deadline")
+
+    if what == "source":
+        # one pass takes 0.2 s: go round until the cancel lands
+        target = src = VideoSource(path)
+        threads = [threading.Thread(target=drain, args=(lambda: src, True))]
+    else:
+        target = bus = FrameBus(path, ["a", "b"])
+        threads = [threading.Thread(
+            target=drain, args=(lambda n=n: bus.subscribe(n), False))
+            for n in ("a", "b")]
+    for t in threads:
+        t.start()
+    if what == "bus":
+        # the pass starts once both have subscribed, on a thread of its own
+        while bus._thread is None:
+            time.sleep(0.001)
+        threads.append(bus._thread)
+    time.sleep(rng.uniform(0.0, 0.02))
+    target.cancel("round over")
+    for t in threads:
+        t.join(5)
+        assert not t.is_alive(), f"{t.name} still running 5 s after cancel"
+    return ended
+
+
+cancelled = attempts = 0
+while cancelled < rounds:
+    attempts += 1
+    # a bus whose one pass was over before the cancel is not a round
+    assert attempts <= 2 * rounds, (cancelled, attempts)
+    ended = run_round()
+    assert what == "bus" or ended == ["deadline"], ended
+    cancelled += set(ended) == {"deadline"}
+print(f"{what}: {cancelled} rounds cancelled in {attempts}")
+"""
+
+
+@pytest.mark.parametrize("what,rounds", [("source", 200), ("bus", 20)])
+def test_cancel_under_a_running_read(sample_video, what, rounds):
+    """cancel() from another thread while the owner decodes flat out:
+    every round ends in DeadlineExceeded within 5 s and the process lives
+    (a VideoSource, and a FrameBus with two subscribers: a subscriber
+    polls its queue once a second, so a bus round costs a second and 20
+    are run). At PR 33 cancel() released the capture under the read and
+    either count aborted or stood still, six runs of six."""
+    done = subprocess.run(
+        [sys.executable, "-c", _CANCEL_ROUNDS, what, str(sample_video),
+         str(rounds)],
+        cwd=REPO, capture_output=True, text=True, timeout=240)
+    assert done.returncode == 0, (done.returncode, done.stdout[-2000:],
+                                  done.stderr[-2000:])
+    assert f"{what}: {rounds} rounds cancelled" in done.stdout
+
+
 def test_register_after_expiry_cancels_immediately():
     cancelled = []
 
@@ -446,3 +532,74 @@ def test_cli_run_quarantines_and_tallies(tmp_path, capsys, monkeypatch):
     main(argv + ["retry_failed=true"])
     out3 = capsys.readouterr().out
     assert "1 failed" in out3  # re-ran (and failed again: still corrupt)
+
+
+# ------------------------------------------- the suite's own time limit
+
+_TWO_TESTS = """
+import ctypes
+import time
+
+
+def test_outlasts_the_limit():
+    if {stuck}:
+        # a main thread no signal handler can reach: the second lock of a
+        # plain mutex never returns (cv2's read() under a released capture
+        # stood still the same way)
+        libc = ctypes.CDLL(None)
+        mutex = ctypes.create_string_buffer(64)
+        libc.pthread_mutex_lock(mutex)
+        libc.pthread_mutex_lock(mutex)
+    time.sleep(30)
+
+
+def test_after_it():
+    assert True
+"""
+
+# the limit is a constant of tests/conftest.py with no option to set it:
+# the child's conftest takes that module's hooks and fixture and a limit of 1 s
+_ONE_SECOND_CONFTEST = """
+import importlib.util
+
+spec = importlib.util.spec_from_file_location("repo_conftest", {conftest!r})
+repo_conftest = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(repo_conftest)
+repo_conftest.TEST_TIME_LIMIT_S = 1
+pytest_configure = repo_conftest.pytest_configure
+pytest_handlecrashitem = repo_conftest.pytest_handlecrashitem
+_time_limit = repo_conftest._time_limit
+"""
+
+_AS_THE_DRIVER = ["-p", "xdist", "-n", "2", "--dist", "loadfile"]
+
+
+@pytest.mark.parametrize("stuck,how", [
+    (False, ["-p", "no:xdist"]),
+    (False, _AS_THE_DRIVER),
+    (True, _AS_THE_DRIVER),
+], ids=["sleeping-serial", "sleeping-xdist", "stuck-in-c-xdist"])
+def test_time_limit_fails_the_test_and_the_run_goes_on(tmp_path, stuck, how):
+    """A test that outlasts the limit fails with every thread's stack on
+    stderr, and the test after it still runs and passes: the signal's
+    handler where the main thread can be interrupted, the end of the
+    worker (reported and replaced by xdist) where it cannot."""
+    if "xdist" in how:
+        pytest.importorskip("xdist")
+    (tmp_path / "conftest.py").write_text(_ONE_SECOND_CONFTEST.format(
+        conftest=str(REPO / "tests" / "conftest.py")))
+    (tmp_path / "test_two.py").write_text(_TWO_TESTS.format(stuck=stuck))
+    env = {k: v for k, v in os.environ.items() if not k.startswith("PYTEST_")}
+    done = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         *how, "test_two.py"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=200)
+    assert "1 failed, 1 passed" in done.stdout, (done.stdout[-3000:],
+                                                 done.stderr[-3000:])
+    assert "test_two.py::test_outlasts_the_limit" in done.stdout
+    # the stacks: the test's own frame, on the real stderr
+    assert "most recent call first" in done.stderr
+    assert "in test_outlasts_the_limit" in done.stderr
+    if not stuck:
+        assert "TIME LIMIT: test_two.py::test_outlasts_the_limit" \
+            in done.stderr

@@ -362,6 +362,102 @@ def test_reencode_tmp_file_cleanup(sample_video, tmp_path):
     assert kept.exists(), "keep_tmp=True must preserve the temp file"
 
 
+def test_release_of_an_unread_source_drops_its_tmp_file(sample_video,
+                                                        tmp_path):
+    """release() touches no capture (nobody iterates, none is open) and
+    still cleans up what the constructor made."""
+    src = VideoSource(sample_video, fps=2.0, fps_mode="reencode",
+                      tmp_path=str(tmp_path))
+    tmp_file = Path(src._tmp_file)
+    assert tmp_file.exists()
+    src.release()
+    assert not tmp_file.exists()
+    with pytest.raises(RuntimeError, match="single-pass"):
+        next(src.frames())
+
+
+def test_cancelled_stream_is_released_by_the_thread_that_reads_it(
+        sample_video, monkeypatch):
+    """One thread owns a capture: cancel() from another thread only sets
+    the flag, and the capture is released where it was opened."""
+    import threading
+
+    import cv2
+
+    from video_features_tpu.utils.faults import DeadlineExceeded
+    log = []  # (opened by, released by) per capture
+
+    real = cv2.VideoCapture
+
+    class RecordingCapture:
+        def __init__(self, *a):
+            self.cap = real(*a)
+            self.opened_by = threading.get_ident()
+
+        def release(self):
+            log.append((self.opened_by, threading.get_ident()))
+            self.cap.release()
+
+        def __getattr__(self, name):
+            return getattr(self.cap, name)
+
+    monkeypatch.setattr(cv2, "VideoCapture", RecordingCapture)
+    src = VideoSource(sample_video)
+    first_frame, cancelled = threading.Event(), threading.Event()
+    raised = []
+
+    def iterate():
+        try:
+            for _ in src.frames():
+                first_frame.set()
+                cancelled.wait(5)
+        except DeadlineExceeded as e:
+            raised.append(e)
+
+    reader = threading.Thread(target=iterate)
+    reader.start()
+    assert first_frame.wait(5)
+    before = len(log)
+    src.cancel("enough")
+    assert len(log) == before, "cancel() released a capture"
+    cancelled.set()
+    reader.join(5)
+    assert not reader.is_alive() and len(raised) == 1
+    assert "enough" in str(raised[0])
+    assert log[before:] == [(reader.ident, reader.ident)]
+    assert all(o == r for o, r in log), log
+
+
+@pytest.mark.parametrize("fps", [None, 1.0], ids=["every-frame", "fps-filter"])
+def test_cancel_is_seen_within_one_source_frame(sample_video, monkeypatch,
+                                                fps):
+    """The flag is looked at before every source frame, the ones an fps
+    filter drops included (~19 in a row at 1 fps): no decode call follows
+    a cancel()."""
+    from video_features_tpu.utils import io as vio
+    from video_features_tpu.utils.faults import DeadlineExceeded
+    src = VideoSource(sample_video, fps=fps)
+    calls = []
+
+    def counting(name):
+        real = getattr(vio._FrameStream, name)
+
+        def call(stream):
+            calls.append(name)
+            if len(calls) == 30:
+                src.cancel("at the 30th source frame")
+            return real(stream)
+        return call
+
+    monkeypatch.setattr(vio._FrameStream, "read", counting("read"))
+    monkeypatch.setattr(vio._FrameStream, "skip", counting("skip"))
+    with pytest.raises(DeadlineExceeded, match="30th source frame"):
+        for _ in src.frames():
+            pass
+    assert len(calls) == 30
+    assert ("skip" in calls) == (fps is not None)
+
+
 def test_reencode_total_mode(sample_video, tmp_path):
     """total + reencode: the reference derives fps from total and decodes
     the re-encoded file capped at total frames (utils/io.py:83-89)."""

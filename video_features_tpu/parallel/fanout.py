@@ -306,7 +306,6 @@ class FrameBus:
         self._started = False
         self._probe_error: Optional[str] = None
         self._thread: Optional[threading.Thread] = None
-        self._stream: Optional[_FrameStream] = None
         self._cancelled = False
         #: cumulative shared decode seconds (read/skip/cvtColor); written
         #: only by the decode thread, read for per-family attribution
@@ -389,17 +388,16 @@ class FrameBus:
         return None if sub is None else sub.decode_shared_ms
 
     def cancel(self, reason: str = "cancelled") -> None:
-        """Kill the whole pass (every family fails with
-        DeadlineExceeded semantics via its own source cancel)."""
+        """Cancel the whole pass (every family fails with
+        DeadlineExceeded semantics via its own source cancel). The bus
+        thread sees the flag before its next source frame and releases
+        the stream it opened; no other thread touches that capture."""
         self._cancelled = True
         with self._cond:
             subs = list(self._subs.values())
-            stream = self._stream
             self._cond.notify_all()
         for s in subs:
             s.cancel(reason)
-        if stream is not None:
-            stream.release()
 
     # -- barrier + plan probing ---------------------------------------------
     def _all_arrived(self) -> bool:
@@ -456,8 +454,6 @@ class FrameBus:
         finished: set = set()
         t_pass = time.perf_counter()
         stream = _FrameStream(self.path, channel_order="bgr")
-        with self._cond:
-            self._stream = stream
         try:
             src_idx = 0
             while not self._cancelled:
@@ -560,8 +556,6 @@ class FrameBus:
                 s._error = msg
                 s._push(("error", msg))
         finally:
-            with self._cond:
-                self._stream = None
             stream.release()
             # one umbrella span over the whole union pass: on the bus
             # thread's lane it brackets the per-frame decode stage spans,

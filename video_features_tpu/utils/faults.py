@@ -230,10 +230,14 @@ class FaultContext:
 
     The watchdog is a daemon :class:`threading.Timer`; at
     ``deadline_s`` it calls ``cancel()`` on every registered source.
-    Cancellation is cooperative-but-forceful: sources release their
-    underlying capture/worker processes (unblocking a stuck ``read()``)
-    and raise :class:`DeadlineExceeded` from their ``frames()`` loop, so
-    only THIS video fails — the worker thread survives.
+    Cancellation is cooperative: an in-thread source (``VideoSource``,
+    the shared ``FrameBus``) is only flagged — the thread that opened the
+    capture sees the flag before its next frame, raises
+    :class:`DeadlineExceeded` from its ``frames()`` loop and releases its
+    own capture, so only THIS video fails and the worker thread survives.
+    No capture is released under a running read (cv2 deadlocks or aborts
+    there). A decoder stuck inside one call is bounded by
+    ``video_decode=process``, whose ``cancel()`` ends a child process.
     """
 
     def __init__(self, video_path: str, deadline_s: Optional[float] = None,
@@ -271,7 +275,7 @@ class FaultContext:
             self.deadline_expired = True
             sources = list(self._sources)
         print(f"WATCHDOG: {self.video_path} exceeded video_deadline_s="
-              f"{self.deadline_s}; killing its in-flight decode "
+              f"{self.deadline_s}; cancelling its in-flight decode "
               f"({len(sources)} source(s))")
         from .. import telemetry
         telemetry.inc("vft_deadline_expirations_total")

@@ -35,7 +35,6 @@ ffmpeg-less hosts (docs/performance.md records the measured numbers).
 """
 from __future__ import annotations
 
-import threading
 from pathlib import Path
 from typing import Callable, Iterator, List, Optional, Tuple, Union
 
@@ -262,6 +261,11 @@ class _FrameStream:
     ``resize=device``), converted back to RGB on device
     (ops/colorspace.py). Requires even frame dimensions (I420 chroma
     subsampling).
+
+    One thread owns a stream: the one that opened it reads and releases
+    it. cv2 is safe per capture, not across threads on one capture (a
+    ``release()`` under another thread's ``read()`` deadlocks or aborts
+    in libavcodec's frame threads).
     """
 
     def __init__(self, path: str, channel_order: str = "rgb"):
@@ -280,17 +284,11 @@ class _FrameStream:
     def read(self) -> Optional[np.ndarray]:
         if self._inject is not None:
             self._inject.check("decode.read", {"video": self._path})
-        # local ref: a concurrent release() (deadline watchdog) nulls
-        # self.cap; going through the local keeps this thread's call
-        # coherent and the next loop iteration observes the None
-        cap = self.cap
-        if cap is None:
-            return None
-        ok, frame = cap.read()
+        ok, frame = self.cap.read()
         if not ok and self._first:
             # cv2 sometimes fails on frame #0 only (reference utils/io.py:99-106)
             print("Detect missing frame")
-            ok, frame = cap.read()
+            ok, frame = self.cap.read()
         self._first = False
         if not ok:
             return None
@@ -304,23 +302,15 @@ class _FrameStream:
         by the fps filter — they pay decode only, never conversion.
         Same frame-0 retry as :meth:`read` (the missing-frame-0 workaround
         shifts indices identically on both paths)."""
-        cap = self.cap
-        if cap is None:
-            return False
-        ok = cap.grab()
+        ok = self.cap.grab()
         if not ok and self._first:
             print("Detect missing frame")
-            ok = cap.grab()
+            ok = self.cap.grab()
         self._first = False
         return ok
 
     def release(self):
-        # swap-then-release: idempotent and callable from the watchdog
-        # thread while the decode thread is inside read()/skip() — cv2
-        # fails the in-flight call instead of blocking forever
-        cap, self.cap = self.cap, None
-        if cap is not None:
-            cap.release()
+        self.cap.release()
 
 
 class VideoSource:
@@ -360,11 +350,9 @@ class VideoSource:
         self.channel_order = channel_order
 
         # deadline-watchdog support (utils/faults.py FaultContext):
-        # cancel() is thread-safe and kills the in-flight decode
+        # cancel() sets the flag, frames() sees it at its next frame
         self._cancelled = False
         self._cancel_reason = ""
-        self._active_stream: Optional[_FrameStream] = None
-        self._state_lock = threading.Lock()
 
         self._tmp_file: Optional[str] = None
         self._keep_tmp = keep_tmp
@@ -406,23 +394,22 @@ class VideoSource:
         return self.num_frames
 
     def cancel(self, reason: str = "cancelled") -> None:
-        """Thread-safe kill of the in-flight decode (deadline watchdog).
+        """Thread-safe cancel of the in-flight decode (deadline watchdog).
 
-        Marks the source cancelled and releases the active
-        ``_FrameStream`` so a read blocked inside cv2 fails promptly; the
-        iterating thread then raises :class:`DeadlineExceeded` instead of
-        emitting a silently-truncated stream."""
-        with self._state_lock:
-            self._cancelled = True
-            self._cancel_reason = reason or "cancelled"
-            stream = self._active_stream
-        if stream is not None:
-            stream.release()
+        Marks the source cancelled and touches no capture: the iterating
+        thread sees the flag before its next source frame, raises
+        :class:`DeadlineExceeded` instead of emitting a silently-truncated
+        stream, and releases its own stream on the way out. A decoder
+        stuck INSIDE one cv2 call is not interrupted here: that is what
+        ``video_decode=process`` bounds (its cancel ends a child process)."""
+        self._cancel_reason = reason or "cancelled"
+        self._cancelled = True
 
     def release(self) -> None:
         """Thread-safe teardown (same surface as ProcessVideoSource /
-        ParallelVideoSource): cancels any in-flight iteration and drops
-        the re-encoded temp file if one exists."""
+        ParallelVideoSource): cancels any in-flight iteration (which
+        releases its own stream) and drops the re-encoded temp file if
+        one exists."""
         self.cancel("released")
         self._cleanup_tmp()
 
@@ -458,17 +445,6 @@ class VideoSource:
                 "pass: its re-encoded temp file was already deleted "
                 "(construct a new source, or pass keep_tmp=True)")
         stream = _FrameStream(self.path, self.channel_order)
-        with self._state_lock:
-            self._active_stream = stream
-        # checked AFTER registering: a cancel() landing between flag-set
-        # and registration is caught here instead of being lost
-        try:
-            self._raise_if_cancelled()
-        except DeadlineExceeded:
-            with self._state_lock:
-                self._active_stream = None
-            stream.release()
-            raise
         tf = self.transform
 
         # `decode` stays the stage every reader knows; its children say
@@ -493,10 +469,6 @@ class VideoSource:
                     self._raise_if_cancelled()
                     rgb = timed_read()
                     if rgb is None:
-                        # a watchdog-released stream ends exactly like a
-                        # normal EOF — distinguish them or a killed decode
-                        # would write truncated features as a success
-                        self._raise_if_cancelled()
                         return
                     yield emit(rgb, out_idx)
                     out_idx += 1
@@ -504,8 +476,11 @@ class VideoSource:
                 src_idx = -1
                 current = None
                 for out_idx, want in enumerate(self.index_map):
-                    self._raise_if_cancelled()
                     while src_idx < want:
+                        # before every source frame, read or skipped: a
+                        # cancel is seen within one decoded frame, however
+                        # many the fps filter drops in a row
+                        self._raise_if_cancelled()
                         if src_idx < want - 1:
                             # this source frame is dropped by the fps
                             # filter: grab()-skip it (no conversion/copy,
@@ -518,7 +493,6 @@ class VideoSource:
                             nxt = timed_read()
                             current = nxt
                         if nxt is None:
-                            self._raise_if_cancelled()
                             # container metadata overstated the frame count;
                             # reaching stream end inside this loop always
                             # means the resampled output is short
@@ -531,8 +505,6 @@ class VideoSource:
                         src_idx += 1
                     yield emit(current, out_idx)
         finally:
-            with self._state_lock:
-                self._active_stream = None
             stream.release()
             self._cleanup_tmp()
 

@@ -1,6 +1,8 @@
 """``ops/moe.py held_experts``: the buffer the sorted assignments go through
-is as long as the share of the experts held here asks for, and what comes
-out is, bit for bit, what the full-length layer of PR 28 gave."""
+is as long as the share of the experts held here asks for, the way back is
+choice-major with a float32 sum over the leading axis, and what comes out
+is, bit for bit on the CPU, what the full-length layer of PR 28 gave, and
+within rounding what a plain loop over tokens and choices gives."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -117,27 +119,35 @@ def equations(jaxpr):
                     yield from equations(inner)
 
 
-def shaped_equations(jaxpr):
-    """``primitive: input shapes -> output shapes`` of every equation,
-    sorted: what a program computes, whatever order it was written in."""
-    return sorted(
-        f"{e.primitive.name}: "
-        f"{[str(getattr(v, 'aval', v)) for v in e.invars]} -> "
-        f"{[str(v.aval) for v in e.outvars]}" for e in equations(jaxpr))
-
-
 def row_buffers(jaxpr, width):
-    """The leading lengths of what each ``ragged_dot`` reads and writes and
-    of every gather of a (rows, ``width``) array."""
-    lengths = {"ragged_dot": [], "gather": []}
+    """What the row traffic of a jaxpr is made of: the leading lengths of
+    what each ``ragged_dot`` reads and writes (``ragged_dot``) and of every
+    gather of (rows, ``width``) (``rows_in``), and the shape of every gather
+    that yields a three-axis array of ``width`` (``back``)."""
+    found = {"ragged_dot": [], "rows_in": [], "back": []}
     for eqn in equations(jaxpr):
-        name = eqn.primitive.name
+        name, shape = eqn.primitive.name, eqn.outvars[0].aval.shape
         if name == "ragged_dot_general":
-            lengths["ragged_dot"] += [eqn.invars[0].aval.shape[0],
-                                      eqn.outvars[0].aval.shape[0]]
-        elif name == "gather" and eqn.outvars[0].aval.shape[1:] == (width,):
-            lengths["gather"].append(eqn.outvars[0].aval.shape[0])
-    return lengths
+            found["ragged_dot"] += [eqn.invars[0].aval.shape[0], shape[0]]
+        elif name == "gather" and shape[1:] == (width,):
+            found["rows_in"].append(shape[0])
+        elif name == "gather" and len(shape) == 3 and shape[2] == width:
+            found["back"].append(shape)
+    return found
+
+
+def choice_sums(jaxpr, k, t, width):
+    """``(operand dtype, axes)`` of every sum over an array of (``k``,
+    ``t``, ``width``), after checking that no equation of the jaxpr yields
+    one of (``t``, ``k``, ``width``): the choices never sit in a tile's
+    sublanes."""
+    sums = []
+    for eqn in equations(jaxpr):
+        assert all(v.aval.shape != (t, k, width) for v in eqn.outvars), eqn
+        if eqn.primitive.name == "reduce_sum" and \
+                eqn.invars[0].aval.shape == (k, t, width):
+            sums.append((eqn.invars[0].aval.dtype, eqn.params["axes"]))
+    return sums
 
 
 #: two layers of the token family's tiny architecture: 8 experts, top 3
@@ -146,47 +156,160 @@ BATCH, ROW = 16, 96     # 1,536 tokens, 4,608 assignments a layer
 
 
 def step_jaxpr(shards):
+    """The step in bfloat16: what is float32 in it was made so."""
     arch = gh.arch_from_config(TINY, shards, 0)
-    params = jax.eval_shape(lambda: gh.init_params(arch, 0, jnp.float32))
+    params = jax.eval_shape(lambda: gh.init_params(arch, 0, jnp.bfloat16))
     rows = jax.ShapeDtypeStruct((BATCH, 2, ROW), jnp.int32)
     return jax.make_jaxpr(
-        lambda p, r: gh.token_states(arch, p, r, jnp.float32))(params, rows)
+        lambda p, r: gh.token_states(arch, p, r, jnp.bfloat16))(params, rows)
 
 
 def test_with_half_the_experts_held_the_steps_buffers_are_the_bound_long():
     jaxpr = step_jaxpr(2).jaxpr
     conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
     assert len(conds) == 2                       # one a layer
-    n, every = moe.held_rows(BATCH * ROW * 3, 4, 8), BATCH * ROW * 3
+    tokens, every = BATCH * ROW, BATCH * ROW * 3
+    n = moe.held_rows(every, 4, 8)
     assert (n, every) == (3072, 4608)
     for cond in conds:
-        # index 1 is the branch taken when the held count fits
+        # index 1 is the branch taken when the held count fits; either way
+        # the rows come back choice-major, in the compute type
         full, compact = (row_buffers(b.jaxpr, 64)
                          for b in cond.params["branches"])
-        assert compact == {"ragged_dot": [n] * 4, "gather": [n, every]}
-        assert full == {"ragged_dot": [every] * 4, "gather": [every] * 2}
+        assert compact == {"ragged_dot": [n] * 4, "rows_in": [n],
+                           "back": [(3, tokens, 64)]}
+        assert full == {"ragged_dot": [every] * 4, "rows_in": [every],
+                        "back": [(3, tokens, 64)]}
+        assert cond.outvars[0].aval.shape == (3, tokens, 64)
+        assert cond.outvars[0].aval.dtype == jnp.bfloat16
     # nothing of the layer's row traffic is left outside the condition
-    outside = [e.primitive.name for e in jaxpr.eqns]
-    assert "ragged_dot" not in outside
+    # (the one gather there is the embedding's)
+    assert row_buffers(jaxpr.replace(eqns=[
+        e for e in jaxpr.eqns if e.primitive.name != "cond"]), 64) == {
+            "ragged_dot": [], "rows_in": [], "back": [(BATCH, ROW, 64)]}
+    # the three choices are cast to float32, then summed over the leading
+    # axis, once a layer; no (tokens, 3, 64) array anywhere in the step
+    assert choice_sums(jaxpr, 3, tokens, 64) == [(jnp.float32, (0,))] * 2
 
 
-def test_with_every_expert_held_the_step_is_the_parents():
+def test_with_every_expert_held_the_step_has_one_length_and_no_condition():
     jaxpr = step_jaxpr(1).jaxpr
     assert not any(e.primitive.name in ("cond", "while")
                    for e in equations(jaxpr))
-    every = BATCH * ROW * 3
-    found = row_buffers(jaxpr, 64)
-    assert found["ragged_dot"] == [every] * 8    # two layers
-    assert found["gather"] == [every] * 4
-    # the layer alone: the full-length function's equations, each with the
-    # shapes it has there (the inverse permutation is taken a few lines
-    # earlier, nothing else differs)
+    tokens, every = BATCH * ROW, BATCH * ROW * 3
+    assert row_buffers(jaxpr, 64) == {
+        "ragged_dot": [every] * 8, "rows_in": [every] * 2,      # two layers
+        "back": [(BATCH, ROW, 64)] + [(3, tokens, 64)] * 2}     # and embed
+    assert choice_sums(jaxpr, 3, tokens, 64) == [(jnp.float32, (0,))] * 2
+    # the layer alone, at another K and width: one gather in, one back, and
+    # the sum's operand is float32 though everything before it is bfloat16
     args = layer_inputs(0, jnp.bfloat16, held=WIDE)
-    today = jax.make_jaxpr(lambda *a: moe.held_experts(*a, 0, args[6], WIDE)
-                           )(*args[:5])
-    parent = jax.make_jaxpr(lambda *a: full_length(*a, 0, args[6])
-                            )(*args[:5])
-    assert shaped_equations(today.jaxpr) == shaped_equations(parent.jaxpr)
+    alone = jax.make_jaxpr(lambda *a: moe.held_experts(*a, 0, args[6], WIDE)
+                           )(*args[:5]).jaxpr
+    assert row_buffers(alone, D) == {"ragged_dot": [T * K] * 4,
+                                     "rows_in": [T * K], "back": [(K, T, D)]}
+    assert choice_sums(alone, K, T, D) == [(jnp.float32, (0,))]
+    back, = (e for e in equations(alone) if e.primitive.name == "gather"
+             and e.outvars[0].aval.shape == (K, T, D))
+    assert back.outvars[0].aval.dtype == jnp.bfloat16
+
+
+# -- against a plain loop over tokens and choices ---------------------------------------
+
+LOOP_T, LOOP_WIDE, LOOP_FIRST, LOOP_HELD = 512, 16, 6, 4
+NOBODYS = LOOP_FIRST + 1        # a held expert that no token chooses
+UNHELD_ONLY, PADDING = 40, 24   # tokens at the front / at the back
+#: ``|got - want|`` over the norm of a token's terms, the worst token. The
+#: products hand over in the compute type, so in bfloat16 the layer's own
+#: roundings (2**-9 each) are what is held, and in float32 (2**-24) the
+#: sum over the choices too
+TOLERANCE = {jnp.float32: 4e-6, jnp.bfloat16: 3e-2}
+
+
+def loop_inputs(seed, k, dtype):
+    rng = np.random.default_rng(seed)
+    open_to_all = np.delete(np.arange(LOOP_WIDE), NOBODYS)
+    unheld = np.setdiff1d(np.arange(LOOP_WIDE),
+                          np.arange(LOOP_FIRST, LOOP_FIRST + LOOP_HELD))
+    experts = np.stack(
+        [rng.permutation(unheld if i < UNHELD_ONLY else open_to_all)[:k]
+         for i in range(LOOP_T)])
+    gates = jax.nn.softmax(jnp.asarray(
+        rng.standard_normal((LOOP_T, k)), jnp.float32), axis=-1)
+    w_in = rng.standard_normal((LOOP_HELD, D, 2 * INNER)) / np.sqrt(D)
+    w_out = rng.standard_normal((LOOP_HELD, INNER, D)) / np.sqrt(INNER)
+    valid = np.arange(LOOP_T) < LOOP_T - PADDING
+    return (jnp.asarray(rng.standard_normal((LOOP_T, D)), dtype), gates,
+            jnp.asarray(experts, jnp.int32), jnp.asarray(w_in, dtype),
+            jnp.asarray(w_out, dtype), LOOP_FIRST, jnp.asarray(valid))
+
+
+def plain_loop(u, gates, experts, w_in, w_out, first, valid,
+               accumulate=np.float64):
+    """``(sum over a token's held choices of gate * expert(u), the norm of
+    those terms)``, token by token in float64 on the values the layer is
+    handed; the running sum is kept in ``accumulate``."""
+    u, gates, w_in, w_out = (np.asarray(a, np.float64)
+                             for a in (u, gates, w_in, w_out))
+    out = np.zeros(u.shape, np.float64)
+    scale = np.zeros(len(u))
+    for i in np.flatnonzero(np.asarray(valid)):
+        total, terms = np.zeros(u.shape[1], accumulate), []
+        for gate, expert in zip(gates[i], np.asarray(experts)[i] - first):
+            if 0 <= expert < len(w_in):
+                a, b = np.split(u[i] @ w_in[expert], 2)
+                terms.append(gate * ((a / (1 + np.exp(-a)) * b)
+                                     @ w_out[expert]))
+                total = (total + terms[-1].astype(accumulate)
+                         ).astype(accumulate)
+        out[i] = total
+        scale[i] = np.linalg.norm(terms) if terms else 0.0
+    return out, scale
+
+
+def worst_token(got, want, scale):
+    """The largest distance of a token's row from ``want`` in units of the
+    norm of its terms; a token with no term has to be exactly zero."""
+    got = np.asarray(got, np.float64)
+    assert not got[scale == 0].any()
+    far = np.linalg.norm(got - want, axis=1)[scale > 0] / scale[scale > 0]
+    return far.max()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("k", [3, 6, 10])
+def test_held_experts_is_the_plain_loop_over_tokens_and_choices(k, dtype):
+    args = loop_inputs(100 + k, k, dtype)
+    experts, valid = np.asarray(args[2]), np.asarray(args[6])
+    local = experts - LOOP_FIRST
+    here = (local >= 0) & (local < LOOP_HELD) & valid[:, None]
+    # the case is what the docstring says: an expert nobody chose, tokens
+    # none of whose choices is held, padding rows that chose held experts
+    assert not (experts == NOBODYS).any()
+    assert (~here.any(axis=1) & valid).sum() >= UNHELD_ONLY
+    assert ((local >= 0) & (local < LOOP_HELD))[~valid].any()
+    want, scale = plain_loop(*args)
+    assert (scale > 0).sum() > LOOP_T // 3 and not scale[~valid].any()
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(moe.held_experts, static_argnums=(5, 7))(
+            *args, LOOP_WIDE)
+    assert got.dtype == jnp.float32
+    assert worst_token(got, want, scale) < TOLERANCE[dtype]
+
+
+def test_a_bfloat16_sum_over_the_choices_is_noticed():
+    """The same loop with its running sum in bfloat16 is further from the
+    layer, in float32, than the tolerance allows: the comparison above
+    holds the sum over the K choices to float32."""
+    args = loop_inputs(110, 10, jnp.float32)
+    want, scale = plain_loop(*args)
+    rounded, _ = plain_loop(*args, accumulate=jnp.bfloat16)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(moe.held_experts, static_argnums=(5, 7))(
+            *args, LOOP_WIDE)
+    assert worst_token(got, want, scale) < TOLERANCE[jnp.float32]
+    assert worst_token(got, rounded, scale) > 100 * TOLERANCE[jnp.float32]
 
 
 # -- the gate's rule ------------------------------------------------------------------

@@ -23,6 +23,13 @@ buffer with room for every assignment (``moe/all``) and give the same bits.
 A layer that holds every expert has the one length and no condition
 (deepseek_v2 on one chip a layer).
 
+The return trip is choice-major. Every assignment's output is gathered back
+as (K, T, D), choice first, and the K float32 terms of a token are summed
+over that leading axis. A (T, K, D) array would put the K choices in the
+sublane position of a tile, where 10 (or 6) of them pad to the 16 rows of a
+bfloat16 tile and every pass over the array moves the pad too; a leading K
+pads nothing, whatever K is.
+
 An expert is a gated unit: ``(silu(u W[:, :I]) * (u W[:, I:])) V``.
 """
 from __future__ import annotations
@@ -114,7 +121,7 @@ def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
     place = jnp.argsort(order)      # the permutation's inverse, by a sort
 
     def through(length: int) -> jnp.ndarray:
-        """Every assignment's expert output, (T * K, D) in (token, choice)
+        """Every assignment's expert output, (K, T, D) in (choice, token)
         order, by way of the first ``length`` sorted assignments, which
         have to hold every held one."""
         rows = u[order[:length] // k]                           # (length, D)
@@ -127,8 +134,8 @@ def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
                                  preferred_element_type=u.dtype)
         # an assignment that is not held has its place behind the held ones:
         # clamped into the buffer, to a row the caller masks
-        return out[place if length == t * k
-                   else jnp.minimum(place, length - 1)]
+        at = place if length == t * k else jnp.minimum(place, length - 1)
+        return out[at.reshape(t, k).T]
 
     def under(name: str, length: int):
         def branch():
@@ -143,6 +150,6 @@ def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
         sizes.sum() <= n, under("held", n), under("all", t * k))
     # rows past the last group hold nothing that was computed and are
     # masked, not trusted to be zero
-    picked = jnp.where(here[..., None], back.reshape(t, k, -1), 0)
-    weight = jnp.where(here, gates, 0.0)
-    return jnp.sum(weight[..., None] * picked.astype(jnp.float32), axis=1)
+    picked = jnp.where(here.T[..., None], back, 0)
+    weight = jnp.where(here, gates, 0.0).T
+    return jnp.sum(weight[..., None] * picked.astype(jnp.float32), axis=0)

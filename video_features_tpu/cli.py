@@ -194,6 +194,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     else:
         multi = None
         extractor = get_extractor_cls(args.feature_type)(args)
+    from .telemetry import startup
+    startup.mark("ready")
     run_label = ",".join(families)
 
     video_paths = form_list_from_user_input(

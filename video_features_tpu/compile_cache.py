@@ -447,10 +447,12 @@ def _attach_entry(root: str, family: str, config_fp: str
         if _active is not None:
             return _active
         _active = entry
-    os.makedirs(entry.dir, exist_ok=True)
-    entry.verify()
-    entry.warm_at_attach = entry.is_warm()
-    entry.activate()
+    from .telemetry import startup
+    with startup.phase("cache_attach", family=family, entry=entry.key[:12]):
+        os.makedirs(entry.dir, exist_ok=True)
+        entry.verify()
+        entry.warm_at_attach = entry.is_warm()
+        entry.activate()
     return entry
 
 

@@ -12,6 +12,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..config import Config
+from ..telemetry import startup as _startup
 from ..utils import sinks
 
 
@@ -27,15 +28,21 @@ class BaseExtractor:
         self.device = args.get("device", "auto")
         self.precision = args.get("precision", "float32")
         import jax
+        # before the first program: every compile or load of this process
+        # is on the start-up ledger, for a library caller too
+        _startup.install()
         if self.device == "cpu":
             # device=cpu must not claim a chip on a TPU host
             jax.config.update("jax_platforms", "cpu")
-        elif self.device == "tpu" and jax.default_backend() != "tpu":
+        # the first touch of the backend (the TPU runtime starts here)
+        with _startup.phase("backend", device=self.device):
+            backend = jax.default_backend()
+        if self.device == "tpu" and backend != "tpu":
             # when libtpu fails to start JAX falls back to the CPU with a
             # warning; a run that asked for the chip must not carry on there
             raise RuntimeError(
                 f"device=tpu, but JAX's default backend is "
-                f"{jax.default_backend()!r} (devices: {jax.devices()}; "
+                f"{backend!r} (devices: {jax.devices()}; "
                 f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}). Is "
                 "another process holding the chip? Pass device=cpu to run "
                 "on the CPU on purpose.")

@@ -15,6 +15,7 @@ from typing import Any, Callable, Dict, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import startup
 from .common import scope
 
 #: matrices are normal(0, INIT_STD): no checkpoint exists in a sealed machine
@@ -57,9 +58,11 @@ def init_params(draw: Callable[[str, Any], Dict[str, Any]],
     def rounded(kind, key):
         return serving_tree(draw(kind, key), dtype)
 
-    return {**rounded("outer", part_key(seed, len(kinds))),
-            "layers": [rounded(kind, part_key(seed, i))
-                       for i, kind in enumerate(kinds)]}
+    # the host side of it: the draws run on the device after this returns
+    with startup.phase("params", kinds=",".join(sorted(set(kinds)))):
+        return {**rounded("outer", part_key(seed, len(kinds))),
+                "layers": [rounded(kind, part_key(seed, i))
+                           for i, kind in enumerate(kinds)]}
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:

@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # roofline=true (telemetry/roofline.py): per-program cost-card capture at
 # the dispatch boundary — one module-global read per dispatch when off
 from ..telemetry.roofline import observe_dispatch as _roofline_observe
+from ..telemetry import startup as _startup
 from ..telemetry import trace as _trace
 
 
@@ -228,7 +229,9 @@ class DataParallelApply:
             param_shardings = jax.tree_util.tree_map(
                 lambda s: NamedSharding(self.mesh, s), param_specs,
                 is_leaf=lambda x: isinstance(x, P))
-        self.params = jax.device_put(params, param_shardings)
+        # the host side of an asynchronous copy: nothing waits for it here
+        with _startup.phase("place"):
+            self.params = jax.device_put(params, param_shardings)
         self._batch_sharding = batch_sharding
         #: the jitted step's stable name: a profiler trace's "XLA Modules"
         #: line says ``jit_<program>``, the host timeline's ``mesh.enqueue``
@@ -248,6 +251,9 @@ class DataParallelApply:
         #: made by the calling thread is ``last_seq``
         self._seq = itertools.count()
         self._local = threading.local()
+        #: padded shapes that have entered ``_enqueue``: the first of each
+        #: traces, lowers and compiles or loads the step
+        self._dispatched: set = set()
 
     @property
     def n_devices(self) -> int:
@@ -265,7 +271,15 @@ class DataParallelApply:
         return seq
 
     def _enqueue(self, padded, rows: int, seq: int):
-        """The one call into the jitted step, under ``mesh.enqueue``."""
+        """The one call into the jitted step, under ``mesh.enqueue``; the
+        first of each padded shape under the start-up ledger's
+        ``first_dispatch`` too (two threads that meet on a new shape both
+        record it: both waited)."""
+        if padded.shape not in self._dispatched:
+            self._dispatched.add(padded.shape)
+            with _startup.phase("first_dispatch", program=self.program,
+                                padded_rows=int(padded.shape[0])):
+                return self._enqueue(padded, rows, seq)
         with _trace.span("mesh.enqueue", seq=seq, rows=rows,
                          padded_rows=int(padded.shape[0]),
                          program=self.program):

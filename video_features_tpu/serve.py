@@ -342,6 +342,8 @@ class ServeLoop:
                             else None)
         self.out_root = str(out_root if out_root is not None
                             else args.output_path)
+        from .telemetry import startup
+        startup.mark("ready")
 
         # telemetry recorder is NOT optional in serve mode: its heartbeat
         # in the SPOOL dir is the liveness/readiness protocol (clients
@@ -868,8 +870,10 @@ class ServeLoop:
     def run(self) -> int:
         self.recorder.start()
         self._set_state("ready")
+        from .telemetry import startup
         print(f"vft-serve: ready — spool={self.spool_dir} "
               f"families={','.join(self.families)} workers={self.workers} "
+              f"{startup.ready_line()} "
               f"(heartbeat {self.recorder.heartbeat_path})")
         from concurrent.futures import ThreadPoolExecutor
         served = 0

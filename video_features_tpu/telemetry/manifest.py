@@ -18,6 +18,8 @@ import sys
 import time
 from typing import Any, Dict, Optional
 
+from . import startup
+
 MANIFEST_SCHEMA_VERSION = "vft.run_manifest/1"
 MANIFEST_FILENAME = "_run.json"
 
@@ -104,6 +106,9 @@ def build_manifest(*,
         "failure_tallies": dict(failure_tallies or {}),
         "stage_totals": dict(stage_totals or {}),
         "compile_cache": dict(compile_cache or {}),
+        # the process's own account of its start (telemetry/startup.py):
+        # seconds by phase, programs compiled or loaded, the cache's share
+        "startup": startup.summary(),
         # output-health roll-up (telemetry/health.py): per-family digest
         # record + NaN/Inf totals; {} when health=false (nothing observed)
         "health": dict(health or {}),

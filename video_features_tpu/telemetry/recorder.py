@@ -32,7 +32,7 @@ import uuid
 from typing import Callable, Dict, List, Optional
 
 from ..utils.profiling import StageProfiler, profiler
-from . import jsonl, manifest
+from . import jsonl, manifest, startup
 from .heartbeat import HeartbeatThread, heartbeat_filename
 from .metrics import FPS_BUCKETS, LATENCY_BUCKETS, MetricsRegistry
 from .spans import VideoSpan, current_span
@@ -41,44 +41,11 @@ SPANS_FILENAME = "_telemetry.jsonl"
 
 # -- process-wide compile-cache event counts --------------------------------
 # jax.monitoring listeners cannot be unregistered individually, so they are
-# installed once and recorders read deltas against a start-of-run baseline.
+# installed once (telemetry/startup.py, which also keeps what they say of
+# every program) and recorders read deltas against a start-of-run baseline.
 
-_mon_lock = threading.Lock()
-_mon_counts: Dict[str, int] = {}
-_mon_installed = False
-
-
-def _bump_mon(event: str) -> None:
-    with _mon_lock:
-        _mon_counts[event] = _mon_counts.get(event, 0) + 1
-
-
-def _install_monitoring() -> None:
-    global _mon_installed
-    with _mon_lock:
-        if _mon_installed:
-            return
-        _mon_installed = True
-    try:
-        from jax import monitoring
-
-        def on_event(event: str, **kw) -> None:
-            if "compilation_cache" in event:
-                _bump_mon(event)
-
-        def on_duration(event: str, duration: float, **kw) -> None:
-            if "compilation_cache" in event:
-                _bump_mon(event)
-
-        monitoring.register_event_listener(on_event)
-        monitoring.register_event_duration_secs_listener(on_duration)
-    except Exception:
-        pass  # telemetry degrades, extraction does not
-
-
-def _mon_snapshot() -> Dict[str, int]:
-    with _mon_lock:
-        return dict(_mon_counts)
+_install_monitoring = startup.install
+_mon_snapshot = startup.cache_event_counts
 
 
 def compile_cache_baseline() -> Dict[str, int]:

@@ -155,6 +155,13 @@ KNOWN_SPAN_NAMES = (
     "mesh.pad",             # pad to the wire bucket (args: seq, rows)
     "mesh.enqueue",         # the jitted call (seq, rows, padded_rows, program)
     "mesh.fetch",           # the blocking D2H of dispatch `seq` (in forward)
+    # -- the start-up ledger's phases (PR 36, telemetry/startup.py), on the
+    # timeline where a recorder already runs; `startup.ready` is an instant
+    "startup.backend",      # the first touch of the backend (args: device)
+    "startup.cache_attach",  # compile_cache.py: verify + activate an entry
+    "startup.params",       # resolve_params / init_params (model_key, kinds)
+    "startup.place",        # host side of the parameters' device_put
+    "startup.first_dispatch",  # first enqueue of a padded shape (program)
 )
 
 #: the counter tracks the hot path emits (``trace.counter``)

@@ -391,7 +391,19 @@ def resolve_params(model_key: str,
 
     ``init_fn``: builds a randomly-initialized tree (also the msgpack
     template). ``convert_fn``: maps a torch state_dict onto that tree.
+    The whole of it is the start-up ledger's ``params`` phase
+    (telemetry/startup.py).
     """
+    from ..telemetry import startup
+    with startup.phase("params", model_key=model_key):
+        return _resolve_params(model_key, init_fn, convert_fn, weights_path,
+                               allow_random, cache_converted)
+
+
+def _resolve_params(model_key: str, init_fn: Callable[[], Any],
+                    convert_fn: Callable[[Dict[str, Any]], Any],
+                    weights_path: Optional[str], allow_random: bool,
+                    cache_converted: bool) -> Any:
     ckpt = find_checkpoint(model_key, weights_path)
     if ckpt is None:
         if allow_random:

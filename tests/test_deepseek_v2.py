@@ -378,6 +378,26 @@ def test_a_document_states_mla_once_and_counts_its_assignments(
     assert blocks[:4] == [{"kept": 1}, {"total": 1}] * 2
 
 
+def test_the_first_item_states_how_its_grouped_products_run(extractor,
+                                                            tmp_path):
+    """One ``moe`` event on the first item's span (the token families'
+    common half): on the CPU the products are ``ragged_dot`` and the
+    kernel's gate says why."""
+    from video_features_tpu.telemetry.spans import VideoSpan
+    (doc,) = documents(8, (50,))
+    path = token_file(tmp_path / "doc.tokens", doc)
+    extractor._moe_stated = False
+    with VideoSpan(path) as span:
+        extractor.extract(path)
+        extractor.extract(path)
+    (stated,) = [e for e in span.record["events"] if e["kind"] == "moe"]
+    assert {k: stated[k] for k in ("products", "rows", "experts", "widths",
+                                   "tiles")} == {
+        "products": "ragged_dot", "rows": 2 * ROW * 3, "experts": 8,
+        "widths": [[64, 64], [32, 64]], "tiles": None}
+    assert "cpu" in stated["fallback"]
+
+
 def test_serve_loop_turns_token_files_into_feature_files(tmp_path):
     """The normal path: ``vft-serve`` over a spool of requests whose items
     are token files, two workers packing into shared rows."""

@@ -1,4 +1,4 @@
-"""The fused lookup kernel compiled for a described v5e, no chip attached.
+"""The program's own kernels compiled for a described v5e, no chip attached.
 
 Interpret mode (tests/test_kernels.py) checks values; it cannot see what the
 chip's compiler refuses: a slice off the tiling, a block or a spill area
@@ -54,3 +54,31 @@ def test_proj_kernel_compiles_for_a_v5e(one_chip, h8, w8, pairs):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert f"f32[1,{q},256]" in text
+
+
+#: (length, K, N, experts, tiles): both products of DeepSeek-V2-Lite's cell
+#: (every expert held: one length) and of granite's (the compact buffer and
+#: the buffer with room for every assignment), K whole at all of them; and a
+#: K so wide that it is tiled, with the accumulator in VMEM
+@pytest.mark.parametrize("length, k, n, experts, tiles", [
+    (98304, 2048, 2816, 64, (256, 2048, 1408)),
+    (98304, 1408, 2048, 64, (256, 1408, 2048)),
+    (102400, 4096, 1536, 36, (256, 4096, 512)),
+    (102400, 768, 4096, 36, (256, 768, 2048)),
+    (163840, 4096, 1536, 36, (256, 4096, 512)),
+    (163840, 768, 4096, 36, (256, 768, 2048)),
+    (4096, 8192, 4096, 8, (256, 4096, 512))])
+def test_grouped_matmul_compiles_for_a_v5e(one_chip, length, k, n, experts,
+                                           tiles):
+    from video_features_tpu.kernels import grouped_matmul as gm
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert gm.tiles_for(k, n, 2) == tiles
+    compiled = gm.grouped_matmul.lower(
+        spec((length, k)), spec((experts, k, n)),
+        spec((experts,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"bf16[{length},{n}]" in text

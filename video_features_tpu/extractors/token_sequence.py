@@ -33,10 +33,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..config import Config
+from ..kernels import grouped_matmul
+from ..ops import moe
 from ..parallel.mesh import DataParallelApply
 from ..parallel.packer import SegmentPacker
-from ..telemetry import trace
+from ..telemetry import startup, trace
 from ..utils.profiling import profiler
 from .base import BaseExtractor
 
@@ -82,6 +85,8 @@ class TokenSequenceExtractor(BaseExtractor):
     #: ``stack_size: null`` resolves to this many tokens
     default_stack_size = 4096
 
+    _moe_stated = False
+
     def __init__(self, args: Config) -> None:
         super().__init__(args)
         self.model_name = args.get("model_name")
@@ -105,6 +110,18 @@ class TokenSequenceExtractor(BaseExtractor):
         # drawn where they will live: a second copy would not fit
         params = self.model.init_params(self.arch, WEIGHTS_SEED, self.dtype,
                                         sharding=NamedSharding(mesh, P()))
+        # how the routed layers' grouped products will run, from the gate
+        # the step itself asks as it is traced (``ops/moe.py``)
+        routed = next(w for w in params["layers"] if "experts_in" in w)
+        self._moe_products = moe.stated_products(
+            self.batch_size * self.stack_size, self.arch.num_experts_per_tok,
+            self.arch.counter_shape[-1], self.dtype, routed["experts_in"],
+            routed["experts_out"])
+        if self._moe_products["products"] == "pallas":
+            # Pallas's import is a second of this start: named here, and
+            # not spent inside the step's first trace
+            with startup.phase("kernels"):
+                grouped_matmul.ready()
         self.runner = DataParallelApply(
             partial(type(self).device_forward, self.arch, self.max_segments,
                     self.dtype),
@@ -114,6 +131,12 @@ class TokenSequenceExtractor(BaseExtractor):
                                      max_segments=self.max_segments)
 
     def extract(self, video_path: str) -> Dict[str, np.ndarray]:
+        if not self._moe_stated:
+            # feature values do not say which form the routed experts'
+            # grouped products ran in: a ``moe`` event on the first item's
+            # span does (telemetry=true)
+            self._moe_stated = True
+            telemetry.event("moe", **self._moe_products)
         with profiler.stage("decode"), \
                 trace.span("decode.read", item=str(video_path)):
             ids = read_tokens(video_path, self.arch.vocab_held,

@@ -8,9 +8,11 @@ selected experts only those in ``[first, first + held)`` live here (expert
 parallelism: the others are on the chips that share the layer), and the
 layer returns ``sum_e gate_e * expert_e(u)`` over the selected experts that
 are held: what the absent ones would add is left out, by the plain reference
-too. Each expert's rows go through one grouped matrix product
-(``jax.lax.ragged_dot``), never a dense product over all experts masked
-afterwards.
+too. Each expert's rows go through one grouped matrix product, never a dense
+product over all experts masked afterwards: on a TPU, at widths of whole
+lanes, the Pallas kernel of ``kernels/grouped_matmul.py``, elsewhere
+``jax.lax.ragged_dot`` (:func:`pallas_products` asks the kernel's own gate,
+once a layer as the step is traced; nothing else selects one).
 
 No capacity and no dropped token. The assignments are sorted by expert, the
 held ones first, into a buffer whose length follows the share of the experts
@@ -35,10 +37,13 @@ An expert is a gated unit: ``(silu(u W[:, :I]) * (u W[:, I:])) V``.
 from __future__ import annotations
 
 import math
-from typing import Tuple
+from functools import partial
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels import grouped_matmul as kernel     # imports no Pallas
 
 
 def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
@@ -100,6 +105,38 @@ def held_rows(assignments: int, held: int, wide: int) -> int:
     return min(assignments, -(-room // 1024) * 1024)
 
 
+def pallas_products(length: int, dtype, w_in, w_out) -> Optional[str]:
+    """Why the two products of ``length`` rows of ``dtype`` through experts
+    ``w_in`` (E, D, 2I) and ``w_out`` (E, I, D) do not both run in the
+    Pallas kernel, or ``None`` where they do
+    (``kernel.grouped_matmul_refusal`` of each: the backend and the
+    shapes)."""
+    for w in (w_in, w_out):
+        refusal = kernel.grouped_matmul_refusal(
+            jax.ShapeDtypeStruct((length, w.shape[1]), dtype), w)
+        if refusal is not None:
+            return refusal
+    return None
+
+
+def stated_products(tokens: int, top_k: int, wide: int, dtype, w_in, w_out
+                    ) -> dict:
+    """What a ``moe`` event says of the layer :func:`held_experts` runs for
+    ``tokens`` tokens of ``dtype`` a dispatch (feature values do not say
+    which products ran): the answer of the gate the step itself asks, at
+    the compact buffer's length."""
+    held, d, two_i = w_in.shape
+    rows = held_rows(tokens * top_k, held, wide)
+    fallback = pallas_products(rows, dtype, w_in, w_out)
+    widths = [[d, two_i], [two_i // 2, d]]
+    return dict(
+        products="pallas" if fallback is None else "ragged_dot", rows=rows,
+        experts=held, widths=widths, fallback=fallback,
+        tiles=None if fallback is not None else [
+            list(kernel.tiles_for(k, n, jnp.dtype(dtype).itemsize))
+            for k, n in widths])
+
+
 def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
                  w_in: jnp.ndarray, w_out: jnp.ndarray, first: int,
                  valid: jnp.ndarray, wide: int) -> jnp.ndarray:
@@ -126,12 +163,14 @@ def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
         have to hold every held one."""
         rows = u[order[:length] // k]                           # (length, D)
         # the products accumulate in float32 and hand over in the compute
-        # type
-        hidden = jax.lax.ragged_dot(rows, w_in, sizes,
-                                    preferred_element_type=u.dtype)
+        # type, in either form
+        product = kernel.grouped_matmul \
+            if pallas_products(length, u.dtype, w_in, w_out) is None \
+            else partial(
+                jax.lax.ragged_dot, preferred_element_type=u.dtype)
+        hidden = product(rows, w_in, sizes)
         gate, up = jnp.split(hidden, 2, axis=-1)
-        out = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_out, sizes,
-                                 preferred_element_type=u.dtype)
+        out = product(jax.nn.silu(gate) * up, w_out, sizes)
         # an assignment that is not held has its place behind the held ones:
         # clamped into the buffer, to a row the caller masks
         at = place if length == t * k else jnp.minimum(place, length - 1)

@@ -284,7 +284,8 @@ def test_the_two_expert_shares_add_up_to_the_uncut_layer(arch):
             assert np.array_equal(chosen, want_chosen)
             total = total + (out - shared)
             # and the program's share is the reference's share
-            gates, picks = ds.moe.route(u, w["router"], 3, over_all=True,
+            gates, picks = ds.moe.route(u, w["router"], 3,
+                                        rule="softmax_topk",
                                         renormalise=False)
             routed = ds.moe.held_experts(
                 u, gates, picks, w["experts_in"], w["experts_out"],

@@ -57,12 +57,15 @@ def test_proj_kernel_compiles_for_a_v5e(one_chip, h8, w8, pairs):
 
 
 #: (length, K, N, experts, tiles): both products of DeepSeek-V2-Lite's cell
-#: (every expert held: one length) and of granite's (the compact buffer and
-#: the buffer with room for every assignment), K whole at all of them; and a
-#: K so wide that it is tiled, with the accumulator in VMEM
+#: and of LFM2-8B-A1B's (every expert held: one length) and of granite's
+#: (the compact buffer and the buffer with room for every assignment), K
+#: whole at all of them; and a K so wide that it is tiled, with the
+#: accumulator in VMEM
 @pytest.mark.parametrize("length, k, n, experts, tiles", [
     (98304, 2048, 2816, 64, (256, 2048, 1408)),
     (98304, 1408, 2048, 64, (256, 1408, 2048)),
+    (65536, 2048, 3584, 32, (256, 2048, 896)),
+    (65536, 1792, 2048, 32, (256, 1792, 1024)),
     (102400, 4096, 1536, 36, (256, 4096, 512)),
     (102400, 768, 4096, 36, (256, 768, 2048)),
     (163840, 4096, 1536, 36, (256, 4096, 512)),

@@ -314,11 +314,20 @@ def test_a_bfloat16_sum_over_the_choices_is_noticed():
 
 # -- the gate's rule ------------------------------------------------------------------
 
-def plain_gate(logits, k, over_all, renormalise, scaling):
-    """The two published rules in numpy, token by token."""
+#: lfm2_moe's rule: a per-expert bias that moves the choice alone
+SELECTION_BIAS = 0.05 * np.random.default_rng(12).standard_normal(WIDE)
+
+
+def plain_gate(logits, k, rule, renormalise, scaling, selection_bias=None):
+    """The three published rules in numpy, token by token."""
     gates, experts = [], []
     for row in np.asarray(logits, np.float64):
-        if over_all:
+        if rule == "sigmoid":
+            s = 1.0 / (1.0 + np.exp(-row))
+            bias = 0.0 if selection_bias is None else selection_bias
+            top = np.argsort(-(s + bias), kind="stable")[:k]
+            g = s[top] / (s[top].sum() + 1e-6) if renormalise else s[top]
+        elif rule == "softmax_topk":
             p = np.exp(row - row.max())
             p /= p.sum()
             top = np.argsort(-p, kind="stable")[:k]
@@ -334,11 +343,15 @@ def plain_gate(logits, k, over_all, renormalise, scaling):
 
 @pytest.mark.parametrize("rule", [
     dict(),                                                 # granite's
-    dict(over_all=True, renormalise=False),                 # deepseek_v2's
-    dict(over_all=True, renormalise=True),
-    dict(over_all=True, renormalise=False, scaling=2.5),
+    dict(rule="softmax_topk", renormalise=False),           # deepseek_v2's
+    dict(rule="softmax_topk", renormalise=True),
+    dict(rule="softmax_topk", renormalise=False, scaling=2.5),
+    dict(rule="sigmoid", selection_bias=SELECTION_BIAS),    # lfm2_moe's
+    dict(rule="sigmoid", selection_bias=SELECTION_BIAS, scaling=2.5),
+    dict(rule="sigmoid"),
 ], ids=["top_k_then_softmax", "softmax_then_top_k", "renormalised",
-        "scaled"])
+        "scaled", "sigmoid_biased", "sigmoid_biased_scaled",
+        "sigmoid_unbiased"])
 def test_route_under_each_rule_is_the_plain_computation(rule):
     rng = np.random.default_rng(11)
     u = jnp.asarray(rng.standard_normal((64, D)), jnp.float32)
@@ -346,18 +359,32 @@ def test_route_under_each_rule_is_the_plain_computation(rule):
     with jax.default_matmul_precision("highest"):
         gates, experts = moe.route(u, router, K, **rule)
         logits = jnp.dot(u, router)
+    named = rule.get("rule", "topk_softmax")
     want_gates, want_experts = plain_gate(
-        logits, K, rule.get("over_all", False),
-        rule.get("renormalise", True), rule.get("scaling", 1.0))
+        logits, K, named, rule.get("renormalise", True),
+        rule.get("scaling", 1.0), rule.get("selection_bias"))
     assert np.array_equal(np.asarray(experts), want_experts)
     np.testing.assert_allclose(np.asarray(gates), want_gates, rtol=1e-5)
+    if rule.get("selection_bias") is not None:     # the bias moved a choice
+        _, unbiased = plain_gate(logits, K, "sigmoid", True, 1.0)
+        assert (np.sort(unbiased, 1) != np.sort(want_experts, 1)).any()
     total = np.asarray(gates).sum(axis=1)
-    if not rule.get("over_all") or rule.get("renormalise"):
+    if named != "softmax_topk" or rule.get("renormalise"):
         np.testing.assert_allclose(total, rule.get("scaling", 1.0),
                                    rtol=1e-5)
     else:       # the chosen probabilities of a softmax over all: under 1
         assert (total <= rule.get("scaling", 1.0) * (1 + 1e-6)).all()
         assert total.min() < 0.99 * rule.get("scaling", 1.0)
+
+
+@pytest.mark.parametrize("rule", ["topk_softmax", "softmax_topk"])
+def test_a_selection_bias_belongs_to_the_sigmoid_rule_alone(rule):
+    u, router = jnp.ones((8, D)), jnp.ones((D, WIDE))
+    with pytest.raises(ValueError, match="selection bias"):
+        moe.route(u, router, K, rule=rule,
+                  selection_bias=jnp.asarray(SELECTION_BIAS))
+    with pytest.raises(ValueError, match="none of"):
+        moe.route(u, router, K, rule="softmax")
 
 
 def test_granites_rule_is_the_default_and_its_program_is_the_parents():

@@ -1,5 +1,5 @@
 """What the token-sequence extractors share (``granite_hybrid``,
-``deepseek_v2``).
+``deepseek_v2``, ``lfm2_moe``).
 
 The item is a file of token ids (``.tokens``: raw little-endian int32, what
 a tokenizer run over a caption or a transcript leaves), not a video. A
@@ -165,6 +165,12 @@ class TokenSequenceExtractor(BaseExtractor):
                 .sum(axis=1).max()), series="held")
             trace.counter("moe.assignments", int(a_layer[0].sum()),
                           series="all")
+            # how uneven each routed layer's load is: its fullest expert
+            # over the mean of the router's width
+            for i, load in enumerate(a_layer):
+                trace.counter("moe.fullest_over_mean",
+                              float(load.max() / load.mean()),
+                              series=f"layer{i}")
         if self.show_pred:
             self.maybe_show_pred(ids, windows)
         return {self.feature_type: np.ascontiguousarray(

@@ -370,7 +370,7 @@ def token_states(arch: Arch, params: Mapping[str, Any], rows: jnp.ndarray,
                          ).reshape(bsz * t, -1)
             gates, picks = moe.route(
                 u, w["router"], arch.num_experts_per_tok, router_dtype,
-                over_all=True, renormalise=arch.norm_topk_prob,
+                rule="softmax_topk", renormalise=arch.norm_topk_prob,
                 scaling=arch.routed_scaling_factor)
             routed = moe.held_experts(u, gates, picks, w["experts_in"],
                                       w["experts_out"], arch.first_expert,

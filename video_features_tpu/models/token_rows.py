@@ -1,7 +1,7 @@
 """What the models over packed rows of tokens share (``granite_hybrid``,
-``deepseek_v2``): how their seeded weights are keyed and rounded, the
-RMSNorm, and the step's last stage, which turns per-token states into one
-line per segment.
+``deepseek_v2``, ``lfm2_moe``): how their seeded weights are keyed and
+rounded, the RMSNorm, and the step's last stage, which turns per-token
+states into one line per segment.
 
 A row is ``(2, T) int32``: token ids and segment ids (``parallel/packer.py
 SegmentPacker``; 0 is padding, a document's window is one segment, each a

@@ -2,7 +2,8 @@
 
 The router keeps its published width: every token's logits over ALL experts,
 the ``top_k`` largest, gates = softmax over those ``top_k`` logits (or, by
-:func:`route`'s static rule, the ``top_k`` largest of the softmax over all).
+:func:`route`'s static rule, the ``top_k`` largest of the softmax over all,
+or of a sigmoid over all with a bias that moves the choice alone).
 Of the
 selected experts only those in ``[first, first + held)`` live here (expert
 parallelism: the others are on the chips that share the layer), and the
@@ -46,9 +47,14 @@ import jax.numpy as jnp
 from ..kernels import grouped_matmul as kernel     # imports no Pallas
 
 
+#: :func:`route`'s rules: granite's, deepseek_v2's, lfm2_moe's
+RULES = ("topk_softmax", "softmax_topk", "sigmoid")
+
+
 def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
-          router_dtype=jnp.float32, over_all: bool = False,
-          renormalise: bool = True, scaling: float = 1.0
+          router_dtype=jnp.float32, rule: str = "topk_softmax",
+          renormalise: bool = True, scaling: float = 1.0,
+          selection_bias: Optional[jnp.ndarray] = None
           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(gates, experts)``, each (T, top_k): the router's choice for every
     token of ``u`` (T, D) over all ``router.shape[1]`` experts. Logits and
@@ -56,17 +62,35 @@ def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
     comparison notices): a logit rounded to bfloat16 swaps near-tied
     experts.
 
-    The gate's rule is static. By default (granite's) the ``top_k`` largest
-    logits are chosen and the gates are the softmax over those. With
-    ``over_all`` (deepseek_v2's ``scoring_func`` softmax) the softmax runs
-    over every expert's logit and the ``top_k`` largest probabilities are
-    chosen; they are divided by their sum only where ``renormalise``
-    (``norm_topk_prob``), and multiplied by ``scaling``
-    (``routed_scaling_factor``; the published deepseek_v2 code leaves the
-    factor out where it renormalises, deepseek_v3's applies both, as here)."""
+    The gate's ``rule`` is static. ``"topk_softmax"`` (granite's, the
+    default): the ``top_k`` largest logits are chosen and the gates are the
+    softmax over those. ``"softmax_topk"`` (deepseek_v2's ``scoring_func``
+    softmax): the softmax runs over every expert's logit and the ``top_k``
+    largest probabilities are chosen; they are divided by their sum only
+    where ``renormalise`` (``norm_topk_prob``), and multiplied by
+    ``scaling`` (``routed_scaling_factor``; the published deepseek_v2 code
+    leaves the factor out where it renormalises, deepseek_v3's applies both,
+    as here). ``"sigmoid"`` (lfm2_moe's): every logit goes through a sigmoid
+    ``s``, the experts with the ``top_k`` largest ``s + selection_bias`` are
+    chosen (the per-expert bias, float32, moves the choice and nothing
+    else), and the gates are the chosen ``s``, divided by their sum + 1e-6
+    where ``renormalise``, times ``scaling``. ``selection_bias`` belongs to
+    the sigmoid rule alone."""
+    if rule not in RULES:
+        raise ValueError(f"route: rule {rule!r} is none of {RULES}")
+    if selection_bias is not None and rule != "sigmoid":
+        raise ValueError(f"route: a selection bias under rule {rule!r}")
     logits = jnp.dot(u, router.astype(u.dtype),
                      preferred_element_type=jnp.float32).astype(router_dtype)
-    if not over_all:
+    if rule == "sigmoid":
+        s = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, experts = jax.lax.top_k(
+            s if selection_bias is None
+            else s + selection_bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(s, experts, axis=-1)
+        if renormalise:
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-6)
+    elif rule == "topk_softmax":
         top, experts = jax.lax.top_k(logits, top_k)
         gates = jax.nn.softmax(top.astype(jnp.float32), axis=-1)
     else:

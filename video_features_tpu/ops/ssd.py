@@ -15,7 +15,8 @@ depend on the chunk length. One group of ``B``/``C`` serves every head
 (``mamba_n_groups`` 1).
 
 :func:`causal_conv1d` is the depthwise convolution in front of it, whose
-taps do not read across a segment boundary either.
+taps do not read across a segment boundary either; lfm2_moe's short
+convolution runs the same taps with no bias.
 
 Segments are contiguous runs of one id in a row (``parallel/packer.py
 SegmentPacker`` lays them out so); that is what lets "no boundary between
@@ -23,20 +24,25 @@ SegmentPacker`` lays them out so); that is what lets "no boundary between
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 
-def causal_conv1d(x: jnp.ndarray, weight: jnp.ndarray, bias: jnp.ndarray,
-                  seg: jnp.ndarray) -> jnp.ndarray:
+def causal_conv1d(x: jnp.ndarray, weight: jnp.ndarray,
+                  bias: Optional[jnp.ndarray], seg: jnp.ndarray
+                  ) -> jnp.ndarray:
     """Depthwise causal convolution along ``T``: ``x`` (B, T, C), ``weight``
-    (K, C) with tap ``j`` on ``x[t - (K - 1 - j)]``, ``bias`` (C,), ``seg``
-    (B, T). A tap whose source token lies before the row or in another
-    segment reads zero. Accumulates in float32, returns ``x.dtype``."""
+    (K, C) with tap ``j`` on ``x[t - (K - 1 - j)]``, ``bias`` (C,) or
+    ``None``, ``seg`` (B, T). A tap whose source token lies before the row or
+    in another segment reads zero. Accumulates in float32, returns
+    ``x.dtype``."""
     k = weight.shape[0]
     t = x.shape[1]
-    out = bias.astype(jnp.float32) + \
-        x.astype(jnp.float32) * weight[k - 1].astype(jnp.float32)
+    out = x.astype(jnp.float32) * weight[k - 1].astype(jnp.float32)
+    if bias is not None:
+        out = bias.astype(jnp.float32) + out
     for back in range(1, k):
         source = jnp.pad(x, ((0, 0), (back, 0), (0, 0)))[:, :t]
         source_seg = jnp.pad(seg, ((0, 0), (back, 0)),

@@ -20,6 +20,7 @@ _DISPATCH = {
     "pwc": ("pwc", "ExtractPWC"),
     "granite_hybrid": ("granite_hybrid", "ExtractGraniteHybrid"),
     "deepseek_v2": ("deepseek_v2", "ExtractDeepSeekV2"),
+    "lfm2_moe": ("lfm2_moe", "ExtractLFM2Moe"),
 }
 
 #: families that consume the AUDIO track: in a multi-family run they

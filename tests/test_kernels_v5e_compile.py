@@ -11,11 +11,16 @@ The topology is described inside a fixture and nowhere while a module is
 imported: one process at a time may load the TPU's library, and under
 several workers only the one that is given this file may load it.
 """
+import importlib
 import os
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+import yaml
 
 from video_features_tpu.kernels import corr_lookup as cl
 
@@ -85,3 +90,65 @@ def test_grouped_matmul_compiles_for_a_v5e(one_chip, length, k, n, experts,
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
     assert f"bf16[{length},{n}]" in text
+
+
+def _loop_bodies(text):
+    """The compiled HLO text of every ``while`` body of a program."""
+    bodies = []
+    for name in re.findall(r" while\(.*?body=(%[\w.\-]+)", text):
+        start = text.index("\n" + name + " ")
+        bodies.append(text[start:text.index("\n}\n", start)])
+    return bodies
+
+
+#: (family, rows, tokens): the attention layers of the three token cells at
+#: their real widths; lfm2's and granite's repeat 8 key/value heads to 32
+@pytest.mark.parametrize("family, rows, tokens", [
+    ("lfm2_moe", 1, 16384), ("granite_hybrid", 4, 4096),
+    ("deepseek_v2", 1, 16384)])
+def test_no_attention_loop_rebuilds_the_keys_or_values_for_a_v5e(
+        one_chip, family, rows, tokens):
+    """The blocks ``blockwise_attention``'s loops index are made once,
+    before them: no ``while`` body of the layer broadcasts or copies an
+    array as large as all of K (left alone the compiler sank the repeat to
+    every head, and two layout copies, into the scan over query tiles).
+    The loop scores a tile of every head's 512 queries by 512 keys."""
+    mod = importlib.import_module(f"video_features_tpu.models.{family}")
+    published = yaml.safe_load((
+        Path(__file__).resolve().parents[1] / "video_features_tpu" / "configs"
+        / f"{family}.yml").read_text())["architecture"]
+    arch = mod.arch_from_config(published, 1, 0)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # the layer, its weights, the key head's width and the rotary one (no
+    # cos and sin where 0)
+    if family == "deepseek_v2":
+        layer, hd, rope = (mod.latent_attention, arch.qk_head_dim,
+                           arch.qk_rope_head_dim)
+        drawn = lambda: mod._draw_layer(arch, "dense", jax.random.key(0))[
+            "attn"]
+    else:
+        layer, hd, rope = ((mod.attention, arch.head_dim, arch.head_dim)
+                           if family == "lfm2_moe" else
+                           (mod.attention_mixer, arch.head_dim, 0))
+        drawn = lambda: mod._attention_weights(arch, jax.random.key(0))
+    w = {k: spec(s.shape, s.dtype if s.ndim < 2 else jnp.bfloat16)
+         for k, s in jax.eval_shape(drawn).items()}
+    args = [w, spec((rows, tokens, arch.hidden_size)),
+            spec((rows, tokens), jnp.int32)]
+    if rope:
+        args += [spec((rows, tokens, rope // 2), jnp.float32)] * 2
+    text = jax.jit(lambda *a: layer(arch, *a)).lower(*args).compile().as_text()
+    heads = arch.num_attention_heads
+    all_of_k = rows * tokens * heads * hd
+    bodies = _loop_bodies(text)
+    assert any(f"f32[{heads},512,512]" in b for b in bodies)  # the scores
+    for body in bodies:
+        for line in body.splitlines():
+            shape = re.search(r"= \w+\[([\d,]*)\]\S* (broadcast|copy)\(",
+                              line)
+            if shape and shape.group(1):
+                assert np.prod([int(x) for x in shape.group(1).split(",")]
+                               ) < all_of_k, line

@@ -397,3 +397,21 @@ def test_the_first_item_states_the_router_and_every_item_its_fullest_expert(
             for i, row in enumerate(a_layer)]
     assert [e["args"] for e in events[:3]] == want
     assert all(1.0 <= v for e in want for v in e.values())
+
+
+def test_the_first_item_states_the_tile_attention_scores_a_step_at(
+        extractor, tmp_path):
+    """One ``attention`` event on the first item's span: a row of ``ROW``
+    tokens is one query tile and one key block, over every head."""
+    from video_features_tpu.telemetry.spans import VideoSpan
+    path = token_file(tmp_path / "doc.tokens", documents(9, (40,))[0])
+    extractor._moe_stated = False
+    with VideoSpan(path) as span:
+        extractor.extract(path)
+        extractor.extract(path)
+    (tile,) = [e for e in span.record["events"] if e["kind"] == "attention"]
+    heads = extractor.arch.num_attention_heads
+    assert {k: tile[k] for k in ("q_tile", "block_size", "heads",
+                                 "score_tile_mb")} == {
+        "q_tile": ROW, "block_size": ROW, "heads": heads,
+        "score_tile_mb": round(heads * ROW * ROW * 4 / 1e6, 2)}

@@ -220,6 +220,17 @@ def test_a_causal_document_of_16384_tokens_keeps_528_of_1024_blocks():
     assert int((hi - lo).sum()) == 1024
 
 
+@pytest.mark.parametrize("heads, t, tile, mb", [
+    (32, 16384, 512, 33.55),    # lfm2's row: 32 heads of 64
+    (32, 4096, 512, 33.55),     # granite's
+    (16, 16384, 512, 16.78),    # dsv2's
+    (4, 96, 96, 0.15)])         # a row shorter than a tile is one tile
+def test_the_stated_tile_is_the_one_the_loop_scores(heads, t, tile, mb):
+    from video_features_tpu.parallel.sequence import stated_tile
+    assert stated_tile(heads, t) == {"q_tile": tile, "block_size": tile,
+                                     "heads": heads, "score_tile_mb": mb}
+
+
 def _scan_over_every_block(q, k, v, block_size, scale):
     """``blockwise_attention`` as it was before it had tiles (PR 32), without
     a mask: what the unmasked call still has to be."""
@@ -264,6 +275,25 @@ def test_unmasked_it_is_the_one_scan_it_was(rng, t, bs):
     assert np.array_equal(
         np.asarray(blockwise_attention(q, k, v, block_size=bs, scale=1.0)),
         np.asarray(_scan_over_every_block(q, k, v, bs, 1.0)))
+
+
+@pytest.mark.parametrize("segments", [False, True])
+def test_the_key_and_value_blocks_are_made_before_the_loops(rng, segments):
+    """Grouped-query keys and values repeated to every head: the blocks the
+    loops index pass an optimization barrier before the scan, so nothing
+    that makes them can be sunk into it (``tests/test_kernels_v5e_compile.py``
+    holds the compiled loops to it at the token cells' widths)."""
+    from video_features_tpu.parallel.sequence import blockwise_attention
+    q = jnp.asarray(rng.normal(size=(1, 64, 4, 8)).astype(np.float32))
+    k, v = (jnp.repeat(jnp.asarray(rng.normal(size=(1, 64, 2, 8))
+                                   .astype(np.float32)), 2, axis=2)
+            for _ in range(2))
+    seg = jnp.asarray(np.repeat([1, 2], 32)[None].astype(np.int32))
+    jaxpr = jax.make_jaxpr(lambda q, k, v: blockwise_attention(
+        q, k, v, block_size=16, causal=True,
+        segment_ids=seg if segments else None))(q, k, v).jaxpr
+    kinds = [e.primitive.name for e in jaxpr.eqns]
+    assert kinds.index("optimization_barrier") < kinds.index("scan")
 
 
 @pytest.mark.parametrize("segments", [False, True])

@@ -39,6 +39,7 @@ from ..kernels import grouped_matmul
 from ..ops import moe
 from ..parallel.mesh import DataParallelApply
 from ..parallel.packer import SegmentPacker
+from ..parallel.sequence import stated_tile
 from ..telemetry import startup, trace
 from ..utils.profiling import profiler
 from .base import BaseExtractor
@@ -137,6 +138,10 @@ class TokenSequenceExtractor(BaseExtractor):
             # span does (telemetry=true)
             self._moe_stated = True
             telemetry.event("moe", **self._moe_products)
+            # nor the tile attention's masked loop scores a step at: an
+            # ``attention`` event does
+            telemetry.event("attention", **stated_tile(
+                self.arch.num_attention_heads, self.stack_size))
         with profiler.stage("decode"), \
                 trace.span("decode.read", item=str(video_path)):
             ids = read_tokens(video_path, self.arch.vocab_held,

@@ -151,6 +151,16 @@ def blocks_run(segment_ids, causal: bool = True):
     return int((hi - lo).sum()), lo.size * -(-t // min(BLOCK_SIZE, t))
 
 
+def stated_tile(heads: int, t: int) -> dict:
+    """What one step of :func:`blockwise_attention`'s masked loop scores,
+    for ``heads`` heads over rows of ``t`` tokens: ``q_tile`` queries
+    against ``block_size`` keys, and the float32 score tile they fill, in
+    MB (``heads * q_tile * block_size * 4`` bytes; an ``attention`` event)."""
+    qt, bs = min(Q_TILE, t), min(BLOCK_SIZE, t)
+    return {"q_tile": qt, "block_size": bs, "heads": heads,
+            "score_tile_mb": round(heads * qt * bs * 4 / 1e6, 2)}
+
+
 def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         block_size: int = BLOCK_SIZE, causal: bool = False,
                         scale: Optional[float] = None,
@@ -177,7 +187,9 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     so the bounds decide cost and never the result: a block outside them is
     one whose fold is the identity (``s = -inf``, ``p = 0``, ``alpha = 1``).
     A tile carries its own float32 ``(o, m, l)``; peak score memory is
-    O(H * Q_TILE * block_size). The loop is a ``while`` on the device:
+    O(H * Q_TILE * block_size) (:func:`stated_tile`). The key and value
+    blocks pass an optimization barrier before the scan, so they are made
+    once a call and not once a tile. The loop is a ``while`` on the device:
     nothing differentiates through it. With no mask at all nothing can be
     skipped: one ``lax.scan`` over the K/V blocks scores every query
     against each, O(T * block_size) scores.
@@ -205,8 +217,13 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      ).reshape(b * n_tiles, 1, qt, h, d),
              jnp.repeat(jnp.arange(b), n_tiles),
              jnp.tile(jnp.arange(n_tiles), b), lo, hi)
-    kb = kp.reshape(b * n_blocks, 1, bs, h, d)
-    vb = vp.reshape(b * n_blocks, 1, bs, h, dv)
+    # the blocks the loops index are made once, before them: left alone the
+    # compiler may sink their producers (grouped-query keys and values
+    # repeated to every head, and the copies that lay them out) into the
+    # scan, which then rebuilds all of K and V for every query tile
+    kb, vb = jax.lax.optimization_barrier(
+        (kp.reshape(b * n_blocks, 1, bs, h, d),
+         vp.reshape(b * n_blocks, 1, bs, h, dv)))
     if segment_ids is not None:
         # a padded query or key is in no segment
         tiles += (jnp.pad(segment_ids, ((0, 0), (0, q_pad)),

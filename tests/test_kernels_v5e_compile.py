@@ -62,10 +62,11 @@ def test_proj_kernel_compiles_for_a_v5e(one_chip, h8, w8, pairs):
 
 
 #: (length, K, N, experts, tiles): both products of DeepSeek-V2-Lite's cell
-#: and of LFM2-8B-A1B's (every expert held: one length) and of granite's
-#: (the compact buffer and the buffer with room for every assignment), K
-#: whole at all of them; and a K so wide that it is tiled, with the
-#: accumulator in VMEM
+#: and of LFM2-8B-A1B's (every expert held: one length), of granite's and of
+#: Nemotron-3-Super's (the compact buffer and the buffer with room for every
+#: assignment; nemotron's non-gated products run in its 1,024-wide latent
+#: over 128 short groups), K whole at all of them; and a K so wide that it
+#: is tiled, with the accumulator in VMEM
 @pytest.mark.parametrize("length, k, n, experts, tiles", [
     (98304, 2048, 2816, 64, (256, 2048, 1408)),
     (98304, 1408, 2048, 64, (256, 1408, 2048)),
@@ -75,6 +76,10 @@ def test_proj_kernel_compiles_for_a_v5e(one_chip, h8, w8, pairs):
     (102400, 768, 4096, 36, (256, 768, 2048)),
     (163840, 4096, 1536, 36, (256, 4096, 512)),
     (163840, 768, 4096, 36, (256, 768, 2048)),
+    (112640, 1024, 2688, 128, (256, 1024, 2688)),
+    (112640, 2688, 1024, 128, (256, 2688, 1024)),
+    (360448, 1024, 2688, 128, (256, 1024, 2688)),
+    (360448, 2688, 1024, 128, (256, 2688, 1024)),
     (4096, 8192, 4096, 8, (256, 4096, 512))])
 def test_grouped_matmul_compiles_for_a_v5e(one_chip, length, k, n, experts,
                                            tiles):
@@ -101,11 +106,12 @@ def _loop_bodies(text):
     return bodies
 
 
-#: (family, rows, tokens): the attention layers of the three token cells at
-#: their real widths; lfm2's and granite's repeat 8 key/value heads to 32
+#: (family, rows, tokens): the attention layers of the four token cells at
+#: their real widths; lfm2's and granite's repeat 8 key/value heads to 32,
+#: nemotron's 2 to 32
 @pytest.mark.parametrize("family, rows, tokens", [
     ("lfm2_moe", 1, 16384), ("granite_hybrid", 4, 4096),
-    ("deepseek_v2", 1, 16384)])
+    ("deepseek_v2", 1, 16384), ("nemotron_h", 1, 16384)])
 def test_no_attention_loop_rebuilds_the_keys_or_values_for_a_v5e(
         one_chip, family, rows, tokens):
     """The blocks ``blockwise_attention``'s loops index are made once,
@@ -152,3 +158,41 @@ def test_no_attention_loop_rebuilds_the_keys_or_values_for_a_v5e(
             if shape and shape.group(1):
                 assert np.prod([int(x) for x in shape.group(1).split(",")]
                                ) < all_of_k, line
+
+
+def test_the_nemotron_step_compiles_for_a_v5e_and_fits_its_memory(
+        one_chip, monkeypatch):
+    """The whole step of ``nemotron-3-super-packed-resident`` (11 layers, 128
+    of 512 experts held, one row of 16,384 tokens) for a described v5e: the
+    grouped products of its five E layers in the Pallas kernel, in both
+    branches of each layer's condition, and the arguments and temporaries
+    inside the chip's 16.9 GB with room for what the process holds beside
+    them (measured: 9.028 GB of arguments, 3.655 GB of temporaries). The
+    kernel's gate asks the backend, which is the CPU here: the test opens
+    it where the shapes allow the kernel."""
+    from video_features_tpu.kernels import grouped_matmul as gm
+    from video_features_tpu.models import nemotron_h as nem
+
+    def by_shape(rows, weights):
+        (length, k), (_, _, n) = rows.shape, weights.shape
+        if gm.tiles_for(k, n, rows.dtype.itemsize) is None \
+                or length % gm.ROW_TILE:
+            return "no tiles"
+        return None
+
+    monkeypatch.setattr(gm, "grouped_matmul_refusal", by_shape)
+    published = yaml.safe_load((
+        Path(__file__).resolve().parents[1] / "video_features_tpu" / "configs"
+        / "nemotron_h.yml").read_text())["architecture"]
+    arch = nem.arch_from_config(dict(published, num_hidden_layers=11), 4, 0)
+    assert arch.hybrid_override_pattern == "MEMEMEM*EME"
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: nem.init_params(arch, 0, jnp.bfloat16)))
+    rows = jax.ShapeDtypeStruct((1, 2, 16384), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda p, r: nem.segment_features(
+        arch, 64, jnp.bfloat16, p, r)).lower(params, rows).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 5 * 2 * 2
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(9.028e9, rel=1e-3)
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9

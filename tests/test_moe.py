@@ -245,10 +245,11 @@ def loop_inputs(seed, k, dtype):
 
 
 def plain_loop(u, gates, experts, w_in, w_out, first, valid,
-               accumulate=np.float64):
+               accumulate=np.float64, activation="swiglu"):
     """``(sum over a token's held choices of gate * expert(u), the norm of
     those terms)``, token by token in float64 on the values the layer is
-    handed; the running sum is kept in ``accumulate``."""
+    handed; the running sum is kept in ``accumulate``. An expert is gated
+    (``swiglu``) or not (``relu2``)."""
     u, gates, w_in, w_out = (np.asarray(a, np.float64)
                              for a in (u, gates, w_in, w_out))
     out = np.zeros(u.shape, np.float64)
@@ -257,9 +258,13 @@ def plain_loop(u, gates, experts, w_in, w_out, first, valid,
         total, terms = np.zeros(u.shape[1], accumulate), []
         for gate, expert in zip(gates[i], np.asarray(experts)[i] - first):
             if 0 <= expert < len(w_in):
-                a, b = np.split(u[i] @ w_in[expert], 2)
-                terms.append(gate * ((a / (1 + np.exp(-a)) * b)
-                                     @ w_out[expert]))
+                hidden = u[i] @ w_in[expert]
+                if activation == "relu2":
+                    hidden = np.maximum(hidden, 0.0) ** 2
+                else:
+                    a, b = np.split(hidden, 2)
+                    hidden = a / (1 + np.exp(-a)) * b
+                terms.append(gate * (hidden @ w_out[expert]))
                 total = (total + terms[-1].astype(accumulate)
                          ).astype(accumulate)
         out[i] = total
@@ -298,6 +303,69 @@ def test_held_experts_is_the_plain_loop_over_tokens_and_choices(k, dtype):
     assert worst_token(got, want, scale) < TOLERANCE[dtype]
 
 
+#: nemotron_h's experts: non-gated, a quarter of 64 held, up to 22 choices
+RELU2_T, RELU2_WIDE, RELU2_HELD, RELU2_INNER = 256, 64, 16, 48
+
+
+def relu2_inputs(seed, k, dtype):
+    """Every token chooses ``k`` different experts of 64, of which experts
+    16 .. 31 are held; the experts are ``relu(u W)^2 V``, ``W`` (D, I)."""
+    rng = np.random.default_rng(seed)
+    experts = np.argsort(rng.random((RELU2_T, RELU2_WIDE)), axis=1)[:, :k]
+    gates = 5.0 * jax.nn.softmax(jnp.asarray(
+        rng.standard_normal((RELU2_T, k)), jnp.float32), axis=-1)
+    w_in = rng.standard_normal((RELU2_HELD, D, RELU2_INNER)) / np.sqrt(D)
+    w_out = rng.standard_normal((RELU2_HELD, RELU2_INNER, D)) \
+        / np.sqrt(RELU2_INNER)
+    return (jnp.asarray(rng.standard_normal((RELU2_T, D)), dtype), gates,
+            jnp.asarray(experts, jnp.int32), jnp.asarray(w_in, dtype),
+            jnp.asarray(w_out, dtype), RELU2_HELD,
+            jnp.asarray(np.arange(RELU2_T) < RELU2_T - 8))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("k", [4, 22])
+def test_relu2_experts_are_the_plain_loop_over_tokens_and_choices(k, dtype):
+    """Non-gated ``relu2`` experts (nemotron_h's), up to the 22 choices of
+    its router, a quarter of the experts held: the same tolerance as the
+    gated ones. The first product is I wide, not 2I."""
+    args = relu2_inputs(200 + k, k, dtype)
+    want, scale = plain_loop(*args, activation="relu2")
+    assert (scale > 0).sum() > RELU2_T // 2
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(moe.held_experts, static_argnums=(5, 7, 8))(
+            *args, RELU2_WIDE, "relu2")
+    assert got.dtype == jnp.float32
+    assert worst_token(got, want, scale) < TOLERANCE[dtype]
+    # the gated reading of the same weights is another layer
+    assert worst_token(got, plain_loop(*args[:3], args[3][..., :32],
+                                       args[4][:, :16], *args[5:])[0],
+                       scale) > 0.1
+
+
+def test_a_relu2_unit_and_what_the_moe_event_states_of_it():
+    rng = np.random.default_rng(5)
+    u = jnp.asarray(rng.standard_normal((10, D)), jnp.float32)
+    w_in = jnp.asarray(rng.standard_normal((D, RELU2_INNER)), jnp.float32)
+    w_out = jnp.asarray(rng.standard_normal((RELU2_INNER, D)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = moe.gated_unit(u, w_in, w_out, activation="relu2")
+    want = np.maximum(np.asarray(u, np.float64) @ np.asarray(w_in), 0) ** 2 \
+        @ np.asarray(w_out)
+    # float32 against float64, in units of the largest output
+    assert np.abs(np.asarray(got) - want).max() < 1e-6 * np.abs(want).max()
+    with pytest.raises(ValueError, match="none of"):
+        moe.activate(u, "gelu")
+    # the widths of both products come from the weights' shapes
+    stated = moe.stated_products(
+        1024, 22, 512, jnp.bfloat16,
+        jax.ShapeDtypeStruct((128, 1024, 2688), jnp.bfloat16),
+        jax.ShapeDtypeStruct((128, 2688, 1024), jnp.bfloat16))
+    assert stated["widths"] == [[1024, 2688], [2688, 1024]]
+    assert stated["rows"] == moe.held_rows(1024 * 22, 128, 512) == 7168
+
+
 def test_a_bfloat16_sum_over_the_choices_is_noticed():
     """The same loop with its running sum in bfloat16 is further from the
     layer, in float32, than the tolerance allows: the comparison above
@@ -318,15 +386,17 @@ def test_a_bfloat16_sum_over_the_choices_is_noticed():
 SELECTION_BIAS = 0.05 * np.random.default_rng(12).standard_normal(WIDE)
 
 
-def plain_gate(logits, k, rule, renormalise, scaling, selection_bias=None):
-    """The three published rules in numpy, token by token."""
+def plain_gate(logits, k, rule, renormalise, scaling, selection_bias=None,
+               eps=1e-6):
+    """The three published rules in numpy, token by token; ``eps`` is what
+    the sigmoid rule adds to the chosen sum."""
     gates, experts = [], []
     for row in np.asarray(logits, np.float64):
         if rule == "sigmoid":
             s = 1.0 / (1.0 + np.exp(-row))
             bias = 0.0 if selection_bias is None else selection_bias
             top = np.argsort(-(s + bias), kind="stable")[:k]
-            g = s[top] / (s[top].sum() + 1e-6) if renormalise else s[top]
+            g = s[top] / (s[top].sum() + eps) if renormalise else s[top]
         elif rule == "softmax_topk":
             p = np.exp(row - row.max())
             p /= p.sum()
@@ -349,9 +419,11 @@ def plain_gate(logits, k, rule, renormalise, scaling, selection_bias=None):
     dict(rule="sigmoid", selection_bias=SELECTION_BIAS),    # lfm2_moe's
     dict(rule="sigmoid", selection_bias=SELECTION_BIAS, scaling=2.5),
     dict(rule="sigmoid"),
+    dict(rule="sigmoid", selection_bias=SELECTION_BIAS, scaling=5.0,
+         renormalise_eps=1e-20),                            # nemotron_h's
 ], ids=["top_k_then_softmax", "softmax_then_top_k", "renormalised",
         "scaled", "sigmoid_biased", "sigmoid_biased_scaled",
-        "sigmoid_unbiased"])
+        "sigmoid_unbiased", "sigmoid_biased_scaled_by_5"])
 def test_route_under_each_rule_is_the_plain_computation(rule):
     rng = np.random.default_rng(11)
     u = jnp.asarray(rng.standard_normal((64, D)), jnp.float32)
@@ -362,7 +434,8 @@ def test_route_under_each_rule_is_the_plain_computation(rule):
     named = rule.get("rule", "topk_softmax")
     want_gates, want_experts = plain_gate(
         logits, K, named, rule.get("renormalise", True),
-        rule.get("scaling", 1.0), rule.get("selection_bias"))
+        rule.get("scaling", 1.0), rule.get("selection_bias"),
+        rule.get("renormalise_eps", 1e-6))
     assert np.array_equal(np.asarray(experts), want_experts)
     np.testing.assert_allclose(np.asarray(gates), want_gates, rtol=1e-5)
     if rule.get("selection_bias") is not None:     # the bias moved a choice
@@ -403,3 +476,27 @@ def test_granites_rule_is_the_default_and_its_program_is_the_parents():
 
     assert str(jax.make_jaxpr(now)(u, router)) == \
         str(jax.make_jaxpr(parent)(u, router))
+
+
+def test_top_22_of_512_under_a_bfloat16_router_swaps_what_float32_keeps():
+    """nemotron_h's rule: a sigmoid over 512 experts with a bias of the
+    seeded scale, the top 22, gates renormalised and scaled by 5; inputs
+    and weights exact in bfloat16, 4,096 tokens of width 1,024. float32
+    logits choose what float64 chooses for every token; logits rounded to
+    bfloat16 swap an expert for 7.3% of them."""
+    rng = np.random.default_rng(1)
+    u = jnp.asarray(rng.standard_normal((4096, 1024)), jnp.bfloat16)
+    w = jnp.asarray(0.02 * rng.standard_normal((1024, 512)), jnp.bfloat16)
+    bias = (0.05 * rng.standard_normal(512)).astype(np.float32)
+    exact = np.asarray(u, np.float64) @ np.asarray(w, np.float64)
+    want = np.sort(np.argsort(-(1.0 / (1.0 + np.exp(-exact)) + bias),
+                              axis=1)[:, :22], axis=1)
+
+    def swapped(router_dtype):
+        gates, chosen = moe.route(u, w, 22, router_dtype, rule="sigmoid",
+                                  selection_bias=bias, scaling=5.0,
+                                  renormalise_eps=1e-20)
+        np.testing.assert_allclose(np.asarray(gates).sum(1), 5.0, rtol=1e-5)
+        return float((np.sort(np.asarray(chosen), 1) != want).any(1).mean())
+
+    assert swapped(jnp.float32) < 0.001 < 0.03 < swapped(jnp.bfloat16)

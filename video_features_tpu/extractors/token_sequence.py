@@ -1,5 +1,5 @@
 """What the token-sequence extractors share (``granite_hybrid``,
-``deepseek_v2``, ``lfm2_moe``).
+``deepseek_v2``, ``lfm2_moe``, ``nemotron_h``).
 
 The item is a file of token ids (``.tokens``: raw little-endian int32, what
 a tokenizer run over a caption or a transcript leaves), not a video. A
@@ -165,16 +165,20 @@ class TokenSequenceExtractor(BaseExtractor):
             # layer's
             a_layer = counts.sum(axis=0)         # (routed layers, experts)
             first = self.arch.first_expert
-            trace.counter("moe.assignments", int(
-                a_layer[:, first:first + self.arch.experts_held]
-                .sum(axis=1).max()), series="held")
+            held = a_layer[:, first:first + self.arch.experts_held].sum(
+                axis=1)
+            trace.counter("moe.assignments", int(held.max()), series="held")
             trace.counter("moe.assignments", int(a_layer[0].sum()),
                           series="all")
             # how uneven each routed layer's load is: its fullest expert
-            # over the mean of the router's width
+            # over the mean of the router's width; and what share of the
+            # layer's assignments the experts held here take
             for i, load in enumerate(a_layer):
                 trace.counter("moe.fullest_over_mean",
                               float(load.max() / load.mean()),
+                              series=f"layer{i}")
+                trace.counter("moe.held_share",
+                              float(held[i] / max(load.sum(), 1)),
                               series=f"layer{i}")
         if self.show_pred:
             self.maybe_show_pred(ids, windows)

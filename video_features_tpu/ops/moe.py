@@ -33,7 +33,10 @@ sublane position of a tile, where 10 (or 6) of them pad to the 16 rows of a
 bfloat16 tile and every pass over the array moves the pad too; a leading K
 pads nothing, whatever K is.
 
-An expert is a gated unit: ``(silu(u W[:, :I]) * (u W[:, I:])) V``.
+An expert is a unit ``act(u W) V`` under a static :data:`ACTIVATIONS` rule:
+``swiglu`` (granite, dsv2, lfm2), the gated ``silu(u W[:, :I]) * (u W[:,
+I:])`` of a ``W`` 2I wide, or ``relu2`` (nemotron_h), the non-gated
+``relu(u W)^2`` of a ``W`` I wide.
 """
 from __future__ import annotations
 
@@ -47,15 +50,17 @@ import jax.numpy as jnp
 from ..kernels import grouped_matmul as kernel     # imports no Pallas
 
 
-#: :func:`route`'s rules: granite's, deepseek_v2's, lfm2_moe's
+#: :func:`route`'s rules: granite's, deepseek_v2's, lfm2_moe's and nemotron_h's
 RULES = ("topk_softmax", "softmax_topk", "sigmoid")
+#: an expert's activation (the module docstring)
+ACTIVATIONS = ("swiglu", "relu2")
 
 
 def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
           router_dtype=jnp.float32, rule: str = "topk_softmax",
           renormalise: bool = True, scaling: float = 1.0,
-          selection_bias: Optional[jnp.ndarray] = None
-          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+          selection_bias: Optional[jnp.ndarray] = None,
+          renormalise_eps: float = 1e-6) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(gates, experts)``, each (T, top_k): the router's choice for every
     token of ``u`` (T, D) over all ``router.shape[1]`` experts. Logits and
     gates are float32 (``router_dtype``: the tests lower it to show that the
@@ -70,12 +75,13 @@ def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
     where ``renormalise`` (``norm_topk_prob``), and multiplied by
     ``scaling`` (``routed_scaling_factor``; the published deepseek_v2 code
     leaves the factor out where it renormalises, deepseek_v3's applies both,
-    as here). ``"sigmoid"`` (lfm2_moe's): every logit goes through a sigmoid
-    ``s``, the experts with the ``top_k`` largest ``s + selection_bias`` are
-    chosen (the per-expert bias, float32, moves the choice and nothing
-    else), and the gates are the chosen ``s``, divided by their sum + 1e-6
-    where ``renormalise``, times ``scaling``. ``selection_bias`` belongs to
-    the sigmoid rule alone."""
+    as here). ``"sigmoid"`` (lfm2_moe's, nemotron_h's): every logit goes
+    through a sigmoid ``s``, the experts with the ``top_k`` largest ``s +
+    selection_bias`` are chosen (the per-expert bias, float32, moves the
+    choice and nothing else), and the gates are the chosen ``s``, divided
+    by their sum + ``renormalise_eps`` (lfm2's published 1e-6, nemotron_h's
+    1e-20) where ``renormalise``, times ``scaling``. ``selection_bias``
+    belongs to the sigmoid rule alone."""
     if rule not in RULES:
         raise ValueError(f"route: rule {rule!r} is none of {RULES}")
     if selection_bias is not None and rule != "sigmoid":
@@ -89,7 +95,8 @@ def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
             else s + selection_bias.astype(jnp.float32), top_k)
         gates = jnp.take_along_axis(s, experts, axis=-1)
         if renormalise:
-            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-6)
+            gates = gates / (gates.sum(axis=-1, keepdims=True)
+                             + renormalise_eps)
     elif rule == "topk_softmax":
         top, experts = jax.lax.top_k(logits, top_k)
         gates = jax.nn.softmax(top.astype(jnp.float32), axis=-1)
@@ -101,13 +108,25 @@ def route(u: jnp.ndarray, router: jnp.ndarray, top_k: int,
     return (gates if scaling == 1.0 else gates * scaling), experts
 
 
-def gated_unit(u: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray
-               ) -> jnp.ndarray:
-    """The shared expert: ``(silu(u W[:, :I]) * (u W[:, I:])) V`` for every
+def activate(hidden: jnp.ndarray, activation: str = "swiglu"
+             ) -> jnp.ndarray:
+    """An expert's hidden layer after its activation (:data:`ACTIVATIONS`):
+    ``swiglu`` halves the width, ``relu2`` keeps it."""
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(hidden))
+    if activation != "swiglu":
+        raise ValueError(f"activation {activation!r} is none of "
+                         f"{ACTIVATIONS}")
+    gate, up = jnp.split(hidden, 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
+def gated_unit(u: jnp.ndarray, w_in: jnp.ndarray, w_out: jnp.ndarray,
+               activation: str = "swiglu") -> jnp.ndarray:
+    """The shared expert, ``act(u W) V`` (:func:`activate`) for every
     token, float32 out."""
     hidden = jnp.dot(u, w_in, preferred_element_type=jnp.float32)
-    gate, up = jnp.split(hidden, 2, axis=-1)
-    return jnp.dot((jax.nn.silu(gate) * up).astype(u.dtype), w_out,
+    return jnp.dot(activate(hidden, activation).astype(u.dtype), w_out,
                    preferred_element_type=jnp.float32)
 
 
@@ -131,7 +150,7 @@ def held_rows(assignments: int, held: int, wide: int) -> int:
 
 def pallas_products(length: int, dtype, w_in, w_out) -> Optional[str]:
     """Why the two products of ``length`` rows of ``dtype`` through experts
-    ``w_in`` (E, D, 2I) and ``w_out`` (E, I, D) do not both run in the
+    ``w_in`` (E, D, 2I or I) and ``w_out`` (E, I, D) do not both run in the
     Pallas kernel, or ``None`` where they do
     (``kernel.grouped_matmul_refusal`` of each: the backend and the
     shapes)."""
@@ -149,10 +168,10 @@ def stated_products(tokens: int, top_k: int, wide: int, dtype, w_in, w_out
     ``tokens`` tokens of ``dtype`` a dispatch (feature values do not say
     which products ran): the answer of the gate the step itself asks, at
     the compact buffer's length."""
-    held, d, two_i = w_in.shape
+    held = w_in.shape[0]
     rows = held_rows(tokens * top_k, held, wide)
     fallback = pallas_products(rows, dtype, w_in, w_out)
-    widths = [[d, two_i], [two_i // 2, d]]
+    widths = [list(w_in.shape[1:]), list(w_out.shape[1:])]
     return dict(
         products="pallas" if fallback is None else "ragged_dot", rows=rows,
         experts=held, widths=widths, fallback=fallback,
@@ -163,11 +182,13 @@ def stated_products(tokens: int, top_k: int, wide: int, dtype, w_in, w_out
 
 def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
                  w_in: jnp.ndarray, w_out: jnp.ndarray, first: int,
-                 valid: jnp.ndarray, wide: int) -> jnp.ndarray:
+                 valid: jnp.ndarray, wide: int, activation: str = "swiglu"
+                 ) -> jnp.ndarray:
     """This chip's part of the routed layer, (T, D) float32.
 
     ``u`` (T, D); ``gates`` / ``experts`` (T, K) from :func:`route` over
-    ``wide`` experts; ``w_in`` (E, D, 2I) and ``w_out`` (E, I, D) are experts
+    ``wide`` experts; ``w_in`` (E, D, 2I or I by ``activation``,
+    :func:`activate`) and ``w_out`` (E, I, D) are experts
     ``first`` .. ``first + E - 1``; ``valid`` (T,) is false for padding,
     which is routed nowhere. Assignments to an expert that is not held sort
     behind the held ones and fall outside every group, so they are never
@@ -193,8 +214,7 @@ def held_experts(u: jnp.ndarray, gates: jnp.ndarray, experts: jnp.ndarray,
             else partial(
                 jax.lax.ragged_dot, preferred_element_type=u.dtype)
         hidden = product(rows, w_in, sizes)
-        gate, up = jnp.split(hidden, 2, axis=-1)
-        out = product(jax.nn.silu(gate) * up, w_out, sizes)
+        out = product(activate(hidden, activation), w_out, sizes)
         # an assignment that is not held has its place behind the held ones:
         # clamped into the buffer, to a row the caller masks
         at = place if length == t * k else jnp.minimum(place, length - 1)

@@ -11,8 +11,10 @@ one document into the next. :func:`ssd_scan` computes it in chunks (the
 "state-space duality" form of Dao & Gu 2024): inside a chunk the recurrence
 unrolls into one masked (Q, Q) matrix per head that multiplies the chunk's
 inputs, between chunks only the (P, N) state is carried. The result does not
-depend on the chunk length. One group of ``B``/``C`` serves every head
-(``mamba_n_groups`` 1).
+depend on the chunk length. ``B``/``C`` come in ``G`` groups, each shared
+by ``H / G`` consecutive heads: head ``h`` reads group ``h // (H / G)``
+(nemotron_h's ``n_groups`` 8); one group given as (B, T, N) serves every
+head (granite's ``mamba_n_groups`` 1).
 
 :func:`causal_conv1d` is the depthwise convolution in front of it, whose
 taps do not read across a segment boundary either; lfm2_moe's short
@@ -59,57 +61,68 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     """``y`` (B, T, H, P) float32 of the recurrence above.
 
     ``x`` (B, T, H, P) in the compute type, ``dt`` (B, T, H) float32 after
-    its softplus, ``a`` (H,) float32 negative, ``b`` / ``c`` (B, T, N),
-    ``seg`` (B, T) int32. ``T`` need not be a multiple of ``chunk``: the
-    tail is padded with a segment of its own. Decays and the carried state
-    are float32 (``state_dtype``: the tests carry it in bfloat16 to show
-    that the comparison notices); the matrix products take their operands
-    in ``x.dtype`` and accumulate in float32.
+    its softplus, ``a`` (H,) float32 negative, ``b`` / ``c`` (B, T, N) for
+    one group or (B, T, G, N) for ``G`` groups, ``seg`` (B, T) int32. ``T``
+    need not be a multiple of ``chunk``: the tail is padded with a segment
+    of its own. Decays and the carried state are float32 (``state_dtype``:
+    the tests carry it in bfloat16 to show that the comparison notices);
+    the matrix products take their operands in ``x.dtype`` and accumulate
+    in float32.
+
+    With groups the heads run as (G, H / G): every (Q, Q) score matrix is
+    computed once a group and serves the group's heads.
     """
     bsz, t, h, p = x.shape
     n = b.shape[-1]
+    grouped = b.ndim == 4
+    # the head axes of every einsum below, and the group axis of B and C
+    g, hs = ("g", "gj") if grouped else ("", "h")
+    heads = (b.shape[2], h // b.shape[2]) if grouped else (h,)
+    lift = (None,) * len(heads)     # a (B, C, Q) mask over the head axes
     q = int(chunk)
     pad = (-t) % q
     if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
-        c = jnp.pad(c, ((0, 0), (0, pad), (0, 0)))
+        def tail(v):
+            return ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)
+        x, dt, b, c = (jnp.pad(v, tail(v)) for v in (x, dt, b, c))
         seg = jnp.pad(seg, ((0, 0), (0, pad)), constant_values=-1)
     nc = (t + pad) // q
     mm = x.dtype
-    xc = x.reshape(bsz, nc, q, h, p)
-    dtc = dt.reshape(bsz, nc, q, h).astype(jnp.float32)
-    bc = b.reshape(bsz, nc, q, n).astype(mm)
-    cc = c.reshape(bsz, nc, q, n).astype(mm)
+    xc = x.reshape(bsz, nc, q, *heads, p)
+    dtc = dt.reshape(bsz, nc, q, *heads).astype(jnp.float32)
+    bc = b.reshape(bsz, nc, q, *b.shape[2:]).astype(mm)
+    cc = c.reshape(bsz, nc, q, *c.shape[2:]).astype(mm)
     segc = seg.reshape(bsz, nc, q)
 
     # cum[t] = sum of dt*a over the chunk's tokens up to and including t
-    cum = jnp.cumsum(dtc * a.astype(jnp.float32), axis=2)     # (B, C, Q, H)
+    cum = jnp.cumsum(dtc * a.reshape(heads).astype(jnp.float32),
+                     axis=2)                                  # (B, C, Q, H)
     xdt = (xc.astype(jnp.float32) * dtc[..., None]).astype(mm)
 
     # -- inside a chunk: y[t] += sum_{s<=t, same segment} (C_t.B_s)
     #    exp(cum[t] - cum[s]) dt_s x_s; the (Q, Q) scores serve every head
+    #    of their group
     causal = jnp.tril(jnp.ones((q, q), bool))
     same = (segc[:, :, :, None] == segc[:, :, None, :]) & causal
-    scores = jnp.einsum("bctn,bcsn->bcts", cc, bc,
+    scores = jnp.einsum(f"bct{g}n,bcs{g}n->bc{g}ts", cc, bc,
                         preferred_element_type=jnp.float32)
-    scores = jnp.where(same, scores, 0.0)
-    cum_h = jnp.moveaxis(cum, 3, 2)                           # (B, C, H, Q)
+    scores = jnp.where(same[:, :, None] if grouped else same, scores, 0.0)
+    cum_h = jnp.moveaxis(cum, 2, -1)                          # (B, C, H, Q)
     # above the diagonal the difference is positive: held at 0, where the
     # masked score already makes the entry zero, so nothing overflows
     decay = jnp.exp(jnp.minimum(
         cum_h[..., :, None] - cum_h[..., None, :], 0.0))      # (B,C,H,Q,Q)
-    mixing = (scores[:, :, None] * decay).astype(mm)
-    y = jnp.einsum("bchts,bcshp->bcthp", mixing, xdt,
+    mixing = (jnp.expand_dims(scores, scores.ndim - 2) * decay).astype(mm)
+    y = jnp.einsum(f"bc{hs}ts,bcs{hs}p->bct{hs}p", mixing, xdt,
                    preferred_element_type=jnp.float32)
 
     # -- each chunk's own contribution to the state at its end
     last_seg = segc[:, :, -1]                                 # (B, C)
-    to_end = jnp.exp(cum[:, :, -1:, :] - cum)                 # (B, C, Q, H)
-    to_end = jnp.where((segc == last_seg[..., None])[..., None], to_end, 0.0)
+    to_end = jnp.exp(cum[:, :, -1:] - cum)                    # (B, C, Q, H)
+    to_end = jnp.where((segc == last_seg[..., None])[(..., *lift)], to_end,
+                       0.0)
     weighted = (xdt.astype(jnp.float32) * to_end[..., None]).astype(mm)
-    local = jnp.einsum("bcshp,bcsn->bchpn", weighted, bc,
+    local = jnp.einsum(f"bcs{hs}p,bcs{g}n->bc{hs}pn", weighted, bc,
                        preferred_element_type=jnp.float32)
 
     # -- between chunks: the state that enters chunk c is that of the
@@ -117,8 +130,8 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
     #    if this chunk ends in the same segment
     prev_seg = jnp.concatenate(
         [jnp.full((bsz, 1), -2, segc.dtype), last_seg[:, :-1]], axis=1)
-    keep = jnp.where((last_seg == prev_seg)[..., None],
-                     jnp.exp(cum[:, :, -1, :]), 0.0)          # (B, C, H)
+    keep = jnp.where((last_seg == prev_seg)[(..., *lift)],
+                     jnp.exp(cum[:, :, -1]), 0.0)             # (B, C, H)
 
     def carry_on(state, chunk_c):
         keep_c, local_c = chunk_c
@@ -128,15 +141,16 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a: jnp.ndarray,
         return state, entering
 
     _, entering = jax.lax.scan(
-        carry_on, jnp.zeros((bsz, h, p, n), state_dtype),
+        carry_on, jnp.zeros((bsz, *heads, p, n), state_dtype),
         (jnp.moveaxis(keep, 1, 0), jnp.moveaxis(local, 1, 0)))
     entering = jnp.moveaxis(entering, 0, 1)                   # (B,C,H,P,N)
 
     # -- y[t] += exp(cum[t]) C_t . S_entering for the tokens still in the
     #    segment the state belongs to
-    from_start = jnp.where((segc == prev_seg[..., None])[..., None],
+    from_start = jnp.where((segc == prev_seg[..., None])[(..., *lift)],
                            jnp.exp(cum), 0.0)                 # (B, C, Q, H)
-    carried = jnp.einsum("bctn,bchpn->bcthp", cc, entering.astype(mm),
+    carried = jnp.einsum(f"bct{g}n,bc{hs}pn->bct{hs}p", cc,
+                         entering.astype(mm),
                          preferred_element_type=jnp.float32)
     y = y + carried * from_start[..., None]
     return y.reshape(bsz, nc * q, h, p)[:, :t]

@@ -21,6 +21,7 @@ _DISPATCH = {
     "granite_hybrid": ("granite_hybrid", "ExtractGraniteHybrid"),
     "deepseek_v2": ("deepseek_v2", "ExtractDeepSeekV2"),
     "lfm2_moe": ("lfm2_moe", "ExtractLFM2Moe"),
+    "nemotron_h": ("nemotron_h", "ExtractNemotronH"),
 }
 
 #: families that consume the AUDIO track: in a multi-family run they

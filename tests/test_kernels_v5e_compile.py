@@ -196,3 +196,71 @@ def test_the_nemotron_step_compiles_for_a_v5e_and_fits_its_memory(
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(9.028e9, rel=1e-3)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+
+
+def _large_float32_copies(text, at_least=256e6):
+    """The entry computation's float32 ``copy`` operations of ``at_least``
+    bytes or more: each is an activation written out again in another
+    layout."""
+    start = text.index("\nENTRY ")
+    entry = text[start:text.index("\n}\n", start)]
+    found = []
+    for line in entry.splitlines():
+        shape = re.search(r"= f32\[([\d,]+)\]\S* copy\(", line)
+        if shape and 4 * np.prod([int(x) for x in shape.group(1).split(",")]
+                                 ) >= at_least:
+            found.append(line.strip()[:160])
+    return found
+
+
+#: (family, rows, tokens, bytes the scan reads and writes, bytes one Mamba
+#: layer does): nemotron's 128 heads x 64 over 8 groups of B/C, state 128,
+#: chunk 128; granite's the same heads over one group, chunk 256, four rows
+#: a dispatch
+@pytest.mark.parametrize("family, rows, tokens, scan_bytes, layer_bytes", [
+    ("nemotron_h", 1, 16384, 3.96e9, 11.43e9),
+    ("granite_hybrid", 4, 4096, 3.69e9, 8.67e9)])
+def test_the_mamba_layer_lays_out_no_float32_activation_twice_for_a_v5e(
+        one_chip, family, rows, tokens, scan_bytes, layer_bytes):
+    """``ssd_chunks`` and one ``mamba_mixer`` at the hybrid cells' widths
+    for a described v5e: no float32 ``copy`` of 256 MB or more in either
+    (a relaid activation, ``ops/ssd.py``'s layout rule), and XLA's ``bytes
+    accessed`` within 10% of what the layout reaches. With the activations
+    token-major (B, T, H, P) the scan read 9.12 GB (nemotron) and 7.22 GB
+    (granite), a layer 20.8 GB and 16.2 GB, with five and three float32
+    copies of 537 MB; in this layout 3.96 and 3.69 GB, 11.43 and 8.67."""
+    from video_features_tpu.ops import ssd
+
+    mod = importlib.import_module(f"video_features_tpu.models.{family}")
+    published = yaml.safe_load((
+        Path(__file__).resolve().parents[1] / "video_features_tpu" / "configs"
+        / f"{family}.yml").read_text())["architecture"]
+    arch = mod.arch_from_config(published, 1, 0)
+    if family == "nemotron_h":
+        h, p, n, groups, q = (arch.mamba_num_heads, arch.mamba_head_dim,
+                              arch.ssm_state_size, (arch.n_groups,),
+                              arch.chunk_size)
+    else:
+        h, p, n, groups, q = (arch.mamba_n_heads, arch.mamba_d_head,
+                              arch.mamba_d_state, (), arch.mamba_chunk_size)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = tokens // q
+    scan = jax.jit(ssd.ssd_chunks).lower(
+        spec((rows, c, h, p, q)), spec((rows, c, h, q), jnp.float32),
+        spec((h,), jnp.float32), spec((rows, c, *groups, n, q)),
+        spec((rows, c, *groups, n, q)), spec((rows, c, q), jnp.int32)
+    ).compile()
+    w = {k: spec(s.shape, s.dtype if s.ndim < 2 else jnp.bfloat16)
+         for k, s in jax.eval_shape(
+             lambda: mod._mamba_weights(arch, jax.random.key(0))).items()}
+    layer = jax.jit(lambda *a: mod.mamba_mixer(arch, *a)).lower(
+        w, spec((rows, tokens, arch.hidden_size)),
+        spec((rows, tokens), jnp.int32)).compile()
+    for compiled, reached in ((scan, scan_bytes), (layer, layer_bytes)):
+        assert _large_float32_copies(compiled.as_text()) == []
+        cost = compiled.cost_analysis()
+        cost = cost[0] if isinstance(cost, list) else cost
+        assert cost["bytes accessed"] <= 1.1 * reached

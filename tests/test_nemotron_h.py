@@ -287,6 +287,29 @@ def test_the_grouped_scan_is_the_recurrence(groups, chunk):
         assert relative(scan(b[:, 0], c[:, 0]), scan(b, c)) < 1e-6
 
 
+def test_two_rows_of_the_grouped_scan_are_each_their_recurrence():
+    """Two rows of 45 tokens in chunks of 8 (no multiple: three padding
+    tokens of a segment of their own), 8 heads over 2 groups: the first
+    row's boundaries at 16 and 40 lie on chunk edges, the second's at 13
+    and 24 inside and on one, with a padding tail. Each row is its own
+    token-by-token recurrence: float32 against float64, 1e-5 of the largest
+    output; nothing of one row reaches the other."""
+    rng = np.random.default_rng(7)
+    rows, t, h, p, n, g = 2, 45, 8, 8, 16, 2
+    x = rng.standard_normal((rows, t, h, p))
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (rows, t, h)))
+    a = -rng.uniform(1.0, 16.0, h)
+    b, c = (rng.standard_normal((rows, t, g, n)) for _ in range(2))
+    seg = np.array([[1] * 16 + [2] * 24 + [3] * 5,
+                    [1] * 13 + [2] * 11 + [3] * 17 + [0] * 4])
+    got = np.asarray(ssd.ssd_scan(
+        *(jnp.asarray(v, jnp.float32) for v in (x, dt, a, b, c)),
+        jnp.asarray(seg), 8))
+    for row in range(rows):
+        want = recurrence(x[row], dt[row], a, b[row], c[row], seg[row])
+        assert relative(got[row], want) < 1e-5
+
+
 def test_a_bfloat16_grouped_scan_state_fails_where_float32_passes():
     """512 tokens of one document in chunks of 8 over 4 groups, heads that
     forget slowly: the state is the sum of hundreds of tokens and is handed
@@ -323,10 +346,12 @@ def test_the_group_norm_takes_each_group_alone():
     y, z = (rng.standard_normal((3, 128)).astype(np.float32)
             for _ in range(2))
     weight = np.ones(128, np.float32)
-    base = np.asarray(nem.gated_group_norm(y, z, weight, 4, 1e-5))
+    base = np.asarray(nem.gated_group_norm(y, z, weight, 4, 1e-5)
+                      ).reshape(3, 128)
     scaled = y.copy()
     scaled[:, :32] *= 10.0
-    moved = np.asarray(nem.gated_group_norm(scaled, z, weight, 4, 1e-5))
+    moved = np.asarray(nem.gated_group_norm(scaled, z, weight, 4, 1e-5)
+                       ).reshape(3, 128)
     np.testing.assert_allclose(moved[:, 32:], base[:, 32:], rtol=1e-6)
     gated = (y * z / (1 + np.exp(-z))).reshape(3, 4, 32)
     want = gated / np.sqrt((gated ** 2).mean(-1, keepdims=True) + 1e-5)

@@ -230,26 +230,25 @@ def init_params(arch: Arch, seed: int, dtype, sharding=None) -> Dict[str, Any]:
 
 def mamba_mixer(arch: Arch, w: Mapping[str, jnp.ndarray], u: jnp.ndarray,
                 seg: jnp.ndarray, state_dtype=jnp.float32) -> jnp.ndarray:
-    """``u`` (B, T, D) -> (B, T, D) float32."""
+    """``u`` (B, T, D) -> (B, T, D) float32. Between the projections every
+    activation is in the scan's layout, (B, C, channels, Q) (``ops/ssd.py``),
+    and the projection out reads the normed output as it is."""
     bsz, t, _ = u.shape
     h, p, n = arch.mamba_n_heads, arch.mamba_d_head, arch.mamba_d_state
-    d_in = arch.mamba_d_inner
-    zxbcdt = jnp.dot(u, w["in_proj"], preferred_element_type=jnp.float32)
-    z = zxbcdt[..., :d_in]
-    xbc = zxbcdt[..., d_in:d_in + arch.conv_dim].astype(u.dtype)
-    dt = jax.nn.softplus(zxbcdt[..., d_in + arch.conv_dim:]
-                         + w["dt_bias"].astype(jnp.float32))
-    xbc = jax.nn.silu(ssd.causal_conv1d(xbc, w["conv_w"], w["conv_b"], seg)
-                      .astype(jnp.float32)).astype(u.dtype)
-    xs = xbc[..., :d_in].reshape(bsz, t, h, p)
-    b, c = xbc[..., d_in:d_in + n], xbc[..., d_in + n:]
+    d_in, q = arch.mamba_d_inner, arch.mamba_chunk_size
+    z, xbc, dt, seg = ssd.mamba_in(w, u, seg, d_in, arch.conv_dim, q)
+    nc = seg.shape[1]
+    xs = xbc[:, :, :d_in].reshape(bsz, nc, h, p, q)
+    b, c = xbc[:, :, d_in:d_in + n], xbc[:, :, d_in + n:]
     with scope("ssd"):
-        y = ssd.ssd_scan(xs, dt, -jnp.exp(w["A_log"].astype(jnp.float32)),
-                         b, c, seg, arch.mamba_chunk_size, state_dtype)
-    y = y + xs.astype(jnp.float32) * w["D"].astype(jnp.float32)[:, None]
-    y = y.reshape(bsz, t, d_in) * jax.nn.silu(z)
-    y = rms_norm(y, w["norm"], arch.rms_norm_eps).astype(u.dtype)
-    return jnp.dot(y, w["out_proj"], preferred_element_type=jnp.float32)
+        y = ssd.ssd_chunks(xs, dt, -jnp.exp(w["A_log"].astype(jnp.float32)),
+                           b, c, seg, state_dtype)
+    y = y + xs.astype(jnp.float32) * w["D"].astype(jnp.float32)[:, None, None]
+    y = y.reshape(bsz, nc, d_in, q) * jax.nn.silu(z)
+    y = rms_norm(y, w["norm"], arch.rms_norm_eps, axis=2).astype(u.dtype)
+    out = jnp.einsum("bckq,kd->bcqd", y, w["out_proj"],
+                     preferred_element_type=jnp.float32)
+    return out.reshape(bsz, nc * q, -1)[:, :t]
 
 
 def attention_mixer(arch: Arch, w: Mapping[str, jnp.ndarray], u: jnp.ndarray,

@@ -273,40 +273,44 @@ def init_params(arch: Arch, seed: int, dtype, sharding=None) -> Dict[str, Any]:
 # -- the forward pass --------------------------------------------------------
 
 def gated_group_norm(y: jnp.ndarray, z: jnp.ndarray, weight: jnp.ndarray,
-                     groups: int, eps: float) -> jnp.ndarray:
+                     groups: int, eps: float, axis: int = -1) -> jnp.ndarray:
     """``y * silu(z)`` RMS-normed per group of ``C / groups`` consecutive
     channels (the published ``MambaRMSNormGated``), float32 inside and out:
-    ``y``, ``z`` (..., C)."""
+    ``y``, ``z`` with their ``C`` channels on ``axis``, returned split into
+    (``groups``, ``C / groups``) there."""
+    axis %= y.ndim
     gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    parts = gated.reshape(*gated.shape[:-1], groups, -1)
-    parts = parts * jax.lax.rsqrt(
-        jnp.mean(parts * parts, axis=-1, keepdims=True) + eps)
-    return parts.reshape(gated.shape) * weight.astype(jnp.float32)
+    parts = gated.reshape(*gated.shape[:axis], groups, -1,
+                          *gated.shape[axis + 1:])
+    return rms_norm(parts, weight.reshape(groups, -1), eps, axis=axis + 1)
 
 
 def mamba_mixer(arch: Arch, w: Mapping[str, jnp.ndarray], u: jnp.ndarray,
                 seg: jnp.ndarray, state_dtype=jnp.float32) -> jnp.ndarray:
-    """``u`` (B, T, D) -> (B, T, D) float32."""
+    """``u`` (B, T, D) -> (B, T, D) float32, in the scan's layout between
+    the projections as granite's (``models/granite_hybrid.py
+    mamba_mixer``)."""
     bsz, t, _ = u.shape
     h, p, n = arch.mamba_num_heads, arch.mamba_head_dim, arch.ssm_state_size
-    g, d_in = arch.n_groups, arch.d_inner
-    zxbcdt = jnp.dot(u, w["in_proj"], preferred_element_type=jnp.float32)
-    z = zxbcdt[..., :d_in]
-    xbc = zxbcdt[..., d_in:d_in + arch.conv_dim].astype(u.dtype)
-    dt = jax.nn.softplus(zxbcdt[..., d_in + arch.conv_dim:]
-                         + w["dt_bias"].astype(jnp.float32))
-    xbc = jax.nn.silu(ssd.causal_conv1d(xbc, w["conv_w"], w["conv_b"], seg)
-                      .astype(jnp.float32)).astype(u.dtype)
-    xs = xbc[..., :d_in].reshape(bsz, t, h, p)
-    b = xbc[..., d_in:d_in + g * n].reshape(bsz, t, g, n)
-    c = xbc[..., d_in + g * n:].reshape(bsz, t, g, n)
+    g, d_in, q = arch.n_groups, arch.d_inner, arch.chunk_size
+    z, xbc, dt, seg = ssd.mamba_in(w, u, seg, d_in, arch.conv_dim, q)
+    nc = seg.shape[1]
+    xs = xbc[:, :, :d_in].reshape(bsz, nc, h, p, q)
+    b = xbc[:, :, d_in:d_in + g * n].reshape(bsz, nc, g, n, q)
+    c = xbc[:, :, d_in + g * n:].reshape(bsz, nc, g, n, q)
     with scope("ssd"):
-        y = ssd.ssd_scan(xs, dt, -jnp.exp(w["A_log"].astype(jnp.float32)),
-                         b, c, seg, arch.chunk_size, state_dtype)
-    y = y + xs.astype(jnp.float32) * w["D"].astype(jnp.float32)[:, None]
-    y = gated_group_norm(y.reshape(bsz, t, d_in), z, w["norm"], g,
-                         arch.layer_norm_epsilon).astype(u.dtype)
-    return jnp.dot(y, w["out_proj"], preferred_element_type=jnp.float32)
+        y = ssd.ssd_chunks(xs, dt, -jnp.exp(w["A_log"].astype(jnp.float32)),
+                           b, c, seg, state_dtype)
+    y = y + xs.astype(jnp.float32) * w["D"].astype(jnp.float32)[:, None, None]
+    y = gated_group_norm(y.reshape(bsz, nc, d_in, q), z, w["norm"], g,
+                         arch.layer_norm_epsilon, axis=2).astype(u.dtype)
+    # contracted a group at a time, in the shape the norm leaves: merged
+    # back into one axis first, the norm's scale is written out at full
+    # width before the product reads it
+    out = jnp.einsum("bcgkq,gkd->bcqd", y,
+                     w["out_proj"].reshape(g, d_in // g, -1),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(bsz, nc * q, -1)[:, :t]
 
 
 def attention_mixer(arch: Arch, w: Mapping[str, jnp.ndarray], u: jnp.ndarray,

@@ -65,11 +65,17 @@ def init_params(draw: Callable[[str, Any], Dict[str, Any]],
                            for i, kind in enumerate(kinds)]}
 
 
-def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
-    """float32 inside, ``x.dtype`` out."""
+def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float,
+             axis: int = -1) -> jnp.ndarray:
+    """Over the channels on ``axis``: float32 inside, ``x.dtype`` out.
+    ``weight`` spans ``axis`` and the axes before it that it has."""
+    axis %= x.ndim
     x32 = x.astype(jnp.float32)
-    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * scale * weight.astype(jnp.float32)).astype(x.dtype)
+    scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=axis, keepdims=True)
+                          + eps)
+    weight = weight.astype(jnp.float32).reshape(
+        weight.shape + (1,) * (x.ndim - 1 - axis))
+    return (x32 * scale * weight).astype(x.dtype)
 
 
 def segment_positions(seg: jnp.ndarray) -> jnp.ndarray:

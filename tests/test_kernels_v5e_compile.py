@@ -11,6 +11,7 @@ The topology is described inside a fixture and nowhere while a module is
 imported: one process at a time may load the TPU's library, and under
 several workers only the one that is given this file may load it.
 """
+import functools
 import importlib
 import os
 import re
@@ -109,16 +110,15 @@ def _loop_bodies(text):
 #: (family, rows, tokens): the attention layers of the four token cells at
 #: their real widths; lfm2's and granite's repeat 8 key/value heads to 32,
 #: nemotron's 2 to 32
-@pytest.mark.parametrize("family, rows, tokens", [
+ATTENTION_LAYERS = [
     ("lfm2_moe", 1, 16384), ("granite_hybrid", 4, 4096),
-    ("deepseek_v2", 1, 16384), ("nemotron_h", 1, 16384)])
-def test_no_attention_loop_rebuilds_the_keys_or_values_for_a_v5e(
-        one_chip, family, rows, tokens):
-    """The blocks ``blockwise_attention``'s loops index are made once,
-    before them: no ``while`` body of the layer broadcasts or copies an
-    array as large as all of K (left alone the compiler sank the repeat to
-    every head, and two layout copies, into the scan over query tiles).
-    The loop scores a tile of every head's 512 queries by 512 keys."""
+    ("deepseek_v2", 1, 16384), ("nemotron_h", 1, 16384)]
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_attention(one_chip, family, rows, tokens):
+    """``(arch, key head width, HLO text)``: one attention layer of
+    ``family`` at its published widths, compiled for a described v5e."""
     mod = importlib.import_module(f"video_features_tpu.models.{family}")
     published = yaml.safe_load((
         Path(__file__).resolve().parents[1] / "video_features_tpu" / "configs"
@@ -146,7 +146,19 @@ def test_no_attention_loop_rebuilds_the_keys_or_values_for_a_v5e(
             spec((rows, tokens), jnp.int32)]
     if rope:
         args += [spec((rows, tokens, rope // 2), jnp.float32)] * 2
-    text = jax.jit(lambda *a: layer(arch, *a)).lower(*args).compile().as_text()
+    return arch, hd, jax.jit(lambda *a: layer(arch, *a)).lower(
+        *args).compile().as_text()
+
+
+@pytest.mark.parametrize("family, rows, tokens", ATTENTION_LAYERS)
+def test_no_attention_loop_rebuilds_the_keys_or_values_for_a_v5e(
+        one_chip, family, rows, tokens):
+    """The blocks ``blockwise_attention``'s loops index are made once,
+    before them: no ``while`` body of the layer broadcasts or copies an
+    array as large as all of K (left alone the compiler sank the repeat to
+    every head, and two layout copies, into the scan over query tiles).
+    The loop scores a tile of every head's 512 queries by 512 keys."""
+    arch, hd, text = _compiled_attention(one_chip, family, rows, tokens)
     heads = arch.num_attention_heads
     all_of_k = rows * tokens * heads * hd
     bodies = _loop_bodies(text)
@@ -158,6 +170,52 @@ def test_no_attention_loop_rebuilds_the_keys_or_values_for_a_v5e(
             if shape and shape.group(1):
                 assert np.prod([int(x) for x in shape.group(1).split(",")]
                                ) < all_of_k, line
+
+
+def _loop_computations(text):
+    """The compiled HLO text of every ``while`` body of a program and of
+    every computation those bodies call (fusions, reductions, inner
+    loops), each once."""
+    seen, todo, found = set(), list(
+        re.findall(r" while\(.*?body=(%[\w.\-]+)", text)), []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        start = text.index("\n" + name + " ")
+        found.append(text[start:text.index("\n}\n", start)])
+        todo += re.findall(r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)",
+                           found[-1])
+    return found
+
+
+@pytest.mark.parametrize("family, rows, tokens", ATTENTION_LAYERS)
+def test_no_attention_loop_masks_its_scores_again_for_a_v5e(
+        one_chip, family, rows, tokens):
+    """The fold reads no mask back out of its scores: ``exp(-inf)`` is 0,
+    so no operation inside an attention loop tests a score tile for an
+    infinity (``jit(isinf)``), and none packs the tile's heads into a
+    ``u8``/``u16``/``u32`` mask of 512 x 512 (a test of every float32
+    score, a pass over the tile as long as the exp-sum's, was in the loop
+    until the fold dropped it). The row guards on the running max stay:
+    16 or 32 heads x 512 values, not a tile."""
+    arch, _, text = _compiled_attention(one_chip, family, rows, tokens)
+    tile = 512 * 512
+    row_guards = 0
+    for body in _loop_computations(text):
+        for line in body.splitlines():
+            shape = re.search(r"= (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+            if not shape:
+                continue
+            size = np.prod([int(x) for x in shape.group(2).split(",") if x])
+            if "jit(isinf)" in line:
+                assert size < tile, line[:240]
+                row_guards += 1
+            if shape.group(3) == "reduce" and shape.group(1) in (
+                    "u8", "u16", "u32"):
+                assert size < tile, line[:240]
+    assert row_guards      # the op names are still there to be read
 
 
 def test_the_nemotron_step_compiles_for_a_v5e_and_fits_its_memory(

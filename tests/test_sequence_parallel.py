@@ -318,6 +318,61 @@ def test_a_fully_masked_tile_stays_finite(rng, monkeypatch, segments):
     np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
+def _guarded_fold(q, acc, ck, cv, scale, valid):
+    """The fold as it was before it trusted ``exp(-inf) = 0``: it set every
+    masked element of ``p`` to 0 again, from the scores' infinities."""
+    o, m, l = acc
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, ck,
+                   preferred_element_type=jnp.float32) * scale
+    if valid is not None:
+        s = jnp.where(valid, s, -jnp.inf)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    m_safe = jnp.where(jnp.isinf(m_new), 0.0, m_new)
+    p = jnp.exp(s - m_safe)
+    if valid is not None:
+        p = jnp.where(jnp.isinf(s), 0.0, p)
+    alpha = jnp.where(jnp.isinf(m), 0.0, jnp.exp(m - m_safe))
+    o = o * alpha + jnp.einsum("bhqk,bkhd->bhqd", p, cv,
+                               preferred_element_type=jnp.float32)
+    l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    return o, m_new, l
+
+
+#: (t, causal, segment ids): a causal row, shuffled documents with no causal
+#: mask, sorted documents with padding (0) at the row's end, no mask at all
+#: (keys padded to a whole block), and T = 65 under tiles of 16, whose last
+#: tile is one query and fifteen padded ones, beside a row all padding
+@pytest.mark.parametrize("t, causal, segments", [
+    (75, True, None), (75, False, "shuffled"), (75, True, "sorted"),
+    (75, False, None), (65, True, "all padding"), (65, True, None)],
+    ids=["causal", "segmented", "padded", "unmasked", "masked-tile-segments",
+         "masked-tile-causal"])
+def test_the_fold_gives_the_bits_it_gave_with_its_isinf_guard(
+        rng, monkeypatch, t, causal, segments):
+    """A masked score is -inf and the shift ``m_safe`` is finite (0 on a row
+    with no key yet), so ``exp(s - m_safe)`` is already 0 where the mask
+    holds: the same bits as the fold that zeroed ``p`` from ``isinf(s)``,
+    under ``debug_nans`` where a tile holds no valid pair."""
+    from video_features_tpu.parallel import sequence
+    monkeypatch.setattr(sequence, "Q_TILE", 16)
+    q, k, v = _packed_qkv(rng, 2, t)
+    seg = (np.stack([np.zeros(t, np.int32),
+                     np.repeat([1, 2, 0], [30, 25, 10]).astype(np.int32)])
+           if segments == "all padding" else
+           _segment_ids(rng, segments, 2, t))
+
+    def run():
+        with jax.debug_nans(True):
+            return np.asarray(sequence.blockwise_attention(
+                q, k, v, block_size=8, causal=causal, scale=0.11,
+                segment_ids=None if seg is None else jnp.asarray(seg)))
+
+    got = run()
+    monkeypatch.setattr(sequence, "_softmax_fold", _guarded_fold)
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, run())
+
+
 @pytest.mark.parametrize("row_len, lengths, want", [
     # one tile, one block: nothing to skip
     (96, (96, 34), [(1, 1), (1, 1)]),

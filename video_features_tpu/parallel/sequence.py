@@ -78,9 +78,14 @@ def _fold_finalize(o, l, dtype):
 def _softmax_fold(q, acc, ck, cv, scale, valid):
     """Fold one K/V block into the streaming-softmax accumulator
     ``(o, m, l)`` — unnormalized output, running max, normalizer. ``valid``
-    is an optional (tq, tk) bool mask (causal and/or padding); the -inf
-    guards keep fully-masked rows finite. Shared by the ring and blockwise
-    paths so the delicate numerics live once."""
+    is an optional (tq, tk) bool mask (causal and/or padding) that sets a
+    masked score to -inf. Two guards per row keep a row with no valid key
+    yet finite: ``m_safe`` shifts by 0 where the running max is still
+    -inf, and ``alpha`` is 0 where the old one was. The shift is finite,
+    so ``exp(-inf - m_safe)`` is exactly 0 and a masked element needs no
+    guard of its own: nothing reads the mask back out of the scores.
+    Shared by the ring and blockwise paths so the delicate numerics live
+    once."""
     o, m, l = acc
     s = jnp.einsum("bqhd,bkhd->bhqk", q, ck,
                    preferred_element_type=jnp.float32) * scale
@@ -89,8 +94,6 @@ def _softmax_fold(q, acc, ck, cv, scale, valid):
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     m_safe = jnp.where(jnp.isinf(m_new), 0.0, m_new)
     p = jnp.exp(s - m_safe)
-    if valid is not None:
-        p = jnp.where(jnp.isinf(s), 0.0, p)
     alpha = jnp.where(jnp.isinf(m), 0.0, jnp.exp(m - m_safe))
     o = o * alpha + jnp.einsum("bhqk,bkhd->bhqd", p, cv,
                                preferred_element_type=jnp.float32)
